@@ -3,11 +3,13 @@
 Reference: the C++ data path (dmlc recordio + OMP JPEG decode,
 ``src/io/iter_image_recordio_2.cc``).  The library is built on demand with
 g++ and cached next to the source; every entry point has a pure-Python
-fallback so the framework works without a toolchain.
+fallback so the framework works without a toolchain — taken with one
+logged warning that carries the compiler's message, never in silence.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -35,7 +37,8 @@ def _build():
 
 
 def get_lib():
-    """Load (building if needed) the native library, or None."""
+    """Load (building if needed) the native library, or None — the
+    failure is logged once, with the compiler's message."""
     global _LIB, _TRIED
     with _LOCK:
         if _TRIED:
@@ -60,9 +63,17 @@ def get_lib():
                 ctypes.POINTER(ctypes.c_long), ctypes.c_long,
                 ctypes.POINTER(ctypes.c_uint8), ctypes.c_int, ctypes.c_int,
                 ctypes.c_int, ctypes.c_int, ctypes.c_int]
-            assert lib.mxtpu_version() >= 1
+            if lib.mxtpu_version() < 1:
+                raise OSError("%s reports version %d" %
+                              (_SO, lib.mxtpu_version()))
             _LIB = lib
-        except Exception:
+        except (OSError, subprocess.CalledProcessError,
+                AttributeError) as e:
+            compiler = getattr(e, "stderr", None) or b""
+            logging.getLogger(__name__).warning(
+                "native I/O library unavailable, falling back to the "
+                "pure-Python record reader and decoder: %s\n%s", e,
+                compiler.decode(errors="replace").strip()[-2000:])
             _LIB = None
         return _LIB
 

@@ -265,8 +265,7 @@ def lint_worker_loops(disable=()):
     worker processes, the PS server/client loops, the serving batcher,
     the resilience heartbeat/watchdog threads, the run-ahead engine, the
     data loader, the launcher and all examples.  A worker loop this repo
-    ships must never block unboundedly on a peer that can die — the exact
-    wedge class behind the BENCH_r03..r05 backend-init hangs.  Skipped
+    ships must never block unboundedly on a peer that can die.  Skipped
     silently outside a repo checkout."""
     import glob
     import os

@@ -5,7 +5,7 @@ model; XLA exposes a post-compile ``cost_analysis()`` — but both need a
 working backend.  This module is the hardware-free counterpart: an
 abstract interpreter over a ``ClosedJaxpr`` that never executes (and
 never compiles) anything, so it runs on the 1-core CI host even when the
-TPU is down (the BENCH_r05 failure mode).  It produces, per primitive
+TPU is down.  It produces, per primitive
 and per program:
 
 - **flops** / **transcendentals** — counted with the same conventions as
@@ -177,9 +177,10 @@ def _axis_names(params):
 # + grid, deterministic); the tape consults the registry BEFORE falling
 # back to the body-walk + zero-cost connector, and an unannotated
 # shipped kernel is NAMED (``Tape.unpriced_kernels`` -> COST005) instead
-# of silently costing near-zero.  Keying is the kernel *function name*
-# (``name_and_src_info.name``, stable through functools.partial), the
-# same name the ``lint_kernel_costs`` AST sweep resolves.
+# of silently costing near-zero.  Keying is the kernel *function name*,
+# which every shipped ``pallas_call`` also passes as ``name=`` so that a
+# device trace shows the kernel under it — the same name the
+# ``lint_kernel_costs`` AST sweep resolves.
 KERNEL_COSTS = {}
 
 
@@ -194,13 +195,10 @@ def declare_kernel_cost(kernel_name):
 
 
 def kernel_name_of(eqn):
-    """The kernel function name of a traced ``pallas_call`` eqn (the
-    registry key), or None when it cannot be determined."""
-    nsi = eqn.params.get("name_and_src_info")
-    name = getattr(nsi, "name", None)
-    if name:
-        return str(name)
-    return None
+    """The registry key of a traced ``pallas_call`` eqn: its ``name=``
+    or, for an unnamed call, the kernel function's own name from the
+    kernel jaxpr's debug info (stable through ``functools.partial``)."""
+    return eqn.params["name"] or eqn.params["jaxpr"].debug_info.func_name
 
 
 def _grid_of(eqn):
@@ -343,13 +341,13 @@ def build_tape(closed_jaxpr, axis_sizes=None):
     cond / while) into a flat Tape.  ``axis_sizes`` maps mesh-axis name →
     size for the collective-bytes model (defaults to the jaxpr's bound
     axis sizes where visible, else 1)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Literal
 
     axis_sizes = dict(axis_sizes or {})
     tape = Tape()
 
     def read(env, atom):
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             i = tape.fresh(atom.aval, literal=True)
             tape.literal_values[i] = atom.val
             return i
@@ -411,8 +409,6 @@ def build_tape(closed_jaxpr, axis_sizes=None):
         onto the sub-jaxpr's invars; scan/while/cond get structural
         handling; anything else is traversed with fresh inner inputs
         (cost still counted, liveness approximate)."""
-        import jax
-
         if prim == "pallas_call":
             # declared-cost fast path: one priced op with REAL dataflow
             # (in place of the body walk, whose once-not-per-grid-step
@@ -442,8 +438,7 @@ def build_tape(closed_jaxpr, axis_sizes=None):
             # deterministic: charge the most expensive branch
             best, best_cost = None, -1
             for _, sj, sc in subs:
-                t2 = build_tape(
-                    jax.core.ClosedJaxpr(sj, list(sc)), axis_sizes)
+                t2 = build_tape(ClosedJaxpr(sj, list(sc)), axis_sizes)
                 cost = sum(op.flops for op in t2.ops)
                 if cost > best_cost:
                     best, best_cost = (sj, sc), cost
@@ -492,12 +487,12 @@ def build_tape(closed_jaxpr, axis_sizes=None):
             walk(sj, list(sc), inner_env, sub_scale)
             if si == 0 and len(sj.outvars) == len(eqn.outvars):
                 for outer, inner in zip(eqn.outvars, sj.outvars):
-                    if isinstance(inner, jax.core.Literal) or \
+                    if isinstance(inner, Literal) or \
                             not _same_aval(outer.aval, inner.aval):
                         # stacked scan output vs the body's slice var:
                         # same severing rule as the operands above
                         env[outer] = tape.fresh(outer.aval)
-                        if not isinstance(inner, jax.core.Literal):
+                        if not isinstance(inner, Literal):
                             connected = False
                     else:
                         env[outer] = inner_env.get(
@@ -529,8 +524,7 @@ def build_tape(closed_jaxpr, axis_sizes=None):
         tape.invar_ids.append(i)
     walk(jaxpr, list(closed_jaxpr.consts), env, 1)
     for v in jaxpr.outvars:
-        import jax as _jax
-        if isinstance(v, _jax.core.Literal):
+        if isinstance(v, Literal):
             tape.outvar_ids.append(tape.fresh(v.aval))
         else:
             tape.outvar_ids.append(env[v])

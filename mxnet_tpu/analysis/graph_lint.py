@@ -203,14 +203,14 @@ def _lint_large_consts(symbol, shapes, type_dict):
         return []   # graph doesn't trace — execution will report it
     out = []
     for const in closed.consts:
-        nbytes = getattr(const, "nbytes", 0)
+        # shape x itemsize: jax's typed-constant wrapper has no .nbytes
+        nbytes = int(_np.prod(const.shape)) * _np.dtype(const.dtype).itemsize
         if nbytes > LARGE_CONST_BYTES:
             out.append(Finding(
                 "GRF006", symbol.name or "<graph>",
                 "constant of shape %s (%s, %.1f MiB) is folded into the "
                 "jaxpr; pass it as an argument instead of closing over it" %
-                (tuple(getattr(const, "shape", ())),
-                 getattr(const, "dtype", "?"), nbytes / (1 << 20))))
+                (tuple(const.shape), const.dtype, nbytes / (1 << 20))))
     return out
 
 

@@ -51,8 +51,8 @@ def _pkg_root():
 
 
 def probe_sites_used(root=None):
-    """Scan ``mxnet_tpu/**/*.py`` (plus the shipped drivers:
-    ``bench.py``, ``tools/*.py``) for ``maybe_inject(<literal>, ...)``
+    """Scan ``mxnet_tpu/**/*.py`` (plus the shipped drivers,
+    ``tools/*.py``) for ``maybe_inject(<literal>, ...)``
     calls.  Returns ``(sites, dynamic)``: ``sites`` maps each literal
     site name to its ``file:line`` use sites; ``dynamic`` lists calls
     whose site argument is not a string literal (unverifiable — those
@@ -64,9 +64,7 @@ def probe_sites_used(root=None):
     targets = sorted(glob.glob(os.path.join(root, "**", "*.py"),
                                recursive=True))
     # probe sites also live in the shipped drivers outside the package
-    # (bench.py's backend.init, tools/): same fault model, same sweep
-    if os.path.isfile(os.path.join(repo, "bench.py")):
-        targets.append(os.path.join(repo, "bench.py"))
+    # (tools/train_elastic.py's train.step): same fault model, same sweep
     targets += sorted(glob.glob(os.path.join(repo, "tools", "*.py")))
     for path in targets:
         rel = os.path.relpath(path, os.path.dirname(root))

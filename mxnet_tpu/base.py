@@ -109,6 +109,27 @@ config = _Config()
 
 
 # ---------------------------------------------------------------------------
+# Persistent compilation cache, placed from outside the program.
+# ---------------------------------------------------------------------------
+def use_compilation_cache():
+    """Give this process JAX's persistent compilation cache; returns the
+    directory.  Entry points (``chip_smoke.py``, ``bench.py``,
+    ``tools/serve.py``) call it from ``main`` — never at import.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and
+    nothing is set here.  Otherwise the cache lives at
+    ``<checkout>/.jax_cache``: a fixed path, because the path is part of
+    the cache key and a directory that moves never hits."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+# ---------------------------------------------------------------------------
 # Generic object registry (reference: python/mxnet/registry.py) used by
 # optimizer/metric/initializer subsystems.
 # ---------------------------------------------------------------------------

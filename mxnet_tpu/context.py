@@ -101,12 +101,29 @@ def _backend_devices(platform):
 _ACCEL_CACHE = None
 
 
+def _cpu_requested():
+    """Was the CPU backend asked for by name (``JAX_PLATFORMS=cpu``, as
+    tests/conftest.py pins it)?"""
+    return "cpu" in (jax.config.jax_platforms or "").split(",")
+
+
 def _accelerator_devices():
-    """Local non-CPU jax devices; falls back to CPU if none (host testing)."""
+    """Local non-CPU jax devices.  The CPU devices stand in for them only
+    where the CPU was asked for (:func:`_cpu_requested` — host testing);
+    otherwise a missing accelerator raises instead of ``mx.tpu(0)``
+    silently training on the host."""
     global _ACCEL_CACHE
     if _ACCEL_CACHE is None:
         devs = [d for d in jax.local_devices() if d.platform != "cpu"]
-        _ACCEL_CACHE = devs if devs else _backend_devices("cpu")
+        if not devs:
+            if not _cpu_requested():
+                raise RuntimeError(
+                    "no accelerator device present (jax found only %r) and "
+                    "JAX_PLATFORMS does not name cpu: refusing to resolve "
+                    "an accelerator context to the host"
+                    % sorted({d.platform for d in jax.local_devices()}))
+            devs = _backend_devices("cpu")
+        _ACCEL_CACHE = devs
     return _ACCEL_CACHE
 
 
@@ -148,10 +165,7 @@ def _implicit_default():
     reference defaults to cpu() because its CPU build has no choice)."""
     global _IMPLICIT_DEFAULT
     if _IMPLICIT_DEFAULT is None:
-        try:
-            platform = jax.default_backend()
-        except Exception:
-            platform = "cpu"
+        platform = jax.default_backend()
         _IMPLICIT_DEFAULT = Context("cpu" if platform == "cpu" else "tpu", 0)
     return _IMPLICIT_DEFAULT
 

@@ -58,10 +58,6 @@ class _SlowFeedIter:
 
 
 def main():
-    import jax
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
     import numpy as np
 
     import mxnet_tpu as mx

@@ -332,15 +332,11 @@ class Executor:
         return set(self._jit_cache_keys)
 
     def jit_cache_size(self):
-        """Number of compiled program variants.  Prefers the jit's own
-        cache counter (counts actual XLA traces) and falls back to the
-        tracked signature set."""
-        try:
-            return int(self._jit_infer._cache_size()
-                       + self._jit_train._cache_size()
-                       + self._jit_bwd._cache_size())
-        except AttributeError:
-            return len(self._jit_cache_keys)
+        """Number of compiled program variants: the jits' own cache
+        counters, which count actual XLA traces."""
+        return int(self._jit_infer._cache_size()
+                   + self._jit_train._cache_size()
+                   + self._jit_bwd._cache_size())
 
     # ------------------------------------------------------------------
     @property

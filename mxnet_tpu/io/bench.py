@@ -2,8 +2,7 @@
 
 Measures what the host can FEED, with no accelerator in the loop (run as a
 ``JAX_PLATFORMS=cpu`` subprocess by bench.py, the PR-2 serving pattern —
-the number stays live even when the TPU backend is down, which is exactly
-when BENCH_r03..r05 starved every pipeline key).
+the number stays live even when the TPU backend is down).
 
 ``fed`` here means: decode + augment + transfer fenced on the (cpu)
 device + the fused normalization tail applied, per batch, measured over a
@@ -22,35 +21,12 @@ Prints one JSON line; bench.py merges it into the round record.
 """
 from __future__ import annotations
 
-import io as _pyio
 import json
 import os
 import shutil
 import sys
 import tempfile
 import time
-
-
-def _synth_rec(n, size=224):
-    import numpy as np
-    from PIL import Image
-
-    from .. import recordio
-    tmpdir = tempfile.mkdtemp(prefix="mxtpu_pipe_bench_")
-    rec = os.path.join(tmpdir, "synth.rec")
-    idx = os.path.join(tmpdir, "synth.idx")
-    rng = np.random.RandomState(0)
-    w = recordio.MXIndexedRecordIO(idx, rec, "w")
-    buf = _pyio.BytesIO()
-    for i in range(n):
-        img = rng.randint(0, 255, (size, size, 3), np.uint8)
-        buf.seek(0)
-        buf.truncate()
-        Image.fromarray(img).save(buf, format="JPEG", quality=90)
-        w.write_idx(i, recordio.pack(
-            recordio.IRHeader(0, float(i % 1000), i, 0), buf.getvalue()))
-    w.close()
-    return tmpdir, rec, idx
 
 
 def _timed_epoch(make_iter, consume):
@@ -83,21 +59,20 @@ def _timed_epoch(make_iter, consume):
 
 def main():
     import jax
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
     import jax.numpy as jnp
     import numpy as np
 
     import mxnet_tpu as mx
     from mxnet_tpu import _native, recordio
+    from mxnet_tpu.test_utils import synthetic_image_rec
 
     n = int(os.environ.get("MXTPU_PIPE_BENCH_N", "768"))
     batch = int(os.environ.get("MXTPU_PIPE_BENCH_BATCH", "128"))
     size = int(os.environ.get("MXTPU_PIPE_BENCH_SIZE", "224"))
     workers_curve = [int(w) for w in os.environ.get(
         "MXTPU_PIPE_BENCH_WORKERS", "0,1,2").split(",")]
-    tmpdir, rec, idx = _synth_rec(n, size)
+    tmpdir = tempfile.mkdtemp(prefix="mxtpu_pipe_bench_")
+    rec, idx = synthetic_image_rec(tmpdir, n, size)
     out = {"pipeline_host_cores": os.cpu_count(),
            "pipeline_batch": batch, "pipeline_n_records": n}
     try:

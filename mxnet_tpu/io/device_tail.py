@@ -97,10 +97,7 @@ def tail_cache_sizes():
     with _LOCK:
         items = list(_CACHE.items())
     for key, fn in items:
-        try:
-            out[key] = int(fn._cache_size())
-        except AttributeError:
-            out[key] = -1
+        out[key] = int(fn._cache_size())
     return out
 
 

@@ -4,8 +4,9 @@ Reference: the C++ ``ImageRecordIter`` escapes Python entirely —
 ``preprocess_threads`` OMP workers decode into pinned buffers and a
 prefetcher thread double-buffers the copy (``src/io/
 iter_image_recordio_2.cc``, ``iter_prefetcher.h``).  The Python port's
-thread pool shares one GIL, so on a small host the chip starves: BENCH_r05
-measured the device step at 2391 img/s/chip against a 127 img/s host feed.
+thread pool shares one GIL, so on a small host the chip starves: an
+early driver record measured the device step at 2391 img/s/chip against
+a 127 img/s host feed (earlier installation, one host core).
 
 This module is the process-parallel analogue:
 
@@ -513,6 +514,10 @@ class ImagePipelineIter(DataIter):
         self._begin_epoch()
 
     # -- lifecycle ---------------------------------------------------------
+    def worker_pids(self):
+        """PIDs of the decode worker processes (empty when in-process)."""
+        return [p.pid for p in self._procs if p is not None]
+
     def close(self):
         procs, self._procs = self._procs, []
         for p in procs:

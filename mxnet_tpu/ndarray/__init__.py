@@ -98,10 +98,7 @@ def waitall():
     """Block until all async computation completes (reference engine WaitForAll)."""
     import jax
     (_jnp.zeros(()) + 0).block_until_ready()
-    try:
-        jax.effects_barrier()
-    except AttributeError:
-        pass
+    jax.effects_barrier()
 
 
 def load(fname):

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis.cost import declare_kernel_cost
-from .pallas_kernels import _on_tpu, _sds
+from .pallas_kernels import _sds, on_tpu, resolve_interpret
 
 __all__ = ["FUSED_OPTIMIZER", "FUSED_LAYERNORM", "fused_update_enabled",
            "fused_layernorm_enabled", "supports", "fused_sgd",
@@ -64,7 +64,7 @@ def fused_update_enabled():
     force = os.environ.get("MXTPU_FUSED_OPTIMIZER")
     if force is not None:
         return force == "1"
-    return _on_tpu()
+    return on_tpu()
 
 
 def fused_layernorm_enabled(feature_dim=None, dtype=None):
@@ -76,7 +76,7 @@ def fused_layernorm_enabled(feature_dim=None, dtype=None):
     force = os.environ.get("MXTPU_FUSED_LAYERNORM")
     if force is not None:
         return force == "1"
-    if not _on_tpu():
+    if not on_tpu():
         return False
     if dtype is not None and jnp.dtype(dtype) != jnp.float32:
         return False
@@ -198,8 +198,7 @@ def _flat_call(kernel, scalars, arrays, n_out, aliases, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     p = int(arrays[0].shape[0])
     # off-TPU (interpret) there is no VMEM budget: one whole-array
     # block per call keeps the interpreter's per-grid-step overhead out
@@ -218,6 +217,7 @@ def _flat_call(kernel, scalars, arrays, n_out, aliases, interpret):
                         for _ in range(n_out)) if n_out > 1
         else _sds((rows, 128), jnp.float32, arrays[0]),
         input_output_aliases=dict(aliases),
+        name=kernel.func.__name__,
         interpret=interpret,
     )(scalars, *tiles)
     if n_out == 1:
@@ -325,8 +325,7 @@ def _fused_ln_kernel(x_ref, s_ref, b_ref, o_ref, *, eps):
 def _ln_fwd_impl(x, scale, bias, eps, interpret):
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     d = x.shape[-1]
     lead = x.shape[:-1]
     rows = 1
@@ -350,6 +349,7 @@ def _ln_fwd_impl(x, scale, bias, eps, interpret):
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=_sds((rp, d), x.dtype, x),
+        name="_fused_ln_kernel",
         interpret=interpret,
     )(x2, scale.reshape(1, d), bias.reshape(1, d))
     return out[:rows].reshape(lead + (d,))

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis.cost import declare_kernel_cost
-from .pallas_kernels import _on_tpu
+from .pallas_kernels import resolve_interpret
 
 from jax.experimental import pallas as pl
 
@@ -100,8 +100,7 @@ def generated_call(gk, *arrays, interpret=None, block_rows=None):
     body (broadcast/reduce shapes are baked in).  ``block_rows`` (or the
     kernel's autotuned choice) row-tiles the flat-tileable kernels over
     a ``(block_rows, 128)`` grid; padding rows are sliced off."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     block_rows = block_rows or gk.block_rows
     if block_rows:
         return _tiled_call(gk, arrays, block_rows, interpret)
@@ -111,7 +110,7 @@ def generated_call(gk, *arrays, interpret=None, block_rows=None):
         ins.append(x.reshape((1,)) if x.ndim == 0 else x)
     out_shape = [jax.ShapeDtypeStruct(_rank1(tuple(a.shape)), a.dtype)
                  for a in gk.out_avals]
-    outs = pl.pallas_call(gk.fn, out_shape=out_shape,
+    outs = pl.pallas_call(gk.fn, out_shape=out_shape, name=gk.name,
                           interpret=interpret)(*ins)
     return [o.reshape(tuple(a.shape))
             for o, a in zip(outs, gk.out_avals)]
@@ -138,7 +137,7 @@ def _tiled_call(gk, arrays, block_rows, interpret):
     outs = pl.pallas_call(
         gk.fn, grid=(grid,),
         in_specs=[spec] * len(ins), out_specs=[spec] * len(out_shape),
-        out_shape=out_shape, interpret=interpret)(*ins)
+        out_shape=out_shape, name=gk.name, interpret=interpret)(*ins)
     return [o.reshape((-1,))[:n] for o in outs]
 
 

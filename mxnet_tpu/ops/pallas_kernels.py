@@ -7,7 +7,10 @@ online softmax so the (T×T) score matrix never materializes in HBM —
 the standard TPU flash pattern (see /opt/skills/guides/pallas_guide.md).
 
 On non-TPU backends the same kernel runs in Pallas interpret mode, so
-tests exercise the real kernel logic on the CPU mesh.
+tests exercise the real kernel logic on the CPU mesh.  That choice is
+made in exactly one place, :func:`resolve_interpret`; every kernel in
+the package (here, ``fused_optimizer.py``, ``generated_kernels.py``)
+goes through it.
 
 Training: forward AND backward are Pallas kernels.  The forward emits the
 per-row logsumexp; the backward recomputes probabilities blockwise from
@@ -32,20 +35,26 @@ _NEG_INF = -1e30
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct inheriting `like`'s varying-mesh-axes (vma) type,
     so the kernels compose with shard_map's check_vma typing."""
-    try:
-        vma = getattr(jax.typeof(like), "vma", None)
-    except Exception:
-        vma = None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def on_tpu():
+    """Is the default backend — where un-pinned arrays and jitted
+    programs land — a TPU?  A backend that fails to initialise raises
+    here; it never reads as "no TPU"."""
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(interpret=None):
+    """The package's one compile-or-interpret decision for a Pallas
+    kernel: an explicit ``interpret`` wins; ``None`` means Mosaic on a
+    TPU and the Pallas interpreter everywhere else."""
+    if interpret is None:
+        return not on_tpu()
+    return bool(interpret)
 
 
 def _attention_reference(q, k, v, causal, scale):
@@ -160,7 +169,8 @@ def _flash_attention_fwd_impl(q, k, v, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
             pltpu.VMEM((block_q, D), jnp.float32),     # output accumulator
         ],
-        interpret=interpret,
+        name="_fa_kernel",
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
 
 
@@ -300,8 +310,6 @@ def flash_dq(q, k, v, do, lse, delta, causal, scale, block_q=128,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = not _on_tpu()
     BH, T, D = q.shape
     Tk = k.shape[1]
     block_q = min(block_q, T)
@@ -320,7 +328,8 @@ def flash_dq(q, k, v, do, lse, delta, causal, scale, block_q=128,
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_q, row_q],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
+        name="_fa_dq_kernel",
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, _tile_rows(lse), _tile_rows(delta))
 
 
@@ -331,8 +340,6 @@ def flash_dkv(q, k, v, do, lse, delta, causal, scale, block_q=128,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = not _on_tpu()
     BH, T, D = q.shape
     Tk = k.shape[1]
     block_q = min(block_q, T)
@@ -353,14 +360,13 @@ def flash_dkv(q, k, v, do, lse, delta, causal, scale, block_q=128,
         out_specs=(k_spec, k_spec),
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        interpret=interpret,
+        name="_fa_dkv_kernel",
+        interpret=resolve_interpret(interpret),
     )(q, k, v, do, _tile_rows(lse), _tile_rows(delta))
 
 
 def flash_forward_with_lse(q, k, v, causal, scale, interpret=None):
     """(out, lse) with lse (BH, T) f32 — building block for ring attention."""
-    if interpret is None:
-        interpret = not _on_tpu()
     out, lse8 = _flash_attention_fwd_impl(q, k, v, causal, scale,
                                           block_q=128, block_k=128,
                                           interpret=interpret)
@@ -380,27 +386,24 @@ def _flash_attention_bwd_impl(q, k, v, o, lse, do, causal, scale, block_q,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_core(q, k, v, causal, scale):
-    interpret = not _on_tpu()
     out, _ = _flash_attention_fwd_impl(q, k, v, causal, scale,
                                        block_q=128, block_k=128,
-                                       interpret=interpret)
+                                       interpret=None)
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale):
-    interpret = not _on_tpu()
     out, lse = _flash_attention_fwd_impl(q, k, v, causal, scale,
                                          block_q=128, block_k=128,
-                                         interpret=interpret)
+                                         interpret=None)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, scale, res, g):
     q, k, v, o, lse = res
-    interpret = not _on_tpu()
     return _flash_attention_bwd_impl(q, k, v, o, lse, g, causal, scale,
                                      block_q=128, block_k=128,
-                                     interpret=interpret)
+                                     interpret=None)
 
 
 _flash_core.defvjp(_flash_fwd, _flash_bwd)
@@ -466,8 +469,6 @@ def qmm_requant(x, w, bias, out_scale, relu=True, interpret=None):
     """
     from jax.experimental import pallas as pl
 
-    if interpret is None:
-        interpret = not _on_tpu()
     M, K = x.shape
     N = w.shape[1]
 
@@ -495,7 +496,8 @@ def qmm_requant(x, w, bias, out_scale, relu=True, interpret=None):
         ],
         out_specs=pl.BlockSpec((_QMM_MB, _QMM_NB), lambda i, j: (i, j)),
         out_shape=_sds((Mp, Np), jnp.int8, x),
-        interpret=interpret,
+        name="_qmm_requant_kernel",
+        interpret=resolve_interpret(interpret),
     )(x, w, bias)
     return out[:M, :N]
 
@@ -646,8 +648,6 @@ def conv3x3_epilogue(x, w, scale, shift, relu=True, out_dtype=None,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = not _on_tpu()
     N, H, W, Cin = x.shape
     Cout = w.shape[-1]
     is_int8 = x.dtype == jnp.int8
@@ -733,7 +733,7 @@ def conv3x3_epilogue(x, w, scale, shift, relu=True, out_dtype=None,
         kernel,
         grid=(N // nb, H // th, Cop // tn),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # manual halo DMA
+            pl.BlockSpec(memory_space=pl.ANY),  # manual halo DMA
             pl.BlockSpec((9 * Cp, tn), lambda n, h, co: (0, co)),
             pl.BlockSpec((1, tn), lambda n, h, co: (0, co)),
             pl.BlockSpec((1, tn), lambda n, h, co: (0, co)),
@@ -746,7 +746,8 @@ def conv3x3_epilogue(x, w, scale, shift, relu=True, out_dtype=None,
             pltpu.VMEM((nb * th * W, 9 * Cp), x.dtype),
             pltpu.SemaphoreType.DMA(()),
         ],
-        interpret=interpret,
+        name="_conv3x3_kernel",
+        interpret=resolve_interpret(interpret),
     )(xp, wcol, scale, shift)
     return out if Cop == Cout else out[..., :Cout]
 

@@ -71,10 +71,7 @@ def local_attention(q, k, v, causal=False, scale=None, q_offset=0,
 
 
 def _pvary(x, axis_name):
-    try:
-        return lax.pvary(x, (axis_name,))
-    except AttributeError:
-        return x
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def _to_bhtd(x):
@@ -315,17 +312,12 @@ def _seq_sharded_spec(mesh, axis):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs, check=False):
-    """Version-tolerant shard_map: jax>=0.5 exports jax.shard_map with a
-    check_vma kwarg; 0.4.x has jax.experimental.shard_map with check_rep.
-    check=False either way: the Pallas interpret-mode lowering slices
-    blocks with non-varying program-id indices, which the replication/vma
-    checker rejects; the kernels are correct under manual sharding."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check)
+    """``jax.shard_map`` with ``check_vma`` off by default: the Pallas
+    interpret-mode lowering slices blocks with non-varying program-id
+    indices, which the vma checker rejects; the kernels are correct
+    under manual sharding."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def ring_attention_sharded(q, k, v, mesh, axis="sp", causal=False):

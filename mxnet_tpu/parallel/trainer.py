@@ -1219,8 +1219,20 @@ class DataParallelTrainer:
         loss-scale reciprocal and the finite flag through (``inv_scale``
         / ``ok`` f32 scalars): the fused kernel unscales + select-skips
         in the same pass, the unfused fallback spells the same algebra
-        around ``functional_optimizer_update``."""
+        around ``functional_optimizer_update``.
+
+        The fused update of a replicated group runs under a
+        ``shard_map`` over ``self._mesh`` with replicated specs — every
+        device updates its own full copy, which is what replication
+        meant anyway — because the step is partitioned by GSPMD, and
+        GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot
+        be automatically partitioned" — first met on four v5e chips,
+        CHANGES.md PR 21).  The runtime builders and their analysis
+        twins trace this one spelling.  A group whose parameter is
+        sharded keeps the unfused spelling, which GSPMD does
+        partition."""
         from ..ops import fused_optimizer as _fused
+        from .ring_attention import _shard_map
 
         opt, groups = self._opt, self._groups
         fused_on = (_fused.fused_update_enabled()
@@ -1230,11 +1242,24 @@ class DataParallelTrainer:
         new_vals = [None] * len(train_vals)
         new_states = []
 
+        def _fusable(gi, w):
+            return (fused_on and w.dtype == jnp.float32
+                    and self._group_shardings[gi].spec == PartitionSpec())
+
         def _fused_flat(gi, wf, gf):
             sf = jax.tree_util.tree_map(jnp.ravel, states[gi])
-            kw = ({"inv_scale": inv_scale, "ok": ok} if scaled else {})
-            nwf, nsf = _fused.fused_optimizer_update(
-                opt, gi, wf.ravel(), gf.ravel(), sf, lr, t, **kw)
+            scale_args = (inv_scale, ok) if scaled else ()
+
+            def update(wf, gf, sf, lr, t, *scale_args):
+                return _fused.fused_optimizer_update(
+                    opt, gi, wf, gf, sf, lr, t,
+                    **dict(zip(("inv_scale", "ok"), scale_args)))
+
+            rep = PartitionSpec()
+            nwf, nsf = _shard_map(
+                update, self._mesh,
+                (rep,) * (5 + len(scale_args)), (rep, rep))(
+                    wf.ravel(), gf.ravel(), sf, lr, t, *scale_args)
             ns = jax.tree_util.tree_map(
                 lambda n, o: n.reshape(o.shape), nsf, states[gi])
             return nwf, ns
@@ -1255,7 +1280,7 @@ class DataParallelTrainer:
             idxs = [name_to_idx[n] for n in names]
             if len(idxs) == 1:
                 i = idxs[0]
-                if fused_on and train_vals[i].dtype == jnp.float32:
+                if _fusable(gi, train_vals[i]):
                     nwf, ns = _fused_flat(gi, train_vals[i], grads[i])
                     nw = nwf.reshape(train_vals[i].shape)
                 else:
@@ -1268,7 +1293,7 @@ class DataParallelTrainer:
                 wf = jnp.concatenate(
                     [train_vals[i].ravel() for i in idxs])
                 gf = jnp.concatenate([grads[i].ravel() for i in idxs])
-                if fused_on and wf.dtype == jnp.float32:
+                if _fusable(gi, wf):
                     nwf, ns = _fused_flat(gi, wf, gf)
                 else:
                     nwf, ns = _unfused(gi, wf, gf)
@@ -1817,6 +1842,57 @@ class DataParallelTrainer:
             return NamedSharding(self._mesh, self._plan.batch_spec())
         return NamedSharding(self._mesh, PartitionSpec(self._data_axis))
 
+    def device_arrays(self):
+        """``(params, optimizer_leaves)``: the live device arrays the next
+        step will consume — name -> ``jax.Array`` and a flat list — so a
+        caller can see where the training state sits (devices,
+        shardings) without gathering it to the host.  Only meaningful
+        after the first step."""
+        if self._plan is not None:
+            return dict(self._mesh_params), list(self._mesh_state_leaves)
+        params = {n: p.data()._data
+                  for n, p in self._params_by_name.items()}
+        return params, jax.tree_util.tree_leaves(self._states_raw)
+
+    def lower_step(self, data, label):
+        """``jax.stages.Lowered`` of the jitted function :meth:`step`
+        dispatches, at this batch geometry and the live training state
+        (replicated single-program tier, after the first step) —
+        ``.as_text()`` shows what the step lowers to, e.g. a Pallas
+        kernel as a Mosaic ``tpu_custom_call``.  It lowers the same
+        function with arguments assembled by the same ``_step_args``;
+        it is not a handle on the executable that already ran.  Nothing
+        executes, no buffer is donated."""
+        if self._step_fn is None:
+            raise RuntimeError(
+                "lower_step() needs the replicated tier's compiled step "
+                "(take one step first; the kvstore, zero=1 and mesh_plan "
+                "tiers run split programs)")
+        batch_sh = self.batch_sharding
+        train_vals, aux_vals = self._live_vals()
+        return self._step_fn.lower(*self._step_args(
+            train_vals, aux_vals, self._put_batch(data, batch_sh),
+            self._put_batch(label, batch_sh), jax.random.PRNGKey(0),
+            self._opt.lr))
+
+    def _live_vals(self):
+        """``(train_vals, aux_vals)``: the live parameter arrays in the
+        compiled programs' argument order."""
+        return (tuple(self._params_by_name[n].data()._data
+                      for n in self._train_names),
+                tuple(self._params_by_name[n].data()._data
+                      for n in self._aux_names))
+
+    def _step_args(self, train_vals, aux_vals, x, y, rng, lr_host):
+        """Positional arguments of the replicated tier's compiled step
+        (``_build_step``) — the one assembly :meth:`step` dispatches
+        with and :meth:`lower_step` lowers with."""
+        args = (train_vals, tuple(self._states_raw), aux_vals, x, y, rng,
+                jnp.float32(lr_host), jnp.int32(self._step_count))
+        if self._reduced:
+            args += (self._ls_scale, self._ls_good, self._ls_skipped)
+        return args
+
     def _put_batch(self, arr, sharding):
         """``device_put`` with a fast path: a committed ``jax.Array``
         already laid out per ``sharding`` (the prefetcher's work) is used
@@ -1825,13 +1901,9 @@ class DataParallelTrainer:
         on layout mismatch), and skipping it keeps the prefetch transfer
         the only one."""
         raw = arr._data if isinstance(arr, NDArray) else arr
-        if isinstance(raw, jax.Array) and getattr(raw, "committed", False):
-            try:
-                if raw.sharding.is_equivalent_to(sharding, raw.ndim):
-                    return raw
-            except (AttributeError, TypeError):
-                if raw.sharding == sharding:
-                    return raw
+        if isinstance(raw, jax.Array) and raw.committed and \
+                raw.sharding.is_equivalent_to(sharding, raw.ndim):
+            return raw
         if not isinstance(raw, jax.Array):
             raw = np.asarray(raw)
         return jax.device_put(raw, sharding)
@@ -1847,10 +1919,7 @@ class DataParallelTrainer:
         while len(self._inflight) > limit:
             oldest = self._inflight.popleft()
             t0 = time.perf_counter()
-            try:
-                oldest.block_until_ready()
-            except AttributeError:
-                pass
+            oldest.block_until_ready()
             waited = time.perf_counter() - t0
             self.dispatch_stats.on_backpressure(waited)
             # sub-20us "waits" are block_until_ready call overhead on an
@@ -1867,11 +1936,7 @@ class DataParallelTrainer:
         states are fully materialized (donation already retired)."""
         t0 = time.perf_counter()
         while self._inflight:
-            oldest = self._inflight.popleft()
-            try:
-                oldest.block_until_ready()
-            except AttributeError:
-                pass
+            self._inflight.popleft().block_until_ready()
         waited = time.perf_counter() - t0
         if waited > 0:
             self.dispatch_stats.on_backpressure(waited)
@@ -1931,10 +1996,7 @@ class DataParallelTrainer:
         self._opt.num_update = self._step_count
         lr_host = (self._opt.lr_scheduler(self._step_count)
                    if self._opt.lr_scheduler else self._opt.lr)
-        train_vals = tuple(self._params_by_name[n].data()._data
-                           for n in self._train_names)
-        aux_vals = tuple(self._params_by_name[n].data()._data
-                         for n in self._aux_names)
+        train_vals, aux_vals = self._live_vals()
         rng = _rng.next_key()
 
         if self._kv is not None:
@@ -1954,18 +2016,13 @@ class DataParallelTrainer:
                 self._step_fn = self._build_step()
                 if tele_on and self._grad_accum > 1:
                     attr.set_context("dispatch", "grad_accum")
+            out = self._step_fn(*self._step_args(
+                train_vals, aux_vals, x, y, rng, lr_host))
             if self._reduced:
                 (loss_val, new_vals, new_states, muts, self._ls_scale,
-                 self._ls_good, self._ls_skipped) = self._step_fn(
-                    train_vals, tuple(self._states_raw), aux_vals, x, y,
-                    rng, jnp.float32(lr_host),
-                    jnp.int32(self._step_count), self._ls_scale,
-                    self._ls_good, self._ls_skipped)
+                 self._ls_good, self._ls_skipped) = out
             else:
-                loss_val, new_vals, new_states, muts = self._step_fn(
-                    train_vals, tuple(self._states_raw), aux_vals, x, y,
-                    rng, jnp.float32(lr_host),
-                    jnp.int32(self._step_count))
+                loss_val, new_vals, new_states, muts = out
             self._states_raw = list(new_states)
             if tele_on:
                 # "dispatch" spans from the batch being device-ready to

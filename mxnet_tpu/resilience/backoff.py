@@ -1,11 +1,10 @@
 """Shared retry/backoff policy: exponential backoff with jitter.
 
 Every transient-failure site in the stack retries the same way — the
-bench's backend acquisition (``bench.py _acquire_devices``), the
 launcher's crashed-rank restarts (``tools/launch.py --restart-failed``)
 and the kvstore client's push/pull RPC reconnects (``kvstore_ps.PSClient``)
-all draw their delays from one :class:`BackoffPolicy` instead of three
-divergent hand-rolled loops.  Jitter is the load-shedding half of the
+draw their delays from one :class:`BackoffPolicy` instead of divergent
+hand-rolled loops.  Jitter is the load-shedding half of the
 policy (reference: ps-lite's van retry + the classic "exponential backoff
 and jitter" result): N workers that all lost the same server must not
 redial in lockstep.

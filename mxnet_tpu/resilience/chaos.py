@@ -1,8 +1,8 @@
 """Deterministic fault injection: replay a seeded fault schedule.
 
 Every failure mode this repo has met in production-shaped form — a
-SIGKILLed pipeline worker, a dropped/delayed kvstore push, a stalled
-backend init (BENCH_r03..r05), an overloaded serving queue — gets a
+SIGKILLed pipeline worker, a dropped/delayed kvstore push, an
+overloaded serving queue — gets a
 *reproducible* tier-1 test instead of a flaky prod story.  The pieces:
 
 - **probe sites**: code at failure-relevant points calls
@@ -17,9 +17,8 @@ backend init (BENCH_r03..r05), an overloaded serving queue — gets a
   (count = batch number; a ``delay`` here is the runner-stall /
   queue-overload injection), ``serving.route`` (count = routed-request
   ordinal on the model fleet, ctx = (model, tier)), ``serving.swap``
-  (fleet hot swap, ctx = model name), ``engine.flush``, ``backend.init``
-  (bench.py acquisition attempts), ``checkpoint.save`` (mid-write, for
-  atomicity tests).
+  (fleet hot swap, ctx = model name), ``engine.flush``,
+  ``checkpoint.save`` (mid-write, for atomicity tests).
 - **faults**: ``Fault(site, at, action, arg)`` — trigger the ``at``-th
   probe hit (1-based; or the probe's explicit ``count``) at ``site`` and
   perform ``action``:
@@ -75,7 +74,6 @@ SITES = {
     "mlops.decision": "count = promotion evaluate tick; "
                       "ctx = (model, state)",
     "engine.flush": "run-ahead ring drain",
-    "backend.init": "count = bench.py acquisition attempt",
     "checkpoint.save": "mid-checkpoint-write (atomicity tests)",
     "ckpt.shard_write": "before each shard install of a shard-parallel "
                         "snapshot; ctx = (step, rank)",

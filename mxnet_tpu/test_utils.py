@@ -177,3 +177,32 @@ def separable_images(rng, n, nclass=4, size=12, channels=3, noise=0.4,
         r0, c0 = (c // 2) % 2 * q, c % 2 * q
         X[i, r0:r0 + q, c0:c0 + q] += base + 0.2 * (c // 4)
     return X, y
+
+
+def synthetic_image_rec(directory, n, size=224, classes=1000, seed=0):
+    """Write a seeded synthetic indexed RecordIO of ``n`` random-noise
+    ``size`` x ``size`` JPEGs (label ``i %% classes``) as
+    ``directory/synth.rec`` + ``synth.idx``; returns ``(rec_path,
+    idx_path)``.  The image-pipeline benches and ``chip_smoke.py`` feed
+    from it in place of a dataset (zero-egress environment)."""
+    import io as _pyio
+    import os as _os
+
+    import numpy as _np
+    from PIL import Image
+
+    from . import recordio
+    rec_path = _os.path.join(directory, "synth.rec")
+    idx_path = _os.path.join(directory, "synth.idx")
+    rng = _np.random.RandomState(seed)
+    writer = recordio.MXIndexedRecordIO(idx_path, rec_path, "w")
+    buf = _pyio.BytesIO()
+    for i in range(n):
+        img = rng.randint(0, 255, (size, size, 3), _np.uint8)
+        buf.seek(0)
+        buf.truncate()
+        Image.fromarray(img).save(buf, format="JPEG", quality=90)
+        writer.write_idx(i, recordio.pack(
+            recordio.IRHeader(0, float(i % classes), i, 0), buf.getvalue()))
+    writer.close()
+    return rec_path, idx_path

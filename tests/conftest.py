@@ -21,13 +21,9 @@ if "xla_force_host_platform_device_count" not in flags:
 import jax
 
 if not _USE_TPU:
-    # A site plugin may have force-registered a hardware backend via
-    # jax.config (which outranks the env var) — pin the platform list back
-    # to CPU so the virtual 8-device mesh is what tests actually run on.
-    jax.config.update("jax_platforms", "cpu")
     assert jax.default_backend() == "cpu" and jax.device_count() == 8, (
-        "tests require the virtual 8-device CPU mesh; a site plugin initialized "
-        f"JAX first ({jax.default_backend()}, {jax.device_count()} devices)")
+        "tests require the virtual 8-device CPU mesh; JAX came up as "
+        f"{jax.default_backend()} with {jax.device_count()} devices")
 
 import numpy as np
 import pytest

@@ -778,8 +778,7 @@ def test_dst004_subf32_collective_is_error():
     # the retained widen flavor: an ALREADY-f32 operand widened to f64
     # right before the wire stays a warning (x64 scoped: jax silently
     # maps float64 to float32 otherwise)
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed3 = _step_jaxpr(lambda g: lax.psum(g.astype(jnp.float64),
                                                  "data"),
                               jnp.zeros((1024,), jnp.float32))
@@ -1182,13 +1181,15 @@ def test_tel001_detects_drift(tmp_path):
 
 def test_tel001_probe_site_scan_matches_fault_model():
     """probe_sites_used finds every shipped maybe_inject literal —
-    including the drivers outside the package (bench.py backend.init)."""
+    including the drivers outside the package (tools/train_elastic.py
+    train.step)."""
     from mxnet_tpu.analysis import probe_sites_used
     from mxnet_tpu.resilience.chaos import SITES
     used, dynamic = probe_sites_used()
     assert not dynamic
     assert set(used) == set(SITES)
-    assert any(w.startswith("bench.py:") for w in used["backend.init"])
+    assert any(w.startswith("tools/train_elastic.py:")
+               for w in used["train.step"])
 
 
 # ---------------------------------------------------------------------------

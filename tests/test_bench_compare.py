@@ -1,8 +1,9 @@
-"""tools/bench_compare.py: the BENCH_r*.json lineage as a regression
+"""tools/bench_compare.py: a BENCH_r*.json lineage as a regression
 gate (tier-1, ISSUE 10 satellite).
 
-Contract points: the shipped r01..r05 lineage passes (staleness
-protocol honored — r05's carried-forward keys set no bar); a
+Contract points: a five-record lineage in the shape the driver
+archives (two live rounds, two dead rounds with ``parsed: null``, one
+carry-forward) passes, its carried-forward keys setting no bar; a
 synthetically injected regression in a copied BENCH file exits nonzero
 and names the metric; a malformed record fails fast; the gate math
 (direction, relative vs absolute tolerance, no-prior vacuous pass) is
@@ -11,7 +12,6 @@ pinned at the function level.
 import importlib.util
 import json
 import os
-import shutil
 import subprocess
 import sys
 
@@ -29,26 +29,52 @@ def _load_tool():
 
 
 bc = _load_tool()
-_LINEAGE = sorted(
-    os.path.join(_ROOT, f) for f in os.listdir(_ROOT)
-    if f.startswith("BENCH_r") and f.endswith(".json"))
+
+_R02 = {"metric": "resnet50_train_imgs_per_sec_per_chip", "value": 2391.37,
+        "unit": "img/s/chip", "vs_baseline": 8.011,
+        "pipeline_iter_imgs_per_sec": 701.12,
+        "pipeline_fed_imgs_per_sec": 126.93, "pipeline_host_cores": 1,
+        "int8_infer_imgs_per_sec": 1546.47}
 
 
-def test_real_lineage_passes_check():
-    """The tier-1 CI wiring: the shipped bench history must gate clean
-    (a regressing or malformed BENCH file in a PR fails this test)."""
-    assert _LINEAGE, "no BENCH_r*.json lineage on disk"
+def _record(n, parsed, rc=0):
+    return {"n": n, "cmd": "bench", "rc": rc, "tail": "", "parsed": parsed}
+
+
+@pytest.fixture
+def lineage(tmp_path):
+    """r01/r02 live, r03 timed out, r04 died, r05 re-emits r02 marked
+    stale — the shapes the staleness protocol exists for."""
+    records = [
+        _record(1, {"metric": _R02["metric"], "value": 1254.31,
+                    "unit": "img/s/chip", "vs_baseline": 4.202}),
+        _record(2, _R02),
+        _record(3, None, rc=124),
+        _record(4, None, rc=1),
+        _record(5, dict(_R02, stale=True, stale_from_round=2, stale_keys=[
+            "int8_infer_imgs_per_sec", "pipeline_fed_imgs_per_sec",
+            "pipeline_host_cores", "pipeline_iter_imgs_per_sec"])),
+    ]
+    files = []
+    for rec in records:
+        path = tmp_path / ("BENCH_r%02d.json" % rec["n"])
+        path.write_text(json.dumps(rec))
+        files.append(str(path))
+    return files
+
+
+def test_lineage_passes_check(lineage):
     out = subprocess.run(
-        [sys.executable, _TOOL, "--check"] + _LINEAGE,
+        [sys.executable, _TOOL, "--check"] + lineage,
         capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "bench lineage ok" in out.stdout
 
 
-def test_staleness_protocol_sets_no_bar():
+def test_staleness_protocol_sets_no_bar(lineage):
     """r05 re-emits r02's numbers as carry-forwards (stale/stale_keys);
     they must count as neither newest-live nor best-prior."""
-    report = bc.compare(_LINEAGE)
+    report = bc.compare(lineage)
     gates = report["gates"]
     # pipeline_fed was live ONLY in r02 (r05's copy is stale) -> no bar
     assert gates["pipeline_fed_imgs_per_sec"]["verdict"] == "no-prior"
@@ -59,17 +85,13 @@ def test_staleness_protocol_sets_no_bar():
     assert report["regressions"] == [] and report["malformed"] == []
 
 
-def test_injected_regression_detected(tmp_path):
+def test_injected_regression_detected(lineage, tmp_path):
     """The acceptance criterion: copy a BENCH file, regress one gated
     metric -> exit nonzero, metric named."""
-    for f in _LINEAGE:
-        shutil.copy(f, tmp_path)
-    rec = json.load(open(os.path.join(_ROOT, "BENCH_r02.json")))
-    rec["parsed"]["pipeline_fed_imgs_per_sec"] = 50.0   # was 126.93 live
-    rec["n"] = 6
-    with open(tmp_path / "BENCH_r06.json", "w") as f:
-        json.dump(rec, f)
-    files = sorted(str(p) for p in tmp_path.glob("BENCH_r*.json"))
+    rec = _record(6, dict(_R02, pipeline_fed_imgs_per_sec=50.0))  # was 126.93
+    r06 = tmp_path / "BENCH_r06.json"
+    r06.write_text(json.dumps(rec))
+    files = lineage + [str(r06)]
     out = subprocess.run([sys.executable, _TOOL] + files,
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 2, out.stdout
@@ -77,8 +99,7 @@ def test_injected_regression_detected(tmp_path):
     assert "pipeline_fed_imgs_per_sec" in out.stdout
     # an improvement (or within-tolerance dip) stays green
     rec["parsed"]["pipeline_fed_imgs_per_sec"] = 120.0  # -5.5% < 10% tol
-    with open(tmp_path / "BENCH_r06.json", "w") as f:
-        json.dump(rec, f)
+    r06.write_text(json.dumps(rec))
     out = subprocess.run([sys.executable, _TOOL] + files,
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stdout

@@ -57,6 +57,9 @@ def test_nn_ops_tpu_vs_cpu():
     rng = np.random.RandomState(0)
     x = rng.rand(2, 8, 8, 16).astype(np.float32)
     w = (rng.rand(32, 3, 3, 16) * 0.1).astype(np.float32)  # OHWI (NHWC)
+    # drawn once: a weight drawn inside the case would differ per leg
+    # (the first two-backend run, on a v5e, caught exactly that)
+    wfc = (rng.rand(4, 8 * 8 * 16) * 0.1).astype(np.float32)
     cases = [
         (lambda a: nd.relu(a), [x]),
         (lambda a: nd.softmax(a.reshape((2, -1))), [x]),
@@ -65,10 +68,8 @@ def test_nn_ops_tpu_vs_cpu():
         (lambda a, b: nd.Convolution(
             a, b, num_filter=32, kernel=(3, 3), no_bias=True,
             layout="NHWC"), [x, w]),
-        (lambda a: nd.FullyConnected(
-            a.reshape((2, -1)),
-            nd.array(rng.rand(4, 8 * 8 * 16).astype(np.float32) * 0.1),
-            no_bias=True, num_hidden=4), [x]),
+        (lambda a, b: nd.FullyConnected(
+            a.reshape((2, -1)), b, no_bias=True, num_hidden=4), [x, wfc]),
     ]
     for fn, inputs in cases:
         # TPU matmuls default to bf16-ish precision: loose tolerance

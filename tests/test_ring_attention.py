@@ -84,11 +84,6 @@ def test_ring_grads_match(mesh4):
                                    rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax<0.5 shard_map(check_rep=False) lowers axis_index to a "
-           "PartitionId instruction the CPU SPMD partitioner rejects "
-           "under jit; the unjitted path (tests above) covers the math")
 def test_ring_under_jit(mesh4):
     q, k, v = _qkv(seed=3)
     fn = jax.jit(lambda a, b, c: ring_attention_sharded(a, b, c, mesh4))

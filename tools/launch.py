@@ -8,7 +8,12 @@ collectives).  This launcher reproduces the reference CLI:
 
 - ``launch.py -n 4 --launcher local python train.py`` spawns 4 local
   processes with JAX distributed env wired, each seeing a slice of a CPU
-  device mesh (the dist_sync_kvstore-test pattern, SURVEY.md §4).
+  device mesh (the dist_sync_kvstore-test pattern, SURVEY.md §4).  A
+  CPU-mesh tool: run it with ``JAX_PLATFORMS=cpu``.  On a TPU host every
+  one of the N children would claim every chip, and a chip belongs to
+  one process at a time — there, ONE process drives all the chips
+  through the mesh (``DataParallelTrainer(mesh=...)``, docs/distributed.md)
+  and this launcher starts one rank per *host* (``ssh``/``echo``).
 - ``launch.py -n 4 --launcher ssh -H hostfile python train.py`` drives
   the same env handshake over ssh, one rank per hostfile line
   (round-robin), mirroring the dmlc ssh tracker the reference CI

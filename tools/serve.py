@@ -407,7 +407,9 @@ def main(argv=None):
         raise SystemExit("give --model/--decode specs (a fleet), "
                          "--prefix (a checkpoint) or --demo")
 
+    from mxnet_tpu.base import use_compilation_cache
     from mxnet_tpu.serving import Server
+    use_compilation_cache()
     if args.model or args.decode:
         target = build_fleet(args)
         summary = "fleet %s" % target.models()

@@ -6,7 +6,9 @@ Two modes (docs/elastic.md):
 - **worker** (default): run ONE SPMD training job over the given rank
   set — a ``DataParallelTrainer(zero=1)`` on a ``len(ranks)``-way
   virtual CPU mesh (one host process serving K ranks, exactly how a TPU
-  pod slice runs one process per host).  Each global step, every rank's
+  pod slice runs one process per host).  The worker pins
+  ``JAX_PLATFORMS=cpu`` itself: this driver is the elastic tier's
+  CPU-mesh harness and never runs on the chip.  Each global step, every rank's
   liveness is published to the work directory (``hb-<rank>.json``)
   around its ``train.step`` chaos probe, the step trains, and a
   shard-parallel checkpoint commits every ``--checkpoint-every`` steps.
