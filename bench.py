@@ -159,13 +159,6 @@ def _run_benches(rec):
     if os.environ.get("MXTPU_BENCH_ELASTIC", "1") == "1":
         rec.stage("elastic", 150, _elastic_bench)
 
-    # -- telemetry micro-bench, host-only and BEFORE backend
-    # acquisition: the observability layer's own cost must be provable
-    # cheap — telemetry_overhead_pct (<= 1% gate), metrics_scrape_ms and
-    # flight_recorder_write_ns stay live when the TPU is down
-    if os.environ.get("MXTPU_BENCH_TELEMETRY", "1") == "1":
-        rec.stage("telemetry", 150, _telemetry_bench)
-
     # -- mlops micro-bench, host-only and BEFORE backend acquisition:
     # simulator_accuracy_pct (fleet simulator vs the real host serving
     # path, <= 15% error tolerance), promotion_decision_ms
@@ -441,28 +434,6 @@ def _overlap_bench():
         cwd=_REPO_DIR)
     if out.returncode != 0 or not out.stdout.strip():
         raise RuntimeError("overlap bench rc=%d: %s" % (
-            out.returncode, (out.stderr or out.stdout).strip()[-200:]))
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
-def _telemetry_bench():
-    """telemetry_overhead_pct (trainer step loop with the telemetry
-    layer armed vs off, interleaved min-of-N windows — the <= 1% gate),
-    metrics_scrape_ms (one Prometheus scrape over a populated registry)
-    and flight_recorder_write_ns (one mmap ring record) through
-    mxnet_tpu/telemetry/bench.py.  JAX_PLATFORMS=cpu subprocess — same
-    isolation contract as the serving/pipeline/cost/overlap/resilience
-    stages."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = _REPO_DIR + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "mxnet_tpu.telemetry.bench"],
-        capture_output=True, text=True, timeout=300, env=env,
-        cwd=_REPO_DIR)
-    if out.returncode != 0 or not out.stdout.strip():
-        raise RuntimeError("telemetry bench rc=%d: %s" % (
             out.returncode, (out.stderr or out.stdout).strip()[-200:]))
     return json.loads(out.stdout.strip().splitlines()[-1])
 

@@ -91,6 +91,16 @@ class Block:
         self._prefix, self._params = _BlockScope.create(prefix, params,
                                                         self._alias())
         self._name = self._prefix[:-1] if self._prefix.endswith("_") else self._prefix
+        # the name this block's operations carry inside a compiled
+        # program (``jax.named_scope`` in ``__call__``): its own name
+        # less the enclosing block's prefix, so that nested scopes read
+        # ``resnetv10/stage1/conv2d0``, each part once.  A block with an
+        # empty prefix adds no part, as it adds none to a parameter's name
+        outer = _BlockScope._current
+        outer = outer._block.prefix if outer is not None else ""
+        self._scope_name = "" if self._empty_prefix else (
+            self._name[len(outer):] if self._name.startswith(outer)
+            else self._name)
         self._scope = _BlockScope(self)
         self._children = {}
         self._reg_params = {}
@@ -199,7 +209,17 @@ class Block:
         return block_summary(self, *inputs)
 
     def __call__(self, *args, **kwargs):
-        return self.forward(*args, **kwargs)
+        # every operation traced below carries this block's name in its
+        # HLO metadata (``op_name``), which is what lets a device trace
+        # say which layer an operation belongs to
+        # (docs/observability.md "Program scopes").  Metadata only: it
+        # costs nothing at run time; an eager call pays the context
+        # manager, about a microsecond.  Blocks with an empty prefix are
+        # naming-transparent here as they are for parameters.
+        if not self._scope_name:
+            return self.forward(*args, **kwargs)
+        with jax.named_scope(self._scope_name):
+            return self.forward(*args, **kwargs)
 
     def forward(self, *args, **kwargs):
         raise NotImplementedError

@@ -18,6 +18,8 @@ the multi-axis tier instead — docs/transformer.md.
 from __future__ import annotations
 
 import collections
+import contextlib
+import functools
 import os
 import time
 
@@ -30,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from .. import autograd
 from .. import engine as engine_mod
 from .. import telemetry as _tele
+from ..telemetry import trace as _trace
 from ..ndarray import NDArray
 from ..resilience import chaos as _chaos
 from . import mesh as mesh_mod
@@ -51,6 +54,16 @@ _ELEMENTWISE_OPTIMIZERS = {
     "SGD", "NAG", "Signum", "FTML", "SGLD", "Adam", "AdaGrad", "RMSProp",
     "AdaDelta", "Ftrl", "Adamax", "Nadam",
 }
+
+
+# what the step's ``with span(...)`` blocks enter while telemetry is
+# disarmed: one shared no-op, so that a disarmed step builds no span, no
+# annotation and no record (docs/observability.md "Spans")
+_OFF = contextlib.nullcontext()
+
+
+def _no_span(name):
+    return _OFF
 
 
 class DataParallelTrainer:
@@ -292,6 +305,12 @@ class DataParallelTrainer:
         self._inflight = collections.deque()
         from .. import profiler as _prof
         self.dispatch_stats = _prof.PipelineStats(name="engine.dispatch")
+        # armed only: tells the enqueue's own cost from the time the
+        # runtime held the jitted call back (telemetry/attribution.py)
+        self._enqueue_split = _tele.EnqueueSplit()
+        # step.prepare enqueues small programs of its own (the learning
+        # rate, the step count, the key) and can be held back the same way
+        self._prepare_split = _tele.EnqueueSplit()
         engine_mod.register_flusher(self.flush)
 
     # -- setup -------------------------------------------------------------
@@ -411,8 +430,11 @@ class DataParallelTrainer:
                 # uint8 batch
                 x = NDArray(self._input_transform(x._data))
             out = block(x)
-            l = self._loss(out, y)
-            return l.mean() if hasattr(l, "mean") else l
+            # program scope (docs/observability.md): the loss block and
+            # its mean, under one stable name whatever the loss class
+            with jax.named_scope("loss"):
+                l = self._loss(out, y)
+                return l.mean() if hasattr(l, "mean") else l
 
         self._fwd = functionalize_forward(
             run, self._params_by_name, self._train_names, self._aux_names,
@@ -573,8 +595,8 @@ class DataParallelTrainer:
     def _zero_leaves(self):
         return tuple(jax.tree_util.tree_leaves(self._states_raw[0]))
 
-    def _zero_step(self, train_vals, aux_vals, x, y, rng, lr_host,
-                   tele_on, attr, t1):
+    def _zero_step(self, train_vals, aux_vals, x, y, rng, lr, count,
+                   attr, span):
         """ZeRO-1 split step: local grads + reduce-scatter (one jitted
         shard_map program), then shard-local update + all-gather (a
         second one).  The split mirrors ``_dist_step``'s grad→exchange→
@@ -589,38 +611,38 @@ class DataParallelTrainer:
                     self._zero_treedef, self._mesh,
                     compute_dtype=self._dtype if self._reduced else None,
                     grad_accum=self._grad_accum)
-            if tele_on:
+            if attr:
                 attr.set_context("collective_or_ps", "zero1")
                 if self._grad_accum > 1:
                     attr.set_context("dispatch", "grad_accum")
         if self._reduced:
-            g_sh, loss_val, muts, fin = self._zero_grad_fn(
+            (g_sh, loss_val, muts, fin), own = self._enqueue(
+                span, self._zero_grad_fn,
                 train_vals, aux_vals, x, y, rng, self._ls_scale)
-            if tele_on:
-                t2 = time.perf_counter()
-                attr.add_phase("dispatch", t2 - t1)
+            if attr:
+                attr.add_phase("dispatch", own)
             (new_vals, new_master, new_leaves, new_scale, new_good,
-             new_skipped) = self._zero_update_fn(
+             new_skipped), own = self._enqueue(
+                span, self._zero_update_fn,
                 train_vals, self._zero_master, self._zero_leaves(),
-                g_sh, jnp.float32(lr_host), jnp.int32(self._step_count),
+                g_sh, lr, count,
                 self._ls_scale, self._ls_good, self._ls_skipped, fin)
             self._zero_master = new_master
             self._ls_scale, self._ls_good = new_scale, new_good
             self._ls_skipped = new_skipped
         else:
-            g_sh, loss_val, muts = self._zero_grad_fn(
+            (g_sh, loss_val, muts), own = self._enqueue(
+                span, self._zero_grad_fn,
                 train_vals, aux_vals, x, y, rng)
-            if tele_on:
-                t2 = time.perf_counter()
-                attr.add_phase("dispatch", t2 - t1)
-            new_vals, new_leaves = self._zero_update_fn(
-                train_vals, self._zero_leaves(), g_sh,
-                jnp.float32(lr_host), jnp.int32(self._step_count))
+            if attr:
+                attr.add_phase("dispatch", own)
+            (new_vals, new_leaves), own = self._enqueue(
+                span, self._zero_update_fn,
+                train_vals, self._zero_leaves(), g_sh, lr, count)
         self._states_raw = [jax.tree_util.tree_unflatten(
             self._zero_treedef, list(new_leaves))]
-        if tele_on:
-            attr.add_phase("collective_or_ps",
-                           time.perf_counter() - t2)
+        if attr:
+            attr.add_phase("collective_or_ps", own)
         return loss_val, new_vals, muts
 
     def _build_zero_replica_step(self, declared_k=None):
@@ -902,47 +924,54 @@ class DataParallelTrainer:
 
     def _step_mesh_tier(self, data, label):
         """One mesh-tier training step (the ``step()`` route when a
-        MeshPlan is armed): same chaos probe, attribution phases and
-        run-ahead bookkeeping as the replicated step — grad program
+        MeshPlan is armed): same chaos probe, spans, attribution phases
+        and run-ahead bookkeeping as the replicated step — grad program
         bills ``dispatch``, update program (the ZeRO rs/ag under
         ``zero=1``) bills ``collective_or_ps``."""
-        from .. import _rng
         if not self._ready:
             self._setup_mesh(data, label)
-        tele_on = _tele._ENABLED
-        attr = _tele.attribution() if tele_on else None
-        if tele_on:
-            attr.on_step(self._step_count + 1)
+        return self._under_step_span(self._mesh_step, data, label)
+
+    def _mesh_step(self, data, label, attr, span):
+        from .. import _rng
+        in_flight = self._unfinished() if attr else 0
         batch_sh = self.batch_sharding
-        t0 = time.perf_counter() if tele_on else 0.0
-        x = self._put_batch(data, batch_sh)
-        y = self._put_batch(label, batch_sh)
-        if tele_on:
-            t1 = time.perf_counter()
-            attr.add_phase("h2d_transfer", t1 - t0)
-        else:
-            t1 = 0.0
-        self._step_count += 1
-        _chaos.maybe_inject("trainer.step", self._step_count, ctx=self)
-        self._opt.num_update = self._step_count
-        lr_host = (self._opt.lr_scheduler(self._step_count)
-                   if self._opt.lr_scheduler else self._opt.lr)
-        train_vals = tuple(self._mesh_params[n]
-                           for n in self._mesh_param_names)
-        rng = _rng.next_key()
-        grads, loss_val = self._mesh_grad_fn(train_vals, x, y, rng)
-        if tele_on:
-            t2 = time.perf_counter()
-            attr.add_phase("dispatch", t2 - t1)
-        new_vals, new_leaves = self._mesh_update_fn(
-            train_vals, self._mesh_state_leaves, grads,
-            jnp.float32(lr_host), jnp.int32(self._step_count))
-        if tele_on:
-            attr.add_phase("collective_or_ps",
-                           time.perf_counter() - t2)
-        for name, val in zip(self._mesh_param_names, new_vals):
-            self._mesh_params[name] = val
-        self._mesh_state_leaves = tuple(new_leaves)
+        sp = span("step.h2d")
+        with sp:
+            x = self._put_batch(data, batch_sh)
+            y = self._put_batch(label, batch_sh)
+        if attr:
+            attr.add_phase("h2d_transfer", sp.seconds)
+        sp = span("step.prepare")
+        with sp:
+            self._step_count += 1
+            _chaos.maybe_inject("trainer.step", self._step_count, ctx=self)
+            self._opt.num_update = self._step_count
+            lr_host = (self._opt.lr_scheduler(self._step_count)
+                       if self._opt.lr_scheduler else self._opt.lr)
+            train_vals = tuple(self._mesh_params[n]
+                               for n in self._mesh_param_names)
+            rng = _rng.next_key()
+            lr, count = self._step_scalars(lr_host)
+        if attr:
+            attr.add_phase("dispatch", self._own_cost(
+                self._prepare_split, sp.seconds, in_flight))
+        (grads, loss_val), own = self._enqueue(
+            span, self._mesh_grad_fn, train_vals, x, y, rng)
+        if attr:
+            attr.add_phase("dispatch", own)
+        (new_vals, new_leaves), own = self._enqueue(
+            span, self._mesh_update_fn,
+            train_vals, self._mesh_state_leaves, grads, lr, count)
+        if attr:
+            attr.add_phase("collective_or_ps", own)
+        sp = span("step.commit")
+        with sp:
+            for name, val in zip(self._mesh_param_names, new_vals):
+                self._mesh_params[name] = val
+            self._mesh_state_leaves = tuple(new_leaves)
+        if attr:
+            attr.add_phase("dispatch", sp.seconds)
         self._track_inflight(loss_val)
         return NDArray(loss_val)
 
@@ -1208,6 +1237,7 @@ class DataParallelTrainer:
         return dict(payload["cursor"], step=self._step_count)
 
     # -- the compiled step -------------------------------------------------
+    @jax.named_scope("optimizer_update")
     def _apply_groups(self, train_vals, states, grads, lr, t,
                       inv_scale=None, ok=None):
         """Optimizer update for every group — traced inside the step jit
@@ -1230,7 +1260,11 @@ class DataParallelTrainer:
         CHANGES.md PR 21).  The runtime builders and their analysis
         twins trace this one spelling.  A group whose parameter is
         sharded keeps the unfused spelling, which GSPMD does
-        partition."""
+        partition.
+
+        Everything traced here — the unfused XLA group, the fused Pallas
+        group, the casts between master and compute dtype — carries the
+        program scope ``optimizer_update`` (docs/observability.md)."""
         from ..ops import fused_optimizer as _fused
         from .ring_attention import _shard_map
 
@@ -1403,7 +1437,8 @@ class DataParallelTrainer:
         mean over the batch-sharded axis.  Removing this call is exactly
         the "gradient psum removed" bug class: DST001 fires per
         parameter (tests/test_analysis.py)."""
-        return tuple(jax.lax.pmean(g, self._data_axis) for g in grads)
+        with jax.named_scope("grad_reduce"):
+            return tuple(jax.lax.pmean(g, self._data_axis) for g in grads)
 
     def _build_replica_step(self):
         """Per-replica spelling of the compiled step for static analysis:
@@ -1873,7 +1908,7 @@ class DataParallelTrainer:
         return self._step_fn.lower(*self._step_args(
             train_vals, aux_vals, self._put_batch(data, batch_sh),
             self._put_batch(label, batch_sh), jax.random.PRNGKey(0),
-            self._opt.lr))
+            *self._step_scalars(self._opt.lr)))
 
     def _live_vals(self):
         """``(train_vals, aux_vals)``: the live parameter arrays in the
@@ -1883,12 +1918,19 @@ class DataParallelTrainer:
                 tuple(self._params_by_name[n].data()._data
                       for n in self._aux_names))
 
-    def _step_args(self, train_vals, aux_vals, x, y, rng, lr_host):
+    def _step_scalars(self, lr_host):
+        """``(lr, count)``: the learning rate and the step count as the
+        device scalars every tier's update program takes.  Each convert
+        enqueues a small program of its own, so ``step.prepare`` makes
+        them, not the argument list of a ``step.enqueue`` call."""
+        return jnp.float32(lr_host), jnp.int32(self._step_count)
+
+    def _step_args(self, train_vals, aux_vals, x, y, rng, lr, count):
         """Positional arguments of the replicated tier's compiled step
         (``_build_step``) — the one assembly :meth:`step` dispatches
         with and :meth:`lower_step` lowers with."""
         args = (train_vals, tuple(self._states_raw), aux_vals, x, y, rng,
-                jnp.float32(lr_host), jnp.int32(self._step_count))
+                lr, count)
         if self._reduced:
             args += (self._ls_scale, self._ls_good, self._ls_skipped)
         return args
@@ -1908,40 +1950,80 @@ class DataParallelTrainer:
             raw = np.asarray(raw)
         return jax.device_put(raw, sharding)
 
+    def _unfinished(self):
+        """How many of the ring's steps have not finished.  Steps retire
+        in order, so the unfinished ones are the newest."""
+        count = 0
+        for value in reversed(self._inflight):
+            if value.is_ready():
+                break
+            count += 1
+        return count
+
+    def _own_cost(self, split, seconds, in_flight):
+        """Of a call that enqueued programs and took ``seconds`` with
+        ``in_flight`` steps unfinished before it: the host's own part.
+        What the runtime held the call back for, because its own limit
+        of programs in flight was reached, is billed to
+        ``runahead_stall`` here (``EnqueueSplit``)."""
+        own, held = split.split(seconds, in_flight)
+        _tele.attribution().add_phase("runahead_stall", held)
+        return own
+
+    def _enqueue(self, span, fn, *args):
+        """``(fn(*args), own_s)``: one jitted program enqueued under a
+        ``step.enqueue`` span; the caller bills ``own_s``, the call's
+        time less what the runtime held it back for, to its phase.
+        Disarmed it reads 0 and nothing is counted."""
+        if span is _no_span:
+            return fn(*args), 0.0
+        in_flight = self._unfinished()
+        sp = span("step.enqueue")
+        with sp:
+            out = fn(*args)
+        return out, self._own_cost(self._enqueue_split, sp.seconds,
+                                   in_flight)
+
+    def _wait_for(self, values, span_name):
+        """Block until every one of ``values`` is ready; returns the
+        seconds it took.  Armed, the wait is a span billed to
+        ``runahead_stall``."""
+        if _tele._ENABLED:
+            sp = _trace.span(span_name, step=self._step_count)
+            with sp:
+                for value in values:
+                    value.block_until_ready()
+            _tele.attribution().add_phase("runahead_stall", sp.seconds)
+            return sp.seconds
+        t0 = time.perf_counter()
+        for value in values:
+            value.block_until_ready()
+        return time.perf_counter() - t0
+
     def _track_inflight(self, loss_val):
         """Run-ahead bookkeeping: ring the dispatched step's output and
         apply backpressure — wait on the OLDEST in-flight step when the
-        ring exceeds ``engine.bulk_size()``.  Dispatch order never
-        changes, so any window size is bitwise-identical; only where the
-        host blocks moves."""
+        ring exceeds ``engine.bulk_size()`` (span ``step.backpressure``).
+        Dispatch order never changes, so any window size is
+        bitwise-identical; only where the host blocks moves."""
         self._inflight.append(loss_val)
         limit = engine_mod.bulk_size()
         while len(self._inflight) > limit:
-            oldest = self._inflight.popleft()
-            t0 = time.perf_counter()
-            oldest.block_until_ready()
-            waited = time.perf_counter() - t0
-            self.dispatch_stats.on_backpressure(waited)
-            # sub-20us "waits" are block_until_ready call overhead on an
-            # already-finished step, not device backpressure — skipping
-            # them keeps the armed per-step cost inside the bench budget
-            if waited > 2e-5 and _tele._ENABLED:
-                _tele.attribution().add_phase("runahead_stall", waited)
+            self.dispatch_stats.on_backpressure(self._wait_for(
+                (self._inflight.popleft(),), "step.backpressure"))
         self.dispatch_stats.on_dispatch(len(self._inflight))
 
     def flush(self):
         """Drain the in-flight ring: block until every dispatched step has
-        executed.  Called by ``engine.flush()``/``bulk()`` exit and at
-        ``fit`` epoch boundaries; after it returns, params/optimizer
-        states are fully materialized (donation already retired)."""
-        t0 = time.perf_counter()
-        while self._inflight:
-            self._inflight.popleft().block_until_ready()
-        waited = time.perf_counter() - t0
-        if waited > 0:
-            self.dispatch_stats.on_backpressure(waited)
-            if _tele._ENABLED:
-                _tele.attribution().add_phase("runahead_stall", waited)
+        executed (span ``train.flush``).  Called by
+        ``engine.flush()``/``bulk()`` exit and at ``fit`` epoch
+        boundaries; after it returns, params/optimizer states are fully
+        materialized (donation already retired)."""
+        if self._inflight:
+            waiting = list(self._inflight)
+            self._inflight.clear()
+            self.dispatch_stats.on_backpressure(
+                self._wait_for(waiting, "train.flush"))
         if self._reduced and self._ready:
             # everything dispatched has retired, so the loss-scale
             # scalars are cheap to read: publish the live scale and any
@@ -1960,80 +2042,109 @@ class DataParallelTrainer:
         XLA's async queue and the loss comes back as a lazy device value —
         the host only blocks when the engine's run-ahead window
         (``mx.engine.set_bulk_size``) is full, and then on the *oldest*
-        in-flight step (backpressure), not the newest."""
-        from .. import _rng
+        in-flight step (backpressure), not the newest.
+
+        Telemetry (docs/observability.md "Spans", "Performance doctor"):
+        one flag check when it is off.  Armed, the step is a
+        ``train.step`` span with disjoint children (``step.h2d``,
+        ``step.prepare``, ``step.enqueue``, ``step.commit``,
+        ``step.backpressure``); the ``on_step`` mark closes the previous
+        step's attribution window and stores the flight-ring progress
+        cursor, and each child's duration feeds its phase."""
         if self._plan is not None:
             return self._step_mesh_tier(data, label)
         if not self._ready:
             self._setup(data, label)
+        return self._under_step_span(self._step, data, label)
 
-        # per-step attribution (docs/observability.md "Performance
-        # doctor"): the on_step mark closes the previous step's window —
-        # everything phase-timed since the last mark (backpressure,
-        # metric drains, checkpoints, the fit loop's input wait)
-        # reconciles against that window's wall clock — and stores the
-        # flight-ring progress cursor (the SIGKILLed-worker "how far did
-        # it train" field).  One bool check when telemetry is off (the
-        # <=1% bench gate).
-        tele_on = _tele._ENABLED
-        attr = _tele.attribution() if tele_on else None
-        if tele_on:
-            attr.on_step(self._step_count + 1)
+    def _under_step_span(self, body, data, label):
+        """``body(data, label, attr, span)``: disarmed with no attribution
+        and ``_no_span``; armed inside a ``train.step`` span, after the
+        ``on_step`` mark, with ``span(name)`` opening that step's
+        children."""
+        if not _tele._ENABLED:
+            return body(data, label, None, _no_span)
+        number = self._step_count + 1
+        attr = _tele.attribution()
+        attr.on_step(number)
+        with _trace.span("train.step", step=number):
+            return body(data, label, attr,
+                        functools.partial(_trace.span, step=number))
 
+    def _step(self, data, label, attr, span):
+        """The step itself.  ``attr`` is the armed ``StepAttribution`` or
+        None; ``span(name)`` opens a child span of this step, or is
+        ``_no_span``."""
+        from .. import _rng
+        in_flight = self._unfinished() if attr else 0
         batch_sh = self.batch_sharding
-        t0 = time.perf_counter() if tele_on else 0.0
-        x = self._put_batch(data, batch_sh)
-        y = self._put_batch(label, batch_sh)
-        if tele_on:
-            t1 = time.perf_counter()
-            attr.add_phase("h2d_transfer", t1 - t0)
+        sp = span("step.h2d")
+        with sp:
+            x = self._put_batch(data, batch_sh)
+            y = self._put_batch(label, batch_sh)
+        if attr:
+            attr.add_phase("h2d_transfer", sp.seconds)
 
-        self._step_count += 1
-        # chaos probe: a scheduled fault (SIGKILL at step k, injected
-        # failure, stall) fires HERE — before dispatch, so a killed step
-        # never half-applies (tests/test_resilience.py end-to-end crash)
-        _chaos.maybe_inject("trainer.step", self._step_count, ctx=self)
-        self._opt.num_update = self._step_count
-        lr_host = (self._opt.lr_scheduler(self._step_count)
-                   if self._opt.lr_scheduler else self._opt.lr)
-        train_vals, aux_vals = self._live_vals()
-        rng = _rng.next_key()
+        sp = span("step.prepare")
+        with sp:
+            self._step_count += 1
+            # chaos probe: a scheduled fault (SIGKILL at step k, injected
+            # failure, stall) fires HERE — before dispatch, so a killed
+            # step never half-applies (tests/test_resilience.py
+            # end-to-end crash)
+            _chaos.maybe_inject("trainer.step", self._step_count, ctx=self)
+            self._opt.num_update = self._step_count
+            lr_host = (self._opt.lr_scheduler(self._step_count)
+                       if self._opt.lr_scheduler else self._opt.lr)
+            train_vals, aux_vals = self._live_vals()
+            rng = _rng.next_key()
+            lr, count = self._step_scalars(lr_host)
+            if self._kv is None and not self._zero:
+                # jax.jit itself retraces and caches per input
+                # shape/dtype
+                if self._step_fn is None:
+                    self._step_fn = self._build_step()
+                    if attr and self._grad_accum > 1:
+                        attr.set_context("dispatch", "grad_accum")
+                args = self._step_args(train_vals, aux_vals, x, y, rng,
+                                       lr, count)
+        if attr:
+            # step bookkeeping (arg tuples, lr, the key) is host work; the
+            # small programs it enqueues can be held back like the step
+            attr.add_phase("dispatch", self._own_cost(
+                self._prepare_split, sp.seconds, in_flight))
 
         if self._kv is not None:
             loss_val, new_vals, new_states, muts = self._dist_step(
-                train_vals, aux_vals, x, y, rng, lr_host)
+                train_vals, aux_vals, x, y, rng, lr, count, attr, span)
             self._states_raw = list(new_states)
         elif self._zero:
             # split step: grads + reduce-scatter, then sharded update +
             # all-gather — states updated inside (they live as one
             # sharded flat tree, not per-group)
             loss_val, new_vals, muts = self._zero_step(
-                train_vals, aux_vals, x, y, rng, lr_host,
-                tele_on, attr, t1 if tele_on else 0.0)
+                train_vals, aux_vals, x, y, rng, lr, count, attr, span)
         else:
-            # jax.jit itself retraces and caches per input shape/dtype
-            if self._step_fn is None:
-                self._step_fn = self._build_step()
-                if tele_on and self._grad_accum > 1:
-                    attr.set_context("dispatch", "grad_accum")
-            out = self._step_fn(*self._step_args(
-                train_vals, aux_vals, x, y, rng, lr_host))
+            out, own = self._enqueue(span, self._step_fn, *args)
+            if attr:
+                # the jitted call's own cost is host work; what the
+                # runtime held it back for is a wait on the device
+                attr.add_phase("dispatch", own)
             if self._reduced:
                 (loss_val, new_vals, new_states, muts, self._ls_scale,
                  self._ls_good, self._ls_skipped) = out
             else:
                 loss_val, new_vals, new_states, muts = out
             self._states_raw = list(new_states)
-            if tele_on:
-                # "dispatch" spans from the batch being device-ready to
-                # the step program dispatched — step bookkeeping (arg
-                # tuples, lr) is host dispatch work and bills here
-                attr.add_phase("dispatch", time.perf_counter() - t1)
 
-        for name, val in zip(self._train_names, new_vals):
-            self._params_by_name[name]._data._set_data(val)
-        for name, val in zip(self._fwd.mut_names or (), muts):
-            self._params_by_name[name]._data._set_data(val)
+        sp = span("step.commit")
+        with sp:
+            for name, val in zip(self._train_names, new_vals):
+                self._params_by_name[name]._data._set_data(val)
+            for name, val in zip(self._fwd.mut_names or (), muts):
+                self._params_by_name[name]._data._set_data(val)
+        if attr:
+            attr.add_phase("dispatch", sp.seconds)
         self._track_inflight(loss_val)
         return NDArray(loss_val)
 
@@ -2538,7 +2649,8 @@ class DataParallelTrainer:
         except OSError:
             log.exception("metrics dump to %s failed", path)
 
-    def _dist_step(self, train_vals, aux_vals, x, y, rng, lr_host):
+    def _dist_step(self, train_vals, aux_vals, x, y, rng, lr, count, attr,
+                   span):
         """Split step for multi-process data parallelism: local grads ->
         kvstore push/pull (summed across workers by the PS sync round) ->
         average -> donated optimizer update.  Averaging the per-worker
@@ -2549,26 +2661,26 @@ class DataParallelTrainer:
         if self._grad_fn is None:
             self._grad_fn = self._build_grad_step()
             self._update_fn = self._build_update_step()
-        tele_on = _tele._ENABLED
-        attr = _tele.attribution() if tele_on else None
-        t0 = time.perf_counter() if tele_on else 0.0
-        flat, muts = self._grad_fn(train_vals, aux_vals, x, y, rng)
-        if tele_on:
-            t1 = time.perf_counter()
-            attr.add_phase("dispatch", t1 - t0)
-        self._kv.push(self._flat_key, NDArray(flat))
-        self._kv.pull(self._flat_key, out=self._flat_out)
-        if tele_on:
-            t2 = time.perf_counter()
-            attr.add_phase("collective_or_ps", t2 - t1)
+        (flat, muts), own = self._enqueue(
+            span, self._grad_fn,
+            train_vals, aux_vals, x, y, rng)
+        if attr:
+            attr.add_phase("dispatch", own)
+        sp = span("step.exchange")
+        with sp:
+            self._kv.push(self._flat_key, NDArray(flat))
+            self._kv.pull(self._flat_key, out=self._flat_out)
+        if attr:
+            attr.add_phase("collective_or_ps", sp.seconds)
         # global-batch mean loss comes back out of the update jit, so
         # every rank's callbacks see the number the single-process run
         # would (a local loss would diverge across ranks)
-        loss_val, new_vals, new_states = self._update_fn(
+        (loss_val, new_vals, new_states), own = self._enqueue(
+            span, self._update_fn,
             train_vals, tuple(self._states_raw), self._flat_out._data,
-            jnp.float32(lr_host), jnp.int32(self._step_count))
-        if tele_on:
-            attr.add_phase("dispatch", time.perf_counter() - t2)
+            lr, count)
+        if attr:
+            attr.add_phase("dispatch", own)
         return loss_val, new_vals, new_states, muts
 
     def set_learning_rate(self, lr):

@@ -175,12 +175,13 @@ def build_parts(fwd, opt, plan, state_treedef, compute_dtype=None,
             grads_sum, loss_sum, muts_stack = accumulate_grads(
                 grad_of, train_vals, x, y, n_acc)
             grads = tuple(g / n_acc for g in grads_sum)
-            flat_g = _flatten_pad(grads, plan, jnp)
-            g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
-                                    tiled=True) / k
-            loss_val = lax.pmean(loss_sum / n_acc, axis)
-            muts = tuple(lax.pmean(m.mean(axis=0), axis)
-                         for m in muts_stack)
+            with jax.named_scope("grad_reduce"):
+                flat_g = _flatten_pad(grads, plan, jnp)
+                g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
+                                        tiled=True) / k
+                loss_val = lax.pmean(loss_sum / n_acc, axis)
+                muts = tuple(lax.pmean(m.mean(axis=0), axis)
+                             for m in muts_stack)
             return g_sh, loss_val, muts
     else:
         def grads_part(train_vals, aux_vals, x, y, key):
@@ -190,16 +191,18 @@ def build_parts(fwd, opt, plan, state_treedef, compute_dtype=None,
 
             (loss_val, muts), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(train_vals)
-            flat_g = _flatten_pad(grads, plan, jnp)
             # reduce-scatter lands exactly this rank's owned gradient
             # shard; /k turns the psum semantics into the gradient mean
             # every replicated spelling uses
-            g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
-                                    tiled=True) / k
-            loss_val = lax.pmean(loss_val, axis)
-            muts = tuple(lax.pmean(m, axis) for m in muts)
+            with jax.named_scope("grad_reduce"):
+                flat_g = _flatten_pad(grads, plan, jnp)
+                g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
+                                        tiled=True) / k
+                loss_val = lax.pmean(loss_val, axis)
+                muts = tuple(lax.pmean(m, axis) for m in muts)
             return g_sh, loss_val, muts
 
+    @jax.named_scope("optimizer_update")
     def update_part(train_vals, state_leaves, g_sh, lr, t):
         from ..ops import fused_optimizer as _fused
 
@@ -268,27 +271,29 @@ def _build_parts_reduced(fwd, opt, plan, state_treedef, compute_dtype):
 
         (_, (loss_val, muts)), grads = jax.value_and_grad(
             loss_of, has_aux=True)(train_vals)
-        if _prec.PRECISION_F32_GRAD_REDUCE:
-            # cast BEFORE the collective: the ring reduction must run
-            # f32 (the tightened DST004 contract, docs/precision.md)
-            flat_g = _flatten_pad(grads, plan, jnp)
-            g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
-                                    tiled=True) / k
-        else:
-            # the seam's broken spelling (tests only): reduce in bf16
-            # and widen after — exactly what DST004 must catch
-            flat_g = _flatten_pad(grads, plan, jnp, compute_dtype)
-            g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
-                                    tiled=True).astype(jnp.float32) / k
-        # global inf/nan verdict: every rank checks its owned shard,
-        # pmin ANDs the flags (1.0 = every gradient element finite)
-        fin = lax.pmin(
-            jnp.isfinite(g_sh).all().astype(jnp.float32), axis)
-        loss_val = lax.pmean(loss_val, axis)
-        muts = tuple(lax.pmean(m.astype(jnp.float32), axis)
-                     for m in muts)
+        with jax.named_scope("grad_reduce"):
+            if _prec.PRECISION_F32_GRAD_REDUCE:
+                # cast BEFORE the collective: the ring reduction must run
+                # f32 (the tightened DST004 contract, docs/precision.md)
+                flat_g = _flatten_pad(grads, plan, jnp)
+                g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
+                                        tiled=True) / k
+            else:
+                # the seam's broken spelling (tests only): reduce in bf16
+                # and widen after — exactly what DST004 must catch
+                flat_g = _flatten_pad(grads, plan, jnp, compute_dtype)
+                g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
+                                        tiled=True).astype(jnp.float32) / k
+            # global inf/nan verdict: every rank checks its owned shard,
+            # pmin ANDs the flags (1.0 = every gradient element finite)
+            fin = lax.pmin(
+                jnp.isfinite(g_sh).all().astype(jnp.float32), axis)
+            loss_val = lax.pmean(loss_val, axis)
+            muts = tuple(lax.pmean(m.astype(jnp.float32), axis)
+                         for m in muts)
         return g_sh, loss_val, muts, fin
 
+    @jax.named_scope("optimizer_update")
     def update_part(train_vals, master_sh, state_leaves, g_sh, lr, t,
                     scale, good, skipped, fin):
         from ..ops import fused_optimizer as _fused

@@ -28,8 +28,9 @@ rank's bottleneck phase with the knob that moves it.
 
 Off by default.  The hot-path contract matches the profiler's: every
 instrumented site guards on the module-global ``_ENABLED`` bool — one
-attribute load + bool check when telemetry is off (the bench.py
-``telemetry`` stage gates the *enabled* overhead at <= 1% step time).
+attribute load + bool check when telemetry is off; what the armed path
+costs per step is measured on the chip (PERF.md).  The one always-on part
+is :mod:`.compiles`, whose listener fires only when something compiles.
 
 Arming:
 
@@ -45,24 +46,27 @@ import os
 
 from . import flight as _flight
 from . import trace
+from . import compiles
 from .flight import (FlightRecorder, postmortem, read_ring,
                      render_postmortem)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       SCHEMA_VERSION, flatten_samples, registry)
 from . import attribution as attribution_mod
-from .attribution import (PHASES, HINTS, StepAttribution,
+from .attribution import (PHASES, HINTS, StepAttribution, EnqueueSplit,
                           StragglerDetector, attribution,
                           reset_attribution, dominant_phase_or_none,
                           step_p50_or_none, doctor_report,
                           render_doctor)
 
 __all__ = ["enable", "disable", "enabled", "maybe_enable_from_env",
+           "compiles",
            "record", "cursor", "recorder", "telemetry_dir", "dump_metrics",
            "registry", "MetricsRegistry", "Counter", "Gauge", "Histogram",
            "SCHEMA_VERSION", "flatten_samples",
            "FlightRecorder", "read_ring", "postmortem",
            "render_postmortem", "trace", "fault_event",
-           "PHASES", "HINTS", "StepAttribution", "StragglerDetector",
+           "PHASES", "HINTS", "StepAttribution", "EnqueueSplit",
+           "StragglerDetector",
            "attribution", "reset_attribution", "dominant_phase_or_none",
            "step_p50_or_none", "doctor_report", "render_doctor"]
 
@@ -123,6 +127,8 @@ def enable(directory=None, rank=None, role=None, slots=None,
     # the attribution layer's on_step fuses the progress-cursor store;
     # hand it the armed ring so the trainer hot path stays one call
     attribution_mod.set_ring(_RECORDER)
+    trace.arm()
+    compiles.mark_armed()
     if old is not None:
         old.close()
     return _RECORDER
@@ -135,6 +141,7 @@ def disable():
     _ENABLED = False
     rec, _RECORDER = _RECORDER, None
     attribution_mod.set_ring(None)
+    trace.disarm()
     if rec is not None:
         rec.close()
 
@@ -207,5 +214,19 @@ def fault_event(site, at, action, ctx=None):
 
 def dump_metrics(path, source="mxnet_tpu", extra=None):
     """Write the registry's versioned JSON to ``path`` (see
-    ``metrics.SCHEMA_VERSION`` / docs/observability.md)."""
-    return registry().dump_json(path, source=source, extra=extra)
+    ``metrics.SCHEMA_VERSION`` / docs/observability.md), with the compile
+    counters (``compiles``) and the buffered spans (``spans``: the field
+    names, the events, how many were dropped) beside it: the end of a run
+    is where the span buffer is written out, never the hot path.  The
+    doctor reads both back (``attribution.doctor_report``)."""
+    payload = {"compiles": compiles.counters(),
+               "spans": {"fields": list(trace.SPAN_FIELDS),
+                         "clock": "perf_counter_ns",
+                         "dropped": trace.dropped_spans(),
+                         "events": trace.spans()}}
+    payload.update(extra or {})
+    return registry().dump_json(path, source=source, extra=payload)
+
+
+# always on: the listener fires only when something compiles
+compiles.install()

@@ -24,8 +24,7 @@ performance debuggable; this module is that tool for this stack:
   accumulators exported by a collector (totals, true per-step
   p50/p99 over a bounded window) and per-phase registry *histograms*
   observed once per flight window (per-step means) — the hot path never
-  touches a registry instrument, which is what keeps the bench's
-  ``telemetry_overhead_pct`` gate (<= 1% step time) green.
+  touches a registry instrument.
 - every ``ring_every`` steps the aggregated window is flight-recorded
   (``perf.phases``) so attribution survives a SIGKILL: a dead rank's
   ring still says where its time went.
@@ -61,6 +60,7 @@ import time
 from collections import deque
 
 __all__ = ["PHASES", "HINTS", "CONTEXT_HINTS", "StepAttribution",
+           "EnqueueSplit",
            "StragglerDetector", "attribution", "reset_attribution",
            "dominant_phase_or_none", "step_p50_or_none",
            "doctor_report", "render_doctor"]
@@ -72,8 +72,8 @@ __all__ = ["PHASES", "HINTS", "CONTEXT_HINTS", "StepAttribution",
 PHASES = (
     "input_wait",        # training loop blocked waiting for the next batch
     "h2d_transfer",      # device_put of the batch inside step()
-    "dispatch",          # host-side dispatch of the jitted step program(s)
-    "runahead_stall",    # backpressure: waiting on the oldest in-flight step
+    "dispatch",          # host work only: prepare + own enqueue cost + commit
+    "runahead_stall",    # blocked on the device: ring wait + held-back enqueue
     "collective_or_ps",  # cross-worker kvstore push/pull round
     "metric_drain",      # lazy-metric updates + batch-end callback fetches
     "checkpoint",        # snapshot encode + atomic write (post-flush)
@@ -88,9 +88,13 @@ HINTS = {
     "h2d_transfer": "batch transfers are not overlapped: raise "
                     "prefetch_buffer / feed through PrefetchToDeviceIter "
                     "so the put rides the prefetch thread",
-    "dispatch": "host-side per-step dispatch work dominates: widen "
-                "bulk_size (engine run-ahead) so dispatch overlaps "
-                "device compute, and check SRC004 for per-step syncs",
+    "dispatch": "host-side per-step work dominates (preparing the "
+                "arguments, enqueuing the step, committing its outputs; "
+                "waits on the device are NOT in this phase): cut the "
+                "per-step host work — fewer small device programs per "
+                "step, fewer parameters to commit — and check SRC004 for "
+                "per-step syncs; widening bulk_size only helps while "
+                "runahead_stall is zero",
     "runahead_stall": "the device is the bottleneck (in-flight ring full "
                       "at bulk_size): widening bulk_size will NOT help — "
                       "make the step itself cheaper (batch/precision) or "
@@ -193,8 +197,7 @@ class StepAttribution:
             "MXTPU_ANOMALY_FACTOR", "4.0"))
         self.warmup = int(warmup)
         # open window: SPARSE phase dict — only touched phases have keys.
-        # The per-step hot path is deliberately tiny (the bench's <=1%
-        # overhead gate is the budget): on_step appends one
+        # The per-step hot path is deliberately tiny: on_step appends one
         # (step, wall, phases) tuple to a pending list and add_phase is
         # a GIL-atomic dict add (single writer: the training thread);
         # ALL aggregation — totals, EWMA, flight windows, histograms —
@@ -371,7 +374,7 @@ class StepAttribution:
                 self._anomalies += 1
                 # emission cooldown is step- AND time-based: on fast
                 # noisy steps an anomaly storm must not bill ring-write
-                # time to the armed arm of the overhead bench
+                # time to the armed step
                 t_now = self._now()
                 if (self._last_anomaly_step is None
                         or step - self._last_anomaly_step >= 10) and \
@@ -543,6 +546,60 @@ class StepAttribution:
         for p, v in snap["phases_s"].items():
             out.append(("mxtpu_step_phase_seconds_total", {"phase": p}, v))
         return out
+
+
+class EnqueueSplit:
+    """Splits the wall time of a call that enqueues device programs into
+    the host's own cost and the time the runtime held the call back.
+
+    The runtime keeps its own limit of programs in flight, below the
+    engine's run-ahead ring; a call beyond it returns only when a step
+    retires, a whole step later, and that wait sits *inside* the call
+    (PERF.md, Findings of PR 25 and 26).  The program cannot see the
+    wait, but it can see what surrounds it:
+
+    - ``in_flight``: how many of the ring's steps had not finished when
+      the call was made.  With none in flight nothing can hold the call
+      back: its whole time is the host's, and is a sample of the call's
+      own cost whatever it took;
+    - any other call is held back if it takes more than ``FACTOR`` times
+      the median of the last ``KEEP`` samples plus ``SLACK_S``: the host
+      is then billed that median and the wait the rest, and the call is
+      no sample.  One that takes no longer than that was not held back
+      and is a sample too, so the estimate follows the host in a steady
+      run whose ring never drains.
+
+    Until a call has been made with nothing in flight there is no sample
+    to hold a call against, and calls are billed whole to the host.
+    The three numbers were checked on the chip against a run in which
+    nothing can be held back (``benchmark/host_control.py``), on two
+    device-bound cells.  Stdlib-only."""
+
+    KEEP, FACTOR, SLACK_S = 32, 2.0, 3e-4
+
+    def __init__(self):
+        self._samples = deque(maxlen=self.KEEP)
+
+    def own_cost_s(self):
+        """The call's own cost as the samples have it (their median), or
+        None before the first."""
+        if not self._samples:
+            return None
+        ranked = sorted(self._samples)
+        return ranked[len(ranked) // 2]
+
+    def split(self, seconds, in_flight):
+        """``(own_s, blocked_s)`` of one call that took ``seconds`` with
+        ``in_flight`` steps unfinished before it."""
+        own = self.own_cost_s()
+        if in_flight == 0 or (
+                own is not None
+                and seconds <= self.FACTOR * own + self.SLACK_S):
+            self._samples.append(seconds)
+            return seconds, 0.0
+        if own is None:
+            return seconds, 0.0
+        return own, seconds - own
 
 
 _ATTR = None
@@ -766,6 +823,25 @@ def _rank_label(meta):
     return "%s%s" % (role, "" if rank is None else rank)
 
 
+def _span_summary(spans):
+    """``{"steps", "dropped", "seconds": {name: total}}`` of the span
+    buffer as ``telemetry.dump_metrics`` wrote it (``trace.SPAN_FIELDS``
+    tuples), or None where the dump has no spans."""
+    events = spans.get("events") or []
+    fields = spans.get("fields") or []
+    if not events or not {"name", "start_ns", "end_ns"} <= set(fields):
+        return None
+    name, start, end = (fields.index(f)
+                        for f in ("name", "start_ns", "end_ns"))
+    seconds = {}
+    for ev in events:
+        seconds[ev[name]] = seconds.get(ev[name], 0.0) \
+            + (ev[end] - ev[start]) / 1e9
+    return {"steps": sum(1 for ev in events if ev[name] == "train.step"),
+            "dropped": int(spans.get("dropped") or 0),
+            "seconds": seconds}
+
+
 def doctor_report(directory, factor=None):
     """Read a fleet's telemetry directory (metrics dumps + flight rings)
     and diagnose: per rank, the bottleneck phase + hint; fleet-wide, the
@@ -776,7 +852,10 @@ def doctor_report(directory, factor=None):
     Sources, in preference order per rank: the ``attribution`` snapshot
     embedded in the metrics JSON (a clean exit), else the ``perf.phases``
     windows recovered from the rank's flight ring (a SIGKILLed rank
-    still gets a verdict — that is the point of ring attribution)."""
+    still gets a verdict — that is the point of ring attribution).  The
+    metrics JSON's span buffer says which spans the step's host time is
+    made of (``dispatch`` is three of them), its compile counters whether
+    steps compiled."""
     from .flight import RING_SUFFIX, read_ring
     factor = float(factor or os.environ.get("MXTPU_STRAGGLER_FACTOR",
                                             "2.0"))
@@ -805,6 +884,11 @@ def doctor_report(directory, factor=None):
             anomalies=attr.get("anomalies", 0),
             context=dict(attr.get("context") or {}),
         )
+        summary = _span_summary(doc.get("spans") or {})
+        if summary and summary["steps"]:
+            rec["spans"] = summary
+        if doc.get("compiles"):
+            rec["compiles"] = dict(doc["compiles"])
         rec["source"].append(os.path.basename(path))
     for path in sorted(_glob.glob(os.path.join(str(directory),
                                                "*" + RING_SUFFIX))):
@@ -924,6 +1008,30 @@ def render_doctor(report):
                          % (rec["dominant_phase"],
                             100.0 * rec.get("dominant_share", 0.0),
                             rec["hint"]))
+        spans = rec.get("spans")
+        if spans:
+            per_step = sorted(
+                ((v / spans["steps"], k)
+                 for k, v in spans["seconds"].items() if k != "train.step"),
+                reverse=True)
+            lines.append("   spans, ms per step over the last %d steps%s: %s"
+                         % (spans["steps"],
+                            " (%d older spans dropped)" % spans["dropped"]
+                            if spans["dropped"] else "",
+                            ", ".join("%s %.3f" % (k, 1e3 * v)
+                                      for v, k in per_step)))
+        compiled = rec.get("compiles") or {}
+        if compiled.get("in_span_programs"):
+            lines.append(
+                "   %d program(s) compiled inside training steps (the "
+                "process spent %.1f s tracing and lowering, %.1f s in the "
+                "backend's compiler or cache): past each shape's first "
+                "step that is recompilation -- a shape or a static "
+                "argument changes between steps"
+                % (compiled["in_span_programs"],
+                   compiled.get("trace_s", 0.0)
+                   + compiled.get("lower_s", 0.0),
+                   compiled.get("backend_s", 0.0)))
         if rec.get("anomalies"):
             lines.append("   %d step-time anomaly event(s) flagged"
                          % rec["anomalies"])

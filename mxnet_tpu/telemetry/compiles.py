@@ -1,0 +1,109 @@
+"""Compile counters: what tracing, lowering and compiling cost this process.
+
+One ``jax.monitoring`` duration listener, registered when
+``mxnet_tpu.telemetry`` is imported, accumulates per process
+(docs/observability.md "Compile counters"):
+
+- ``trace_s``: seconds tracing Python to a jaxpr
+  (``/jax/core/compile/jaxpr_trace_duration``).  JAX reports a function
+  traced inside another one as well as the outer one; a report that ended
+  inside a later report's interval is taken out of the sum, so the total
+  is the union;
+- ``lower_s``: seconds lowering a jaxpr to an MLIR module
+  (``.../jaxpr_to_mlir_module_duration``);
+- ``backend_s``: seconds in the backend's compiler **or**, on a hit of the
+  persistent cache, retrieving the executable
+  (``.../backend_compile_duration``; JAX reports both under it);
+- ``in_span_programs``: executables compiled or loaded (reports of the
+  last) while a telemetry span was open on the compiling thread, i.e.
+  inside ``train.step``: a steady training loop makes none.
+
+Always on: the listener fires only when something is traced, lowered or
+compiled, which a steady step never does.  ``telemetry.enable()`` calls
+:func:`mark_armed`, so "during set-up" is :func:`at_armed` and "since
+armed" is :func:`since_armed`, a subtraction.
+"""
+from __future__ import annotations
+
+import threading
+import time
+
+from . import trace as _trace
+
+__all__ = ["counters", "mark_armed", "at_armed", "since_armed", "install"]
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_SUMS = ("trace_s", "lower_s", "backend_s", "in_span_programs")
+
+_lock = threading.Lock()
+_totals = dict.fromkeys(_SUMS, 0)
+# (end, seconds) of the trace reports not yet found inside a later one
+_open_traces = []
+_armed_at = None
+_installed = False
+
+
+def _on_duration(event, seconds, **_):
+    if event == TRACE_EVENT:
+        end = time.perf_counter()
+        with _lock:
+            inside = [t for t in _open_traces if t[0] >= end - seconds]
+            _totals["trace_s"] += seconds - sum(t[1] for t in inside)
+            del _open_traces[len(_open_traces) - len(inside):]
+            _open_traces.append((end, seconds))
+            if len(_open_traces) > 64:
+                del _open_traces[:32]
+    elif event == LOWER_EVENT:
+        with _lock:
+            _totals["lower_s"] += seconds
+    elif event == BACKEND_EVENT:
+        in_span = _trace.current() is not None
+        with _lock:
+            _totals["backend_s"] += seconds
+            _totals["in_span_programs"] += in_span
+
+
+def install():
+    """Register the listener, once.  False where this process has no
+    jax (a postmortem host): the counters then stay at nought."""
+    global _installed
+    if _installed:
+        return True
+    try:
+        from jax import monitoring
+    except ImportError:
+        return False
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    _installed = True
+    return True
+
+
+def counters():
+    """The totals since the process began."""
+    with _lock:
+        return dict(_totals)
+
+
+def mark_armed():
+    global _armed_at
+    snapshot = counters()
+    with _lock:
+        _armed_at = snapshot
+
+
+def at_armed():
+    """The totals as they stood when telemetry was last armed (what
+    set-up cost), or None if it never was."""
+    with _lock:
+        return None if _armed_at is None else dict(_armed_at)
+
+
+def since_armed():
+    """The sums accumulated since telemetry was last armed, or None."""
+    then = at_armed()
+    if then is None:
+        return None
+    now = counters()
+    return {k: now[k] - then[k] for k in now}

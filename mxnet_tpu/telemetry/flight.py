@@ -28,10 +28,9 @@ The header also carries a **progress cursor** (``cursor_step`` /
 ``cursor_ts_ns``): a fixed-size struct-packed store updated by
 :meth:`FlightRecorder.set_cursor` with no JSON, no allocation and no
 slot consumed — cheap enough for a *per-training-step* probe on the
-trainer's dispatch path (the bench gates the whole enabled path at
-<= 1% step time; a full ``record()`` per step measurably is not, on a
-1-core host where host python competes with XLA compute).  A SIGKILLed
-worker's ring thus answers "how far did it train" exactly.
+trainer's dispatch path (a full ``record()`` per step measurably is
+not, on a 1-core host where host python competes with XLA compute).  A
+SIGKILLed worker's ring thus answers "how far did it train" exactly.
 
 :func:`postmortem` reconstructs the last-N-events-per-rank story of a
 dead fleet from a directory of rings — the ``python -m mxnet_tpu.telemetry

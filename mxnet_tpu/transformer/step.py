@@ -140,6 +140,12 @@ def build_parts(program, apply_update, state_leaf_counts, zero=0,
         else:
             loss, grads = jax.value_and_grad(program.loss_replica)(
                 tuple(train_vals), x, y, key)
+        # the collectives the program spells itself carry one scope
+        # (docs/observability.md "Program scopes")
+        with jax.named_scope("grad_reduce"):
+            return _reduce(grads, loss)
+
+    def _reduce(grads, loss):
         if plan.present("pipe"):
             # the ONE pipe-axis exchange: complete the pipe-replicated
             # params' partial grads; stage-local blk_* grads pass
@@ -167,6 +173,7 @@ def build_parts(program, apply_update, state_leaf_counts, zero=0,
             grads = tuple(lax.pmean(g, batch_axes) for g in grads)
         return tuple(grads), loss
 
+    @jax.named_scope("optimizer_update")
     def update_part(train_vals, state_leaves, grads, lr, t):
         if zero:
             flat_w = _flatten_pad(train_vals, zero_plan, jnp)

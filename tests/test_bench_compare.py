@@ -209,21 +209,21 @@ def test_gate_math_directions(tmp_path):
                 "parsed": parsed}
     a = tmp_path / "BENCH_r01.json"
     b = tmp_path / "BENCH_r02.json"
-    a.write_text(json.dumps(rec(1, {"telemetry_overhead_pct": 0.5,
+    a.write_text(json.dumps(rec(1, {"checkpoint_overhead_pct": 0.5,
                                     "serving_reqs_per_sec": 100.0})))
-    # overhead 0.5 -> 0.9 is within +0.5 abs slack; reqs/s -15% is not
-    b.write_text(json.dumps(rec(2, {"telemetry_overhead_pct": 0.9,
+    # overhead 0.5 -> 2.0 is within +2.0 abs slack; reqs/s -15% is not
+    b.write_text(json.dumps(rec(2, {"checkpoint_overhead_pct": 2.0,
                                     "serving_reqs_per_sec": 85.0})))
     report = bc.compare([str(a), str(b)])
-    assert report["gates"]["telemetry_overhead_pct"]["verdict"] == "ok"
+    assert report["gates"]["checkpoint_overhead_pct"]["verdict"] == "ok"
     assert report["gates"]["serving_reqs_per_sec"]["verdict"] == \
         "regression"
     assert report["regressions"] == ["serving_reqs_per_sec"]
     # overhead past the absolute slack regresses
-    b.write_text(json.dumps(rec(2, {"telemetry_overhead_pct": 1.2,
+    b.write_text(json.dumps(rec(2, {"checkpoint_overhead_pct": 3.5,
                                     "serving_reqs_per_sec": 100.0})))
     report = bc.compare([str(a), str(b)])
-    assert report["regressions"] == ["telemetry_overhead_pct"]
+    assert report["regressions"] == ["checkpoint_overhead_pct"]
     # --tolerance-scale widens every gate
     report = bc.compare([str(a), str(b)], tolerance_scale=2.0)
     assert report["regressions"] == []

@@ -652,21 +652,6 @@ def test_trainer_fit_dumps_versioned_metrics_json(tmp_path):
     assert out.returncode == 0 and "mxtpu_pipeline" in out.stdout
 
 
-def test_telemetry_bench_keys():
-    env = _cpu_env(MXTPU_TELE_BENCH_STEPS=40)
-    out = subprocess.run(
-        [sys.executable, "-m", "mxnet_tpu.telemetry.bench"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["flight_recorder_write_ns"] > 0
-    assert rec["metrics_scrape_ms"] > 0
-    assert isinstance(rec["telemetry_overhead_gate_ok"], bool)
-    # the <= 1% gate is asserted on the full-length bench run; at the
-    # test's reduced step count only sanity-bound the number
-    assert rec["telemetry_overhead_pct"] < 10.0
-
-
 # ---------------------------------------------------------------------------
 # (f) the headline: 2 workers + 1 server, chaos SIGKILL of the server
 # ---------------------------------------------------------------------------
