@@ -3,8 +3,11 @@ from __future__ import annotations
 
 import numpy as np
 
+from ... import autograd
 from ... import ndarray as nd
-from ..block import Block, HybridBlock
+from ...telemetry import compiles
+from ..block import Block, HybridBlock, _in_cached_trace
+from .conv_layers import _Conv
 
 
 class Sequential(Block):
@@ -46,8 +49,20 @@ class HybridSequential(HybridBlock):
             self.register_child(block)
 
     def hybrid_forward(self, F, x):
-        for block in self._children.values():
-            x = block(x)
+        # A child's output goes to the next child and nowhere else: the
+        # one place where "this convolution feeds only a BatchNorm" can be
+        # seen.  There the bias gradient is zero by algebra (the batch
+        # mean takes any per-channel constant out again), so the
+        # convolution is told not to compute it; the forward is untouched.
+        blocks = list(self._children.values())
+        training = autograd.is_training()
+        for block, consumer in zip(blocks, blocks[1:] + [None]):
+            if training and _bias_grad_is_zero(block, consumer):
+                if _in_cached_trace():
+                    compiles.count_blocked_bias_grad()
+                x = block(x, block_bias_grad=True)
+            else:
+                x = block(x)
         return x
 
     def __len__(self):
@@ -63,6 +78,23 @@ class HybridSequential(HybridBlock):
 
     def __iter__(self):
         return iter(self._children.values())
+
+
+def _bias_grad_is_zero(conv, consumer):
+    """True where ``conv`` adds a bias per channel as the last thing it
+    does and ``consumer`` is a BatchNorm that, in training mode, subtracts
+    the batch mean over every other axis: ``d loss / d bias`` is then
+    exactly zero.  Read from the two layers, nothing else; a subclass that
+    brings its own forward is not known to be either."""
+    if not (isinstance(conv, _Conv) and type(consumer) is BatchNorm
+            and type(conv).hybrid_forward is _Conv.hybrid_forward):
+        return False
+    if conv._op_name != "Convolution" or conv.bias is None \
+            or conv.act is not None or consumer._use_global_stats:
+        return False
+    ndim = len(conv._kernel) + 2
+    channel_axis = ndim - 1 if conv._channels_last else 1
+    return consumer._axis % ndim == channel_axis
 
 
 class Dense(HybridBlock):

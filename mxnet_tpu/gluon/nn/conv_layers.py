@@ -65,8 +65,15 @@ class _Conv(HybridBlock):
             else:
                 self.weight.shape = (self._channels, in_c // g) + tuple(self._kernel)
 
-    def hybrid_forward(self, F, x, weight, bias=None):
+    def hybrid_forward(self, F, x, weight, bias=None, block_bias_grad=False):
+        """``block_bias_grad`` is the container's to pass
+        (``HybridSequential``), never a user's: it says that whatever
+        consumes this output is unchanged by a per-channel shift, so the
+        bias's gradient is zero and need not be computed.  The bias still
+        enters the forward value."""
         op = getattr(F, self._op_name)
+        if block_bias_grad:
+            bias = F.BlockGrad(bias)
         out = op(x, weight, bias, **self._kwargs)
         if self.act is not None:
             out = self.act(out)

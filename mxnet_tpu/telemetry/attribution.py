@@ -1032,6 +1032,11 @@ def render_doctor(report):
                    compiled.get("trace_s", 0.0)
                    + compiled.get("lower_s", 0.0),
                    compiled.get("backend_s", 0.0)))
+        if compiled.get("blocked_bias_grads"):
+            lines.append(
+                "   %d convolution-bias gradient(s) left out of the traced "
+                "programs: zero behind a training-mode BatchNorm"
+                % compiled["blocked_bias_grads"])
         if rec.get("anomalies"):
             lines.append("   %d step-time anomaly event(s) flagged"
                          % rec["anomalies"])
