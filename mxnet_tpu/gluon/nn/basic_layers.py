@@ -59,7 +59,7 @@ class HybridSequential(HybridBlock):
         for block, consumer in zip(blocks, blocks[1:] + [None]):
             if training and _bias_grad_is_zero(block, consumer):
                 if _in_cached_trace():
-                    compiles.count_blocked_bias_grad()
+                    compiles.count("blocked_bias_grads")
                 x = block(x, block_bias_grad=True)
             else:
                 x = block(x)

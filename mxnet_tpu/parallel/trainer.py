@@ -802,8 +802,8 @@ class DataParallelTrainer:
     def _setup_mesh(self, data, label):
         """Materialize the mesh tier: params placed per the program's
         PartitionSpecs, optimizer state per-param (or ZeRO-1 flat over
-        ``model × data`` under ``zero=1``), the two jitted ``shard_map``
-        programs built from the ONE per-replica spelling
+        ``model × data`` under ``zero=1``), the jitted ``shard_map``
+        program built from the ONE per-replica spelling
         (``transformer/step.py``)."""
         from ..transformer import step as _tstep
         self._ensure_mesh()
@@ -888,46 +888,18 @@ class DataParallelTrainer:
             self._mesh_state_leaves = tuple(state_leaves)
 
         apply_update = self._mesh_apply_update(self._mesh_state_treedefs)
-        self._mesh_grad_fn, self._mesh_update_fn = \
-            _tstep.build_runtime_fns(
-                program, apply_update, self._mesh_leaf_counts, mesh,
-                self._mesh_state_specs, zero=self._zero,
-                zero_plan=self._mesh_zero_plan,
-                compute_dtype=self._dtype if self._reduced else None)
-        if _tele._ENABLED:
-            _tele.attribution().set_context("collective_or_ps",
-                                            self._mesh_context_tag())
+        self._mesh_step_fn = _tstep.build_runtime_fn(
+            program, apply_update, self._mesh_leaf_counts, mesh,
+            self._mesh_state_specs, zero=self._zero,
+            zero_plan=self._mesh_zero_plan,
+            compute_dtype=self._dtype if self._reduced else None)
         self._ready = True
-
-    def _mesh_context_tag(self):
-        """Which mesh axis the doctor should name when collective time
-        dominates: the axis carrying more MODELED wire bytes in the
-        step's priced schedule (docs/transformer.md; the CONTEXT_HINTS
-        entries in telemetry/attribution.py)."""
-        plan = self._plan
-        tags = {"model": "tp_model", "sequence": "tp_sequence",
-                "pipe": "pp_pipeline"}
-        armed = [a for a in ("model", "sequence", "pipe")
-                 if plan.present(a)]
-        if len(armed) == 1:
-            return tags[armed[0]]
-        try:
-            desc = self._setup_desc["data"][0]
-            _, _, shard = self.mesh_report(
-                data_shape=tuple(desc), declared_plan=plan)
-            per_axis = shard.collective_bytes_per_axis
-            best = max(armed or ["model"],
-                       key=lambda a: per_axis.get(a, 0))
-            return tags[best]
-        except Exception:
-            return "tp_model"
 
     def _step_mesh_tier(self, data, label):
         """One mesh-tier training step (the ``step()`` route when a
         MeshPlan is armed): same chaos probe, spans, attribution phases
-        and run-ahead bookkeeping as the replicated step — grad program
-        bills ``dispatch``, update program (the ZeRO rs/ag under
-        ``zero=1``) bills ``collective_or_ps``."""
+        and run-ahead bookkeeping as the replicated step: one program
+        a step, billed to ``dispatch``."""
         if not self._ready:
             self._setup_mesh(data, label)
         return self._under_step_span(self._mesh_step, data, label)
@@ -956,15 +928,11 @@ class DataParallelTrainer:
         if attr:
             attr.add_phase("dispatch", self._own_cost(
                 self._prepare_split, sp.seconds, in_flight))
-        (grads, loss_val), own = self._enqueue(
-            span, self._mesh_grad_fn, train_vals, x, y, rng)
+        (loss_val, new_vals, new_leaves), own = self._enqueue(
+            span, self._mesh_step_fn, train_vals, self._mesh_state_leaves,
+            x, y, rng, lr, count)
         if attr:
             attr.add_phase("dispatch", own)
-        (new_vals, new_leaves), own = self._enqueue(
-            span, self._mesh_update_fn,
-            train_vals, self._mesh_state_leaves, grads, lr, count)
-        if attr:
-            attr.add_phase("collective_or_ps", own)
         sp = span("step.commit")
         with sp:
             for name, val in zip(self._mesh_param_names, new_vals):
@@ -2024,10 +1992,11 @@ class DataParallelTrainer:
             self._inflight.clear()
             self.dispatch_stats.on_backpressure(
                 self._wait_for(waiting, "train.flush"))
-        if self._reduced and self._ready:
+        if self._reduced and self._ready and self._plan is None:
             # everything dispatched has retired, so the loss-scale
             # scalars are cheap to read: publish the live scale and any
-            # newly-skipped steps (docs/observability.md)
+            # newly-skipped steps (docs/observability.md).  The mesh
+            # tier scales no loss (transformer/step.py) and keeps none
             from .. import precision as _precision
             skipped = int(self._ls_skipped)
             _precision.record_loss_scale(

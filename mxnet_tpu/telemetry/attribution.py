@@ -119,22 +119,6 @@ CONTEXT_HINTS = {
         "all-gather program is the bottleneck — grow the per-replica "
         "batch so compute amortizes the gather, or drop zero=1 if the "
         "optimizer state fits replicated (docs/elastic.md)",
-    ("collective_or_ps", "tp_model"):
-        "the model-axis (tensor-parallel) collectives dominate the "
-        "mesh step's modeled schedule: lower model_parallel, or grow "
-        "d_model/per-replica batch so the matmuls amortize the "
-        "row-parallel psums (docs/transformer.md)",
-    ("collective_or_ps", "tp_sequence"):
-        "the sequence-axis collectives dominate the mesh step's "
-        "modeled schedule: switch attention='ulysses' when local "
-        "heads divide the sequence axis (2 all_to_alls vs a K-hop "
-        "ppermute ring), or lower sequence_parallel "
-        "(docs/transformer.md)",
-    ("collective_or_ps", "pp_pipeline"):
-        "the pipe-axis activation ppermutes dominate the mesh step's "
-        "modeled schedule: raise microbatches so compute amortizes "
-        "the per-tick hop (and shrinks the (K-1)/(K-1+M) bubble), or "
-        "lower pipeline stages (docs/pipeline.md)",
     ("dispatch", "grad_accum"):
         "the step runs grad_accum microbatches back-to-back before "
         "its one optimizer update: lower grad_accum if HBM allows the "
@@ -1037,6 +1021,14 @@ def render_doctor(report):
                 "   %d convolution-bias gradient(s) left out of the traced "
                 "programs: zero behind a training-mode BatchNorm"
                 % compiled["blocked_bias_grads"])
+        if compiled.get("ssm_layers"):
+            lines.append(
+                "   %d Mamba-2 layer(s) in the traced programs, the scan in "
+                "%d chunk(s) a sequence; %d layer(s) recomputed in the "
+                "backward pass"
+                % (compiled["ssm_layers"],
+                   compiled.get("ssm_chunks_per_seq", 0),
+                   compiled.get("recomputed_layers", 0)))
         if rec.get("anomalies"):
             lines.append("   %d step-time anomaly event(s) flagged"
                          % rec["anomalies"])

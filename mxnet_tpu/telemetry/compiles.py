@@ -22,7 +22,13 @@ One ``jax.monitoring`` duration listener, registered when
   BatchNorm, which makes the gradient identically zero
   (``gluon/nn/basic_layers.py::HybridSequential``).  Counted by the
   container while a program is traced, once per bias and trace: 32 for
-  one trace of a ``resnet50_v1`` training step.
+  one trace of a ``resnet50_v1`` training step;
+- ``ssm_layers``, ``recomputed_layers``: Mamba-2 layers, and layers whose
+  forward the backward pass recomputes, in the programs traced so far
+  (``transformer/hybrid.py``, once per layer and trace: 9 and 10 for one
+  trace of the ten-layer ``granite-4.0-h-micro`` step);
+- ``ssm_chunks_per_seq``: chunks the state-space scan of the last traced
+  program cuts a sequence into (no sum: the newest value).
 
 Always on: the listener fires only when something is traced, lowered or
 compiled, which a steady step never does.  ``telemetry.enable()`` calls
@@ -37,16 +43,17 @@ import time
 from . import trace as _trace
 
 __all__ = ["counters", "mark_armed", "at_armed", "since_armed", "install",
-           "count_blocked_bias_grad"]
+           "count", "note"]
 
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
-_SUMS = ("trace_s", "lower_s", "backend_s", "in_span_programs",
-         "blocked_bias_grads")
+_NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
+         "blocked_bias_grads", "ssm_layers", "recomputed_layers",
+         "ssm_chunks_per_seq")
 
 _lock = threading.Lock()
-_totals = dict.fromkeys(_SUMS, 0)
+_totals = dict.fromkeys(_NAMES, 0)
 # (end, seconds) of the trace reports not yet found inside a later one
 _open_traces = []
 _armed_at = None
@@ -73,9 +80,17 @@ def _on_duration(event, seconds, **_):
             _totals["in_span_programs"] += in_span
 
 
-def count_blocked_bias_grad():
+def count(name, by=1):
+    """Add ``by`` to the counter ``name``: called while a program is
+    traced, by the code that shapes it."""
     with _lock:
-        _totals["blocked_bias_grads"] += 1
+        _totals[name] += by
+
+
+def note(name, value):
+    """Set ``name`` to ``value``: a reading of the newest traced program."""
+    with _lock:
+        _totals[name] = value
 
 
 def install():

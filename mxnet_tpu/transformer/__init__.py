@@ -24,7 +24,9 @@ fixture — see ``analysis/budget_models.py`` and
 ``trainer.mesh_report()``.
 """
 from .model import TransformerLM, TransformerLMConfig, MeshProgram
-from . import layers, step
+from .hybrid import HybridLM, HybridLMConfig, HybridProgram
+from . import layers, ssm, step
 
 __all__ = ["TransformerLM", "TransformerLMConfig", "MeshProgram",
-           "layers", "step"]
+           "HybridLM", "HybridLMConfig", "HybridProgram",
+           "layers", "ssm", "step"]

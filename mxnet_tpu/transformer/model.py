@@ -28,7 +28,8 @@ import numpy as _np
 
 import jax
 
-__all__ = ["TransformerLMConfig", "TransformerLM", "MeshProgram"]
+__all__ = ["TransformerLMConfig", "TransformerLM", "MeshProgram",
+           "ProgramLayout"]
 
 
 class TransformerLMConfig:
@@ -128,7 +129,35 @@ _PIPE_REPLICATED = frozenset(
     ("embed", "pos_embed", "lnf_scale", "lnf_bias", "w_out"))
 
 
-class MeshProgram:
+class ProgramLayout:
+    """Where a mesh program's parameters live: what the trainer's mesh
+    tier and ``transformer/step.py`` read of any program.  A subclass
+    sets ``cfg``, ``plan``, ``_shapes`` (name -> global shape) and
+    ``_specs`` (name -> PartitionSpec)."""
+
+    def partition_spec(self, name):
+        return self._specs[name]
+
+    def global_shape(self, name):
+        return self._shapes[name]
+
+    def local_shape(self, name):
+        """The per-replica shard shape — what the ``axis_env`` trace and
+        the ``shard_map`` body see."""
+        spec = self._specs[name]
+        shape = list(self._shapes[name])
+        for dim, entry in enumerate(spec):
+            if entry is not None:
+                shape[dim] //= self.plan.size(entry)
+        return tuple(shape)
+
+    def local_batch_shape(self, global_batch):
+        b = global_batch // self.plan.size("data")
+        t = self.cfg.seq_len // self.plan.size("sequence")
+        return (b, t)
+
+
+class MeshProgram(ProgramLayout):
     """One (config, plan) pair's concrete sharded program: parameter
     names/specs/local shapes, the deterministic global initializer, and
     the per-replica loss function (module docstring).
@@ -206,28 +235,6 @@ class MeshProgram:
         self.param_names = [n for n, _, _ in specs]
         self._shapes = {n: s for n, s, _ in specs}
         self._specs = {n: p for n, _, p in specs}
-
-    # -- layout -----------------------------------------------------------
-    def partition_spec(self, name):
-        return self._specs[name]
-
-    def global_shape(self, name):
-        return self._shapes[name]
-
-    def local_shape(self, name):
-        """The per-replica shard shape — what the ``axis_env`` trace and
-        the ``shard_map`` body see."""
-        spec = self._specs[name]
-        shape = list(self._shapes[name])
-        for dim, entry in enumerate(spec):
-            if entry is not None:
-                shape[dim] //= self.plan.size(entry)
-        return tuple(shape)
-
-    def local_batch_shape(self, global_batch):
-        b = global_batch // self.plan.size("data")
-        t = self.cfg.seq_len // self.plan.size("sequence")
-        return (b, t)
 
     # -- init -------------------------------------------------------------
     @staticmethod

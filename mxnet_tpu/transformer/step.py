@@ -23,9 +23,9 @@
   back over ``data`` under ``zero=1`` (the DST007 pair).
 
 Used two ways so runtime and analysis can never drift (the
-``parallel/zero.py`` discipline): ``build_runtime_fns`` wraps the parts
-in two jitted ``shard_map`` programs over the plan's mesh;
-``build_replica_step`` composes them for
+``parallel/zero.py`` discipline): ``build_replica_step`` composes the
+parts; ``build_runtime_fn`` jits the composition as ONE ``shard_map``
+program over the plan's mesh, and the same composition is traced for
 ``jax.make_jaxpr(axis_env=plan.axis_env())`` — the
 ``tp_transformer_train_step`` budget tape and ``trainer.mesh_report()``.
 """
@@ -34,7 +34,7 @@ from __future__ import annotations
 import numpy as _np
 
 __all__ = ["TPZeroPlan", "build_parts", "build_replica_step",
-           "build_runtime_fns", "sgd_momentum_update"]
+           "build_runtime_fn", "sgd_momentum_update"]
 
 
 class TPZeroPlan:
@@ -222,41 +222,35 @@ def build_replica_step(program, apply_update, state_leaf_counts, zero=0,
     return replica_step
 
 
-def build_runtime_fns(program, apply_update, state_leaf_counts, mesh,
-                      state_specs, zero=0, zero_plan=None,
-                      compute_dtype=None):
-    """``(grad_fn, update_fn)`` — the jitted ``shard_map`` programs the
-    trainer dispatches each step.  Params ride their
-    ``program.partition_spec``; the batch rides ``plan.batch_spec()``;
-    optimizer-state leaves ride ``state_specs`` (per-param specs, or the
-    flat ``P(("model", "data"))`` space under ``zero=1``).  ``update_fn``
-    donates params, states and gradients so the update happens in place
-    in HBM."""
+def build_runtime_fn(program, apply_update, state_leaf_counts, mesh,
+                     state_specs, zero=0, zero_plan=None,
+                     compute_dtype=None):
+    """The jitted ``shard_map`` program the trainer dispatches each step:
+    :func:`build_replica_step`'s composition of both halves, ``step_fn(
+    train_vals, state_leaves, x, y, key, lr, t) -> (loss, new_vals,
+    new_state_leaves)``.  Params ride their ``program.partition_spec``;
+    the batch rides ``plan.batch_spec()``; optimizer-state leaves ride
+    ``state_specs`` (per-param specs, or the flat ``P(("model", "data"))``
+    space under ``zero=1``).  Params and states are donated, so the update
+    happens in place in HBM, and the gradients are the program's own
+    temporaries: a step that is enqueued ahead allocates nothing (as two
+    programs, each enqueued step pinned a gradient set of the parameters'
+    size, and the run-ahead filled the chip with them: PERF.md, PR 28)."""
     import jax
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.ring_attention import _shard_map
 
-    plan = program.plan
-    grads_part, update_part = build_parts(
+    replica_step = build_replica_step(
         program, apply_update, state_leaf_counts, zero=zero,
         zero_plan=zero_plan, compute_dtype=compute_dtype)
     param_specs = tuple(program.partition_spec(n)
                         for n in program.param_names)
-    batch_spec = plan.batch_spec()
-    if zero:
-        flat_axes = tuple(a for a in ("pipe", "model", "data")
-                          if plan.present(a))
-        grad_out = P(flat_axes) if flat_axes else P()
-    else:
-        grad_out = param_specs
-    grad_fn = jax.jit(_shard_map(
-        grads_part, mesh,
-        in_specs=(param_specs, batch_spec, batch_spec, P()),
-        out_specs=(grad_out, P())))
-    update_fn = jax.jit(_shard_map(
-        update_part, mesh,
-        in_specs=(param_specs, tuple(state_specs), grad_out, P(), P()),
-        out_specs=(param_specs, tuple(state_specs))),
-        donate_argnums=(0, 1, 2))
-    return grad_fn, update_fn
+    batch_spec = program.plan.batch_spec()
+    state_specs = tuple(state_specs)
+    return jax.jit(_shard_map(
+        replica_step, mesh,
+        in_specs=(param_specs, state_specs, batch_spec, batch_spec, P(),
+                  P(), P()),
+        out_specs=(P(), param_specs, state_specs)),
+        donate_argnums=(0, 1))

@@ -546,7 +546,6 @@ def test_grad_accum_validation():
 def test_grad_accum_attribution_hint():
     from mxnet_tpu.telemetry.attribution import CONTEXT_HINTS
     assert ("dispatch", "grad_accum") in CONTEXT_HINTS
-    assert ("collective_or_ps", "pp_pipeline") in CONTEXT_HINTS
 
 
 # -- bench / gate wiring ----------------------------------------------------

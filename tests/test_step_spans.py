@@ -168,7 +168,9 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
     assert child[4] == parent[3] and parent[4] is None
     assert doc["spans"]["dropped"] == 0
     assert set(doc["compiles"]) == {"trace_s", "lower_s", "backend_s",
-                                    "in_span_programs", "blocked_bias_grads"}
+                                    "in_span_programs", "blocked_bias_grads",
+                                    "ssm_layers", "recomputed_layers",
+                                    "ssm_chunks_per_seq"}
 
 
 def test_the_doctor_reads_the_spans_and_the_compile_counters(

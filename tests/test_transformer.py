@@ -291,17 +291,23 @@ def test_checkpoint_roundtrip_mesh_tier(tmp_path):
         np.testing.assert_array_equal(got[name], want[name])
 
 
-def test_context_hints_and_tag():
-    from mxnet_tpu.telemetry.attribution import CONTEXT_HINTS
-    assert ("collective_or_ps", "tp_model") in CONTEXT_HINTS
-    assert ("collective_or_ps", "tp_sequence") in CONTEXT_HINTS
-    blk = TransformerLM(TransformerLMConfig(**CFG))
-    t1 = DataParallelTrainer(blk, None, "sgd",
-                             mesh_plan=MeshPlan(data=2, model=2))
-    assert t1._mesh_context_tag() == "tp_model"
-    t2 = DataParallelTrainer(blk, None, "sgd",
-                             mesh_plan=MeshPlan(data=2, sequence=2))
-    assert t2._mesh_context_tag() == "tp_sequence"
+def test_mesh_step_bills_dispatch_and_no_collective_phase():
+    """The mesh step is one device program: its host time is
+    ``dispatch``'s, nothing lands in ``collective_or_ps`` and no context
+    is tagged there, so the doctor names no collective knob for it."""
+    from mxnet_tpu import telemetry
+    telemetry.reset_attribution()
+    telemetry.enable()
+    try:
+        _train(MeshPlan(data=2, model=2), steps=3)
+        snapshot = telemetry.attribution().snapshot()
+    finally:
+        telemetry.disable()
+        telemetry.reset_attribution()
+    assert snapshot["steps"] == 2        # a window closes at the next step
+    assert snapshot["phases_s"]["dispatch"] > 0.0
+    assert snapshot["phases_s"]["collective_or_ps"] == 0.0
+    assert "collective_or_ps" not in snapshot["context"]
 
 
 # -- example + bench wiring -------------------------------------------------
