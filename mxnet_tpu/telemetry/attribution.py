@@ -1025,10 +1025,13 @@ def render_doctor(report):
             lines.append(
                 "   %d Mamba-2 layer(s) in the traced programs, the scan in "
                 "%d chunk(s) a sequence; %d layer(s) recomputed in the "
-                "backward pass"
+                "backward pass, %d of them with their projection products "
+                "kept (%.2f GB)"
                 % (compiled["ssm_layers"],
                    compiled.get("ssm_chunks_per_seq", 0),
-                   compiled.get("recomputed_layers", 0)))
+                   compiled.get("recomputed_layers", 0),
+                   compiled.get("kept_product_layers", 0),
+                   compiled.get("kept_product_bytes", 0) / 1e9))
         if rec.get("anomalies"):
             lines.append("   %d step-time anomaly event(s) flagged"
                          % rec["anomalies"])

@@ -23,12 +23,19 @@ One ``jax.monitoring`` duration listener, registered when
   (``gluon/nn/basic_layers.py::HybridSequential``).  Counted by the
   container while a program is traced, once per bias and trace: 32 for
   one trace of a ``resnet50_v1`` training step;
-- ``ssm_layers``, ``recomputed_layers``: Mamba-2 layers, and layers whose
-  forward the backward pass recomputes, in the programs traced so far
-  (``transformer/hybrid.py``, once per layer and trace: 9 and 10 for one
-  trace of the ten-layer ``granite-4.0-h-micro`` step);
-- ``ssm_chunks_per_seq``: chunks the state-space scan of the last traced
-  program cuts a sequence into (no sum: the newest value).
+- ``ssm_layers``, ``recomputed_layers``: Mamba-2 layers, and layers under
+  a ``jax.checkpoint`` boundary, whose backward pass re-runs the layer's
+  forward (all of it, or all but the kept projection products), in the
+  programs traced so far (``transformer/hybrid.py``, once per layer and
+  trace: 9 and 10 for one trace of the ten-layer ``granite-4.0-h-micro``
+  step);
+- ``kept_product_layers``: of those layers, the ones traced with their
+  projection products kept for the backward pass
+  (``transformer/hybrid.py::keeps_products`` decides from the shapes and
+  the device's memory: 10 in that step on a v5e, 0 where they do not fit);
+- ``ssm_chunks_per_seq``, ``kept_product_bytes``: chunks the state-space
+  scan of the last traced program cuts a sequence into, and the bytes of
+  the products it keeps (2.16e9 in that step; no sums: the newest values).
 
 Always on: the listener fires only when something is traced, lowered or
 compiled, which a steady step never does.  ``telemetry.enable()`` calls
@@ -50,7 +57,7 @@ LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "blocked_bias_grads", "ssm_layers", "recomputed_layers",
-         "ssm_chunks_per_seq")
+         "ssm_chunks_per_seq", "kept_product_layers", "kept_product_bytes")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

@@ -31,6 +31,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry import compiles as _compiles
 from . import layers as L
@@ -38,9 +39,15 @@ from . import ssm
 from .model import ProgramLayout
 
 __all__ = ["HybridLMConfig", "HybridLM", "HybridProgram", "LAYER_LEAVES",
-           "causal_gqa_attention", "rms_norm", "gated_mlp"]
+           "causal_gqa_attention", "rms_norm", "gated_mlp",
+           "kept_product_bytes", "keeps_products"]
 
 MIXERS = ("mamba", "attention")
+# what a training step holds on the mesh tier, bytes a parameter: float32
+# master 4, compute copy 2, float32 gradient 4, float32 momentum 4
+RESIDENT_BYTES_PER_PARAM = 14
+# the share of the device's memory the reckoning of `keeps_products` may fill
+ROOM = 0.9
 
 
 class HybridLMConfig:
@@ -184,9 +191,75 @@ def rms_norm(x, weight, eps):
 
 
 def gated_mlp(lp, x):
-    """``(silu(a) * b) W_out`` with ``[a, b] = x W_in``."""
-    a, b = jnp.split(x @ lp["mlp_in"], 2, axis=-1)
+    """``(silu(a) * b) W_out`` with ``[a, b] = x W_in``.  ``@ W_out``'s
+    result carries no tag: it is the layer's output, which nothing in the
+    layer's backward pass reads."""
+    a, b = jnp.split(checkpoint_name(x @ lp["mlp_in"], ssm.PROJECTION), 2,
+                     axis=-1)
     return (jax.nn.silu(a) * b) @ lp["mlp_out"]
+
+
+def _product_widths(cfg, mixer):
+    """Widths of the tagged projection products of one layer."""
+    if mixer == "mamba":
+        mix = [2 * cfg.ssm_inner + 2 * cfg.ssm_state + cfg.ssm_heads,
+               cfg.d_model]
+    else:
+        mix = [cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim,
+               cfg.n_kv_heads * cfg.head_dim, cfg.d_model]
+    return mix + [2 * cfg.d_ff]
+
+
+def kept_product_bytes(cfg, batch, seq, dtype):
+    """Bytes of every layer's tagged projection products over a local
+    chunk of ``batch x seq`` tokens in ``dtype``."""
+    widths = sum(sum(_product_widths(cfg, m)) for m in cfg.layer_types)
+    return batch * seq * widths * jnp.dtype(dtype).itemsize
+
+
+def _layer_live_bytes(cfg, mixer, batch, seq, dtype):
+    """One layer's live set while its backward pass runs, reckoned from
+    shapes: every intermediate of the layer once in ``dtype``, and four
+    float32 arrays of the mixer's scores (the scan's decay matrix of every
+    chunk and its masked product with ``C B^T``, or one block of query rows
+    against the keys; each with its gradient)."""
+    d, f = cfg.d_model, cfg.d_ff
+    if mixer == "mamba":
+        inner, n = cfg.ssm_inner, cfg.ssm_state
+        chunks = -(-seq // cfg.ssm_chunk)
+        widths = 2 * (inner + 2 * n) + 3 * inner
+        scores = batch * chunks * cfg.ssm_heads * cfg.ssm_chunk ** 2
+    else:
+        widths = cfg.n_heads * cfg.head_dim
+        scores = batch * cfg.n_heads * min(cfg.attention_block, seq) * seq
+    widths += sum(_product_widths(cfg, mixer)) + 4 * d + f
+    return (batch * seq * widths * jnp.dtype(dtype).itemsize
+            + 4 * scores * 4)
+
+
+def keeps_products(cfg, n_params, batch, seq, dtype, bytes_limit):
+    """Whether a step over a local chunk of ``batch x seq`` tokens keeps
+    its layers' projection products for the backward pass: they are kept
+    where the resident state (:data:`RESIDENT_BYTES_PER_PARAM` a parameter,
+    an over-count), the kept products and the largest layer's live set fit
+    in :data:`ROOM` of ``bytes_limit``, the device's memory.  A device that
+    reports no limit (``None``) keeps.  A pure function of its arguments:
+    one shape on one kind of device compiles one program."""
+    if bytes_limit is None:
+        return True
+    live = max(_layer_live_bytes(cfg, m, batch, seq, dtype)
+               for m in set(cfg.layer_types))
+    held = (RESIDENT_BYTES_PER_PARAM * n_params
+            + kept_product_bytes(cfg, batch, seq, dtype) + live)
+    return held <= ROOM * bytes_limit
+
+
+def _device_bytes_limit():
+    """The memory of the device a traced program will run on, as its
+    allocator reports it, or None (the CPU reports none).  Nothing else of
+    the allocator's state is read: what is in use differs from run to run."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
 
 
 def _attend_rows(q, k, v, scale, start):
@@ -296,12 +369,13 @@ class HybridProgram(ProgramLayout):
     # -- the per-replica forward + loss ------------------------------------
     def _attention(self, lp, x):
         cfg = self.cfg
-        q = jnp.einsum("btd,dhe->bthe", x, lp["wq"])
-        k = jnp.einsum("btd,dhe->bthe", x, lp["wk"])
-        v = jnp.einsum("btd,dhe->bthe", x, lp["wv"])
+        q, k, v = (checkpoint_name(
+            jnp.einsum("btd,dhe->bthe", x, lp[w]), ssm.PROJECTION)
+            for w in ("wq", "wk", "wv"))
         o = causal_gqa_attention(q, k, v, cfg.attention_multiplier,
                                  cfg.attention_block)
-        return jnp.einsum("bthe,hed->btd", o, lp["wo"])
+        return checkpoint_name(jnp.einsum("bthe,hed->btd", o, lp["wo"]),
+                               ssm.PROJECTION)
 
     def _layer(self, mixer, lp, h):
         """One layer over its leaves ``lp`` (kind -> array)."""
@@ -322,9 +396,19 @@ class HybridProgram(ProgramLayout):
 
     def loss_replica(self, train_vals, x, y, key):
         """Mean token cross-entropy of the local ``(b, t)`` chunk over the
-        held vocabulary; ``train_vals`` follow ``param_names``."""
+        held vocabulary; ``train_vals`` follow ``param_names``.  Every layer
+        is one ``jax.checkpoint``; :func:`keeps_products` decides, while this
+        is traced, whether the layers' projection products are among its
+        residuals (docs/transformer.md "The layer table")."""
         cfg = self.cfg
         p = dict(zip(self.param_names, train_vals))
+        dtype = p["embed"].dtype
+        keep = keeps_products(cfg, sum(v.size for v in train_vals),
+                              *x.shape, dtype, _device_bytes_limit())
+        # one checkpoint a layer, with or without the tagged products among
+        # its residuals; everything else of a layer is re-run either way
+        policy = jax.checkpoint_policies.save_only_these_names(
+            *([ssm.PROJECTION] if keep else []))
         with jax.named_scope("embed"):
             h = jnp.take(p["embed"], x, axis=0)
             h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
@@ -332,14 +416,18 @@ class HybridProgram(ProgramLayout):
             lp = {kind: p["l%d_%s" % (i, kind)]
                   for kind in LAYER_LEAVES[mixer]}
             layer = jax.checkpoint(
-                lambda lp, h, mixer=mixer: self._layer(mixer, lp, h))
+                lambda lp, h, mixer=mixer: self._layer(mixer, lp, h),
+                policy=policy)
             _compiles.count("recomputed_layers")
+            _compiles.count("kept_product_layers", int(keep))
             if mixer == "mamba":
                 _compiles.count("ssm_layers")
             with jax.named_scope("l%d" % i):
                 h = layer(lp, h)
         _compiles.note("ssm_chunks_per_seq",
                        -(-x.shape[1] // cfg.ssm_chunk))
+        _compiles.note("kept_product_bytes",
+                       kept_product_bytes(cfg, *x.shape, dtype) * keep)
         with jax.named_scope("lm_head_loss"):
             hf = rms_norm(h, p["norm_f"], cfg.norm_eps)
             logits = jnp.einsum("btd,vd->btv", hf, p["embed"],

@@ -30,9 +30,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 __all__ = ["ssd_chunked", "ssd_recurrence", "ssd_quadratic",
-           "causal_conv1d", "mamba2_mixer"]
+           "causal_conv1d", "mamba2_mixer", "PROJECTION"]
+
+# the ``checkpoint_name`` of a projection product's result: what a layer's
+# ``jax.checkpoint`` may keep for the backward pass (``hybrid.py``)
+PROJECTION = "projection"
 
 
 def _log_decay(dt, a_log):
@@ -164,7 +169,7 @@ def mamba2_mixer(lp, x, cfg):
     inner = h * p
     b, t, _ = x.shape
     with jax.named_scope("ssm_in_proj"):
-        proj = x @ lp["ssm_in"]
+        proj = checkpoint_name(x @ lp["ssm_in"], PROJECTION)
         z, xbc, dt = jnp.split(proj, [inner, 2 * inner + 2 * n], axis=-1)
     with jax.named_scope("ssm_conv"):
         xbc = jax.nn.silu(causal_conv1d(xbc, lp["ssm_conv_w"],
@@ -180,4 +185,4 @@ def mamba2_mixer(lp, x, cfg):
         y = _gated_rms_norm(y.reshape(b, t, inner), z, lp["ssm_norm"],
                             cfg.norm_eps)
     with jax.named_scope("ssm_out_proj"):
-        return y @ lp["ssm_out"]
+        return checkpoint_name(y @ lp["ssm_out"], PROJECTION)

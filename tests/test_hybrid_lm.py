@@ -250,3 +250,149 @@ def test_data_axis_of_two_matches_one_device():
         losses[data] = [float(trainer.step(ids[:, :-1], ids[:, 1:]).asnumpy())
                         for _ in range(3)]
     np.testing.assert_allclose(losses[2], losses[1], rtol=1e-5)
+
+
+# -- what a layer's checkpoint keeps for the backward pass -------------------
+def _rehearsal_program(family, **sizes):
+    """(config, program, weights by name order, x, y) at the rehearsal
+    size, float32."""
+    from mxnet_tpu.parallel import MeshPlan
+    from mxnet_tpu.transformer import HybridLM, HybridLMConfig
+    config, size = _config(**sizes)
+    cfg = HybridLMConfig.from_hf(family.sized(config, size),
+                                 seq_len=size["seq_len"])
+    program = HybridLM(cfg).mesh_program(MeshPlan(data=1))
+    weights = family.make_weights(config, size, SEED)
+    ids = jax.random.randint(
+        jax.random.PRNGKey(3), (size["batch_per_chip"], size["seq_len"] + 1),
+        0, size["vocab_size"])
+    vals = tuple(weights[n] for n in program.param_names)
+    return cfg, program, vals, ids[:, :-1], ids[:, 1:]
+
+
+def _with_limit(monkeypatch, limit):
+    """The device reports ``limit`` bytes of memory (None: none)."""
+    from mxnet_tpu.transformer import hybrid
+    monkeypatch.setattr(hybrid, "_device_bytes_limit", lambda: limit)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _count_products(jaxpr, *shapes):
+    """``dot_general``s whose two operands and result have ``shapes`` in
+    some order, each with its axes in some order: a product and the two
+    products of its backward pass."""
+    want = sorted(sorted(shape) for shape in shapes)
+    return sum(
+        eqn.primitive.name == "dot_general"
+        and sorted(sorted(v.aval.shape)
+                   for v in (*eqn.invars, *eqn.outvars)) == want
+        for eqn in _equations(jaxpr))
+
+
+def test_kept_products_leave_loss_and_gradients_as_they_were(
+        family, monkeypatch):
+    """With room the projection products are residuals, without it nothing
+    is: the same loss and the same gradient of every leaf."""
+    _, program, vals, x, y = _rehearsal_program(family)
+    got = {}
+    for name, limit in (("kept", None), ("nothing", 1)):
+        _with_limit(monkeypatch, limit)
+        got[name] = jax.jit(jax.value_and_grad(program.loss_replica))(
+            vals, x, y, None)
+    np.testing.assert_allclose(got["kept"][0], got["nothing"][0], rtol=1e-6)
+    for name, kept, nothing in zip(program.param_names, got["kept"][1],
+                                   got["nothing"][1]):
+        scale = float(jnp.max(jnp.abs(nothing)))
+        np.testing.assert_allclose(kept, nothing, rtol=1e-6,
+                                   atol=1e-6 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("limit,in_backward", [(None, 2), (1, 3)])
+def test_which_products_the_backward_pass_runs_again(
+        family, monkeypatch, limit, in_backward):
+    """In the jaxpr of the gradient: ``x @ ssm_in`` and ``x @ mlp_in``
+    stand once in the forward pass and twice (kept) or three times (run
+    again) in the backward pass; the scan's four einsums stand as often
+    either way, so a policy that kept nothing, or kept the scan, fails."""
+    cfg, program, vals, x, y = _rehearsal_program(family)
+    _with_limit(monkeypatch, limit)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(program.loss_replica))(
+        vals, x, y, None).jaxpr
+    b, t = x.shape
+    d, n, h, p = cfg.d_model, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    mamba = cfg.layer_types.count("mamba")
+    width = 2 * cfg.ssm_inner + 2 * n + h
+    assert _count_products(jaxpr, (b, t, d), (d, width), (b, t, width)) \
+        == (1 + in_backward) * mamba
+    assert _count_products(jaxpr, (b, t, d), (d, 2 * cfg.d_ff),
+                           (b, t, 2 * cfg.d_ff)) \
+        == (1 + in_backward) * len(cfg.layer_types)
+    # the scan, told by the chunked shapes of ``C B^T`` and of the masked
+    # decay matrix's product with ``dt x``: forward, again, two backward
+    c, L = t // cfg.ssm_chunk, cfg.ssm_chunk
+    assert _count_products(jaxpr, (b, c, L, n), (b, c, L, n),
+                           (b, c, L, L)) == 4 * mamba
+    assert _count_products(jaxpr, (b, c, h, L, L), (b, c, L, h, p),
+                           (b, c, L, h, p)) == 4 * mamba
+
+
+def test_the_decision_is_a_function_of_shapes_and_the_limit():
+    """The cell's shapes on a 16.9 GB chip keep 2.16 GB of products; twice
+    the tokens do not fit; a device that reports no limit keeps."""
+    from mxnet_tpu.transformer import HybridLMConfig
+    from mxnet_tpu.transformer.hybrid import (keeps_products,
+                                              kept_product_bytes)
+    config, _ = _config()
+    cfg = HybridLMConfig.from_hf(config, seq_len=config["seq_len"])
+    n_params, bf16 = 772.2e6, jnp.bfloat16
+    assert kept_product_bytes(cfg, 1, 4096, bf16) == pytest.approx(
+        2.16e9, rel=0.02)
+    assert keeps_products(cfg, n_params, 1, 4096, bf16, 16.9e9)
+    assert not keeps_products(cfg, n_params, 2, 4096, bf16, 16.9e9)
+    assert keeps_products(cfg, n_params, 2, 4096, bf16, None)
+    # float32 copies of the same tokens are twice the bytes
+    assert not keeps_products(cfg, n_params, 1, 4096, jnp.float32, 16.9e9)
+
+
+@pytest.mark.parametrize("limit,layers", [(None, 3), (1, 0)])
+def test_kept_product_counters(monkeypatch, limit, layers):
+    from mxnet_tpu.parallel import MeshPlan
+    from mxnet_tpu.telemetry import compiles
+    from mxnet_tpu.transformer import HybridLM, HybridLMConfig
+    from mxnet_tpu.transformer.hybrid import kept_product_bytes
+    cfg = HybridLMConfig(layer_types=("mamba", "attention", "mamba"),
+                         seq_len=20, ssm_chunk=8)
+    program = HybridLM(cfg).mesh_program(MeshPlan(data=1))
+    params = program.init_params()
+    _with_limit(monkeypatch, limit)
+    before = compiles.counters()
+    x = jnp.zeros((2, 20), jnp.int32)
+    jax.make_jaxpr(program.loss_replica)(
+        tuple(params[n] for n in program.param_names), x, x, None)
+    after = compiles.counters()
+    assert after["kept_product_layers"] - before["kept_product_layers"] \
+        == layers
+    assert after["recomputed_layers"] - before["recomputed_layers"] == 3
+    # mamba: 2 x 64 + 2 x 8 + 4 and 32; attention: 32 + 16 + 16 + 32; each
+    # layer 2 x 64 for the feed-forward; 40 tokens of float32
+    kept = 40 * 4 * (2 * (148 + 32) + 96 + 3 * 128)
+    assert kept_product_bytes(cfg, 2, 20, jnp.float32) == kept
+    assert after["kept_product_bytes"] == (kept if layers else 0)
+    # the doctor's state-space line prints both counts
+    from mxnet_tpu import telemetry
+    traced = {k: after[k] - before[k] for k in after}
+    traced["kept_product_bytes"] = after["kept_product_bytes"]
+    text = telemetry.render_doctor({
+        "directory": "d", "ranks": {"worker0": {"compiles": traced}},
+        "stragglers": [], "events": dict.fromkeys(
+            ("straggler", "anomaly", "queue_growth", "fault"), ())})
+    assert ("3 layer(s) recomputed in the backward pass, %d of them with "
+            "their projection products kept (%.2f GB)"
+            % (layers, after["kept_product_bytes"] / 1e9)) in text

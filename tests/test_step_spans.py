@@ -170,7 +170,9 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
     assert set(doc["compiles"]) == {"trace_s", "lower_s", "backend_s",
                                     "in_span_programs", "blocked_bias_grads",
                                     "ssm_layers", "recomputed_layers",
-                                    "ssm_chunks_per_seq"}
+                                    "ssm_chunks_per_seq",
+                                    "kept_product_layers",
+                                    "kept_product_bytes"}
 
 
 def test_the_doctor_reads_the_spans_and_the_compile_counters(
