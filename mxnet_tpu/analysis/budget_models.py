@@ -248,9 +248,6 @@ def zero1_runtime_checks(fixture_report, tolerance_pct=10.0):
       psum bytes the global-view mxshard pass infers for the
       replicated spelling, up to the flat-padding ring bytes.
     """
-    import jax
-    import jax.numpy as jnp
-
     from . import shard_fixtures as sf
     from .cost import analyze_fn
     from .findings import Finding
@@ -292,28 +289,11 @@ def zero1_runtime_checks(fixture_report, tolerance_pct=10.0):
 
     # the ZeRO-1 relation against the trainer's own per-replica twin
     twin = _zero1_geometry_trainer(zero=0)
-    train_vals = None
     try:
-        import numpy as _onp
-
-        from ..ndarray import NDArray
-        x0 = NDArray(jnp.zeros(data_shape, _onp.float32))
-        y0 = NDArray(jnp.zeros(label_shape, _onp.int32))
-        twin._setup(x0, y0)
-        train_vals = tuple(twin._params_by_name[n].data()._data
-                           for n in twin._train_names)
-        aux_vals = tuple(twin._params_by_name[n].data()._data
-                         for n in twin._aux_names)
-        states = tuple(twin._states_raw)
-        xs = jax.ShapeDtypeStruct((g["batch"], g["in_dim"]),
-                                  _onp.float32)
-        ys = jax.ShapeDtypeStruct((g["batch"],), _onp.int32)
-        key = jax.ShapeDtypeStruct((2,), _onp.uint32)
+        args = twin._trace_args(data_shape, label_shape, axis_size=k)
         twin_rep = analyze_fn(
-            twin._build_replica_step(), train_vals, states, aux_vals,
-            xs, ys, key, jnp.float32(0.01), jnp.int32(1),
-            axis_env=[("data", k)], donate_argnums=(0, 1),
-            host_argnums=(3, 4))
+            twin._build_replica_step(), *args, axis_env=[("data", k)],
+            donate_argnums=(0, 1), host_argnums=(3, 4))
     except Exception as e:
         findings.append(Finding(
             "COST001", "zero1_mlp_train_step.runtime",

@@ -255,33 +255,14 @@ def lint_trainer(trainer, data_shape=None, label_shape=None,
     Traces the trainer's per-replica step (explicit collectives) with
     ``make_jaxpr(axis_env=...)`` — no devices beyond the trainer's own
     mesh are needed — and combines the jaxpr rules with the DST003
-    sharding-consistency checks.  ``data_shape``/``label_shape`` are
-    required if the trainer has not seen a batch yet.
+    sharding-consistency checks.  ``data_shape`` is the whole batch's
+    (``label_shape`` defaults to one label a row); a trainer that has
+    not seen a batch is set up from zeros of it.
     """
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
-    from .. import _rng
-    from ..ndarray import NDArray
-
-    if not trainer._ready:
-        if data_shape is None:
-            raise ValueError(
-                "trainer has not stepped yet: pass data_shape (and "
-                "label_shape) so the step can be traced")
-        x0 = NDArray(jnp.zeros(tuple(data_shape), _np.dtype(data_dtype)))
-        y0 = NDArray(jnp.zeros(tuple(label_shape or (data_shape[0],)),
-                               _np.dtype(label_dtype)))
-        trainer._setup(x0, y0)
-        data_shape = tuple(data_shape)
-        label_shape = tuple(label_shape or (data_shape[0],))
-    else:
-        if data_shape is None or label_shape is None:
-            raise ValueError("pass the step's data_shape/label_shape")
-        data_shape = tuple(data_shape)
-        label_shape = tuple(label_shape)
-
+    data_shape, label_shape = trainer._setup_from_shapes(
+        data_shape, label_shape, data_dtype, label_dtype)
     mesh = trainer._mesh
     axis = trainer._data_axis
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -298,22 +279,12 @@ def lint_trainer(trainer, data_shape=None, label_shape=None,
         disable=disable, subject="DataParallelTrainer")
 
     # the per-replica spelling sees the batch SHARD
-    shard = max(data_shape[0] // max(ksize, 1), 1)
-    x = jax.ShapeDtypeStruct((shard,) + data_shape[1:],
-                             _np.dtype(data_dtype))
-    y = jax.ShapeDtypeStruct((shard,) + label_shape[1:],
-                             _np.dtype(label_dtype))
-    train_vals = tuple(trainer._params_by_name[n].data()._data
-                       for n in trainer._train_names)
-    aux_vals = tuple(trainer._params_by_name[n].data()._data
-                     for n in trainer._aux_names)
-    states = tuple(trainer._states_raw)
-    key = jax.ShapeDtypeStruct((2,), _np.dtype(np.uint32))
-    step = trainer._build_replica_step()
+    args = trainer._trace_args(data_shape, label_shape, data_dtype,
+                               label_dtype, axis_size=ksize)
+    train_vals, states, aux_vals = args[:3]
     try:
-        closed = jax.make_jaxpr(step, axis_env=[(axis, ksize)])(
-            train_vals, states, aux_vals, x, y, key,
-            jnp.float32(0.01), jnp.int32(1))
+        closed = jax.make_jaxpr(trainer._build_replica_step(),
+                                axis_env=[(axis, ksize)])(*args)
     except Exception as e:
         findings.append(Finding(
             "DST001", "DataParallelTrainer",

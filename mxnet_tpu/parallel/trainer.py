@@ -36,8 +36,9 @@ from ..telemetry import trace as _trace
 from ..ndarray import NDArray
 from ..resilience import chaos as _chaos
 from . import mesh as mesh_mod
+from . import step as _step
 from .functional import (functionalize_forward, functional_optimizer_update,
-                         accumulate_grads, tree_raw)
+                         tree_raw)
 
 __all__ = ["DataParallelTrainer", "DEFAULT_CHECKPOINT_EVERY"]
 
@@ -126,6 +127,8 @@ class DataParallelTrainer:
         # f32 path, byte-identical to before the knob existed.
         self._dtype = _precision.resolve_dtype(dtype)
         self._reduced = _precision.is_reduced(self._dtype)
+        # what every step builder takes: None spells the plain f32 step
+        self._compute_dtype = self._dtype if self._reduced else None
         self._block = block
         self._loss = loss
         self._input_transform = input_transform
@@ -353,44 +356,29 @@ class DataParallelTrainer:
         if self._zero:
             self._setup_zero_states()
         else:
-            # group parameters into fused update buckets (reference
-            # precedent: multi-tensor optimizer launches,
-            # docs/faq/perf.md:214-216 "grouped updates" lever): every
-            # elementwise optimizer applies the identical per-scalar
-            # rule, so same-hyper same-dtype replicated params can be
-            # updated as ONE flat concatenated vector — dozens of small
-            # per-param fusions collapse into a handful of launches.
-            import os as _os
-            # opt-in: fused buckets measured ~2-4%% SLOWER end to end on
-            # resnet-50/v5e even when restricted to tiny BN/bias params
-            # — the concat barriers the backward->optimizer overlap that
-            # XLA otherwise schedules per-gradient
-            # (docs/perf_resnet50_tpu.md "levers measured and
-            # rejected").  Kept env-gated for workloads with thousands
-            # of small params.  The FUSED Pallas update (docs/fusion.md)
-            # rides the same bucket machinery: one flat f32 space, one
-            # kernel pass — on by default on TPU for SGD/Adam, forced
-            # elsewhere via MXTPU_FUSED_OPTIMIZER=1.
+            # group parameters into update buckets (reference precedent:
+            # multi-tensor optimizer launches, docs/faq/perf.md:214-216):
+            # every elementwise optimizer applies the identical
+            # per-scalar rule, so same-hyper same-dtype replicated params
+            # can be updated as ONE flat concatenated vector.  The FUSED
+            # Pallas update (docs/fusion.md) rides the buckets: one flat
+            # f32 space, one kernel pass; on by default on TPU for
+            # SGD/Adam, forced elsewhere via MXTPU_FUSED_OPTIMIZER=1.
+            # Without it a group is one parameter: the concat barriers
+            # the backward->optimizer overlap that XLA otherwise
+            # schedules per gradient (docs/perf_resnet50_tpu.md "levers
+            # measured and rejected").
             from ..ops import fused_optimizer as _fused
-            fused_on = (_fused.fused_update_enabled()
-                        and _fused.supports(self._opt) is not None)
-            groupable = type(self._opt).__name__ in \
-                _ELEMENTWISE_OPTIMIZERS \
-                and (_os.environ.get("MXTPU_GROUP_UPDATES", "0") == "1"
-                     or fused_on)
-            max_group_elems = int(_os.environ.get(
-                "MXTPU_GROUP_MAX_ELEMS",
-                str((1 << 62) if fused_on else 65536)))
+            bucketed = (_fused.fused_update_enabled()
+                        and _fused.supports(self._opt) is not None
+                        and type(self._opt).__name__
+                        in _ELEMENTWISE_OPTIMIZERS)
             buckets = {}
             self._groups = []  # list of [name, ...]
             for name in self._train_names:
                 p = self._params_by_name[name]
-                spec = self._param_spec_fn(name, p.shape)
-                psize = 1
-                for d in p.shape:
-                    psize *= int(d)
-                if not groupable or spec != PartitionSpec() or \
-                        psize > max_group_elems:
+                if not bucketed or \
+                        self._param_spec_fn(name, p.shape) != PartitionSpec():
                     self._groups.append([name])
                     continue
                 key = (float(p.lr_mult), float(p.wd_mult),
@@ -595,50 +583,45 @@ class DataParallelTrainer:
     def _zero_leaves(self):
         return tuple(jax.tree_util.tree_leaves(self._states_raw[0]))
 
-    def _zero_step(self, train_vals, aux_vals, x, y, rng, lr, count,
-                   attr, span):
+    def _enqueue_zero(self, args, attr, span):
         """ZeRO-1 split step: local grads + reduce-scatter (one jitted
         shard_map program), then shard-local update + all-gather (a
-        second one).  The split mirrors ``_dist_step``'s grad→exchange→
-        update shape: the collective program's host time bills to the
-        ``collective_or_ps`` attribution phase, so the doctor sees the
-        reduce-scatter/all-gather shift under zero=1."""
+        second one).  The split mirrors ``_enqueue_dist``'s
+        grad→exchange→update shape: the collective program's host time
+        bills to the ``collective_or_ps`` attribution phase, so the
+        doctor sees the reduce-scatter/all-gather shift under zero=1.
+        The states live as one sharded flat tree, not per group."""
         from . import zero as _zero
+        train_vals, _, aux_vals, x, y, rng, lr, count = args[:8]
         if self._zero_grad_fn is None:
             self._zero_grad_fn, self._zero_update_fn = \
                 _zero.build_runtime_fns(
                     self._fwd, self._opt, self._zero_plan,
                     self._zero_treedef, self._mesh,
-                    compute_dtype=self._dtype if self._reduced else None,
+                    compute_dtype=self._compute_dtype,
                     grad_accum=self._grad_accum)
             if attr:
                 attr.set_context("collective_or_ps", "zero1")
                 if self._grad_accum > 1:
                     attr.set_context("dispatch", "grad_accum")
+        # reduced: the loss-scale scalars ride the arguments, the finite
+        # flag goes from the first program to the second, and the f32
+        # master shard is threaded through the update
+        loss_scale = args[8:]
+        (g_sh, loss_val, muts, *fin), own = self._enqueue(
+            span, self._zero_grad_fn,
+            train_vals, aux_vals, x, y, rng, *loss_scale[:1])
+        if attr:
+            attr.add_phase("dispatch", own)
+        master = (self._zero_master,) if self._reduced else ()
+        out, own = self._enqueue(
+            span, self._zero_update_fn, train_vals, *master,
+            self._zero_leaves(), g_sh, lr, count, *loss_scale, *fin)
         if self._reduced:
-            (g_sh, loss_val, muts, fin), own = self._enqueue(
-                span, self._zero_grad_fn,
-                train_vals, aux_vals, x, y, rng, self._ls_scale)
-            if attr:
-                attr.add_phase("dispatch", own)
-            (new_vals, new_master, new_leaves, new_scale, new_good,
-             new_skipped), own = self._enqueue(
-                span, self._zero_update_fn,
-                train_vals, self._zero_master, self._zero_leaves(),
-                g_sh, lr, count,
-                self._ls_scale, self._ls_good, self._ls_skipped, fin)
-            self._zero_master = new_master
-            self._ls_scale, self._ls_good = new_scale, new_good
-            self._ls_skipped = new_skipped
+            (new_vals, self._zero_master, new_leaves, self._ls_scale,
+             self._ls_good, self._ls_skipped) = out
         else:
-            (g_sh, loss_val, muts), own = self._enqueue(
-                span, self._zero_grad_fn,
-                train_vals, aux_vals, x, y, rng)
-            if attr:
-                attr.add_phase("dispatch", own)
-            (new_vals, new_leaves), own = self._enqueue(
-                span, self._zero_update_fn,
-                train_vals, self._zero_leaves(), g_sh, lr, count)
+            new_vals, new_leaves = out
         self._states_raw = [jax.tree_util.tree_unflatten(
             self._zero_treedef, list(new_leaves))]
         if attr:
@@ -660,7 +643,7 @@ class DataParallelTrainer:
             self._data_axis, k)
         return _zero.build_replica_step(
             self._fwd, self._opt, plan, self._zero_treedef,
-            compute_dtype=self._dtype if self._reduced else None,
+            compute_dtype=self._compute_dtype,
             grad_accum=self._grad_accum), plan
 
     def zero_report(self, data_shape=None, label_shape=None,
@@ -681,19 +664,8 @@ class DataParallelTrainer:
 
         if not self._zero:
             raise ValueError("zero_report needs a zero=1 trainer")
-        if not self._ready:
-            if data_shape is None:
-                raise ValueError(
-                    "trainer has not stepped yet: pass data_shape (and "
-                    "label_shape)")
-            x0 = NDArray(jnp.zeros(tuple(data_shape),
-                                   _onp.dtype(data_dtype)))
-            y0 = NDArray(jnp.zeros(
-                tuple(label_shape or (data_shape[0],)),
-                _onp.dtype(label_dtype)))
-            self._setup(x0, y0)
-        data_shape = tuple(data_shape)
-        label_shape = tuple(label_shape or (data_shape[0],))
+        data_shape, label_shape = self._setup_from_shapes(
+            data_shape, label_shape, data_dtype, label_dtype)
         k = int(declared_axis_size or self._zero_axis_size())
         step, plan = self._build_zero_replica_step(k)
         shard_local = max(data_shape[0] // max(k, 1), 1)
@@ -892,56 +864,19 @@ class DataParallelTrainer:
             program, apply_update, self._mesh_leaf_counts, mesh,
             self._mesh_state_specs, zero=self._zero,
             zero_plan=self._mesh_zero_plan,
-            compute_dtype=self._dtype if self._reduced else None)
+            compute_dtype=self._compute_dtype)
         self._ready = True
 
-    def _step_mesh_tier(self, data, label):
-        """One mesh-tier training step (the ``step()`` route when a
-        MeshPlan is armed): same chaos probe, spans, attribution phases
-        and run-ahead bookkeeping as the replicated step: one program
-        a step, billed to ``dispatch``."""
-        if not self._ready:
-            self._setup_mesh(data, label)
-        return self._under_step_span(self._mesh_step, data, label)
-
-    def _mesh_step(self, data, label, attr, span):
-        from .. import _rng
-        in_flight = self._unfinished() if attr else 0
-        batch_sh = self.batch_sharding
-        sp = span("step.h2d")
-        with sp:
-            x = self._put_batch(data, batch_sh)
-            y = self._put_batch(label, batch_sh)
-        if attr:
-            attr.add_phase("h2d_transfer", sp.seconds)
-        sp = span("step.prepare")
-        with sp:
-            self._step_count += 1
-            _chaos.maybe_inject("trainer.step", self._step_count, ctx=self)
-            self._opt.num_update = self._step_count
-            lr_host = (self._opt.lr_scheduler(self._step_count)
-                       if self._opt.lr_scheduler else self._opt.lr)
-            train_vals = tuple(self._mesh_params[n]
-                               for n in self._mesh_param_names)
-            rng = _rng.next_key()
-            lr, count = self._step_scalars(lr_host)
-        if attr:
-            attr.add_phase("dispatch", self._own_cost(
-                self._prepare_split, sp.seconds, in_flight))
+    def _enqueue_mesh(self, args, attr, span):
+        """The mesh tier's enqueue: one ``shard_map`` program a step
+        (``transformer/step.py::build_runtime_fn``), billed to
+        ``dispatch``.  The mesh program mutates no statistics."""
         (loss_val, new_vals, new_leaves), own = self._enqueue(
-            span, self._mesh_step_fn, train_vals, self._mesh_state_leaves,
-            x, y, rng, lr, count)
+            span, self._mesh_step_fn, *args)
         if attr:
             attr.add_phase("dispatch", own)
-        sp = span("step.commit")
-        with sp:
-            for name, val in zip(self._mesh_param_names, new_vals):
-                self._mesh_params[name] = val
-            self._mesh_state_leaves = tuple(new_leaves)
-        if attr:
-            attr.add_phase("dispatch", sp.seconds)
-        self._track_inflight(loss_val)
-        return NDArray(loss_val)
+        self._mesh_state_leaves = tuple(new_leaves)
+        return loss_val, new_vals, ()
 
     def mesh_report(self, data_shape=None, label_shape=None,
                     declared_plan=None):
@@ -1016,7 +951,7 @@ class DataParallelTrainer:
         step = _tstep.build_replica_step(
             program, self._mesh_apply_update(treedefs), leaf_counts,
             zero=self._zero, zero_plan=zp,
-            compute_dtype=self._dtype if self._reduced else None)
+            compute_dtype=self._compute_dtype)
         train_avals = tuple(
             jax.ShapeDtypeStruct(program.local_shape(n), _onp.float32)
             for n in program.param_names)
@@ -1308,205 +1243,30 @@ class DataParallelTrainer:
             new_states.append(ns)
         return tuple(new_vals), tuple(new_states)
 
-    def _build_step(self):
-        fwd = self._fwd
-        if self._reduced:
-            return jax.jit(self._reduced_pure_step(),
-                           donate_argnums=(0, 1))
-
-        n_acc = self._grad_accum
-        if n_acc > 1:
-            # microbatched spelling (grad_accum): left-fold sum of
-            # per-microbatch grads (functional.accumulate_grads), ONE
-            # optimizer update on the mean — the n_acc=1 spelling below
-            # stays byte-identical to the historical traced program
-            def pure_step(train_vals, states, aux_vals, x, y, key, lr,
-                          t):
-                def grad_of(tv, xi, yi):
-                    def loss_of(t_):
-                        outs, muts = fwd(t_, aux_vals, (xi, yi), key)
-                        return outs[0], muts
-                    return jax.value_and_grad(loss_of, has_aux=True)(tv)
-
-                grads_sum, loss_sum, muts_stack = \
-                    accumulate_grads(grad_of, train_vals, x, y, n_acc)
-                grads = tuple(g / n_acc for g in grads_sum)
-                loss_val = loss_sum / n_acc
-                muts = tuple(m.mean(axis=0) for m in muts_stack)
-                new_vals, new_states = self._apply_groups(
-                    train_vals, states, grads, lr, t)
-                return loss_val, new_vals, new_states, muts
-
-            return jax.jit(pure_step, donate_argnums=(0, 1))
-
-        def pure_step(train_vals, states, aux_vals, x, y, key, lr, t):
-            def loss_of(tv):
-                outs, muts = fwd(tv, aux_vals, (x, y), key)
-                return outs[0], muts
-
-            (loss_val, muts), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(train_vals)
-            new_vals, new_states = self._apply_groups(
-                train_vals, states, grads, lr, t)
-            return loss_val, new_vals, new_states, muts
-
-        return jax.jit(pure_step, donate_argnums=(0, 1))
-
-    def _reduced_pure_step(self):
-        """Mixed-precision replicated spelling: the f32 ``train_vals``
-        ARE the masters; they cast to the compute dtype at the forward
-        boundary (so grads come back f32 through the cast transpose),
-        the scaled loss drives the backward, and the optimizer update
-        unscales + select-skips on the global finite flag — one kernel
-        pass when fused (docs/precision.md)."""
-        from .. import precision as _precision
-        fwd, dtype = self._fwd, self._dtype
-
-        def _to_compute(v):
-            if hasattr(v, "dtype") and jnp.issubdtype(v.dtype,
-                                                      jnp.floating):
-                return v.astype(dtype)
-            return v
-
-        def pure_step(train_vals, states, aux_vals, x, y, key, lr, t,
-                      scale, good, skipped):
-            x_c = _to_compute(x)
-            aux_c = tuple(_to_compute(a) for a in aux_vals)
-
-            def loss_of(tv):
-                tv_c = tuple(_to_compute(w) for w in tv)
-                outs, muts = fwd(tv_c, aux_c, (x_c, y), key)
-                raw = outs[0].astype(jnp.float32)
-                return raw * scale, (raw, muts)
-
-            (_, (loss_val, muts)), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(train_vals)
-            fin = _precision.all_finite(grads)
-            inv = (1.0 / scale).astype(jnp.float32)
-            new_vals, new_states = self._apply_groups(
-                train_vals, states, grads, lr, t,
-                inv_scale=inv, ok=fin.astype(jnp.float32))
-            new_scale, new_good = _precision.loss_scale_update(
-                scale, good, fin)
-            new_skipped = skipped + (1 - fin.astype(jnp.int32))
-            muts = tuple(m.astype(jnp.float32) for m in muts)
-            return (loss_val, new_vals, new_states, muts,
-                    new_scale, new_good, new_skipped)
-
-        return pure_step
-
-    def _reduce_grads(self, grads):
-        """Cross-replica gradient mean over the data axis.
-
-        This is the step's ONE reduction point: explicit in the
-        per-replica spelling (``_build_replica_step``, what the DST lint
-        verifies); under ``jax.jit`` + ``NamedSharding`` the compiler
-        inserts the equivalent psum automatically because the loss is a
-        mean over the batch-sharded axis.  Removing this call is exactly
-        the "gradient psum removed" bug class: DST001 fires per
-        parameter (tests/test_analysis.py)."""
-        with jax.named_scope("grad_reduce"):
-            return tuple(jax.lax.pmean(g, self._data_axis) for g in grads)
+    def _pure_step(self, axis=None):
+        """The replicated tier's step as one pure function, composed of
+        ``parallel/step.py``'s parts around this trainer's forward and
+        ``_apply_groups``: what ``_step_fn`` jits and, with ``axis``, the
+        same step seen from one shard of the data axis.  Its arguments
+        are ``_step_args``'; ``_trace_args`` sets a fresh trainer up."""
+        if not self._ready:
+            raise RuntimeError("the step is built over the set-up "
+                               "trainer: assemble _trace_args first")
+        return _step.build_replica_step(
+            self._fwd, self._apply_groups, axis=axis,
+            compute_dtype=self._compute_dtype,
+            grad_accum=self._grad_accum)
 
     def _build_replica_step(self):
         """Per-replica spelling of the compiled step for static analysis:
-        the SAME forward/loss/optimizer code as ``_build_step``, seen
-        from one shard of the data axis, with the cross-replica
-        collectives written out (grads, the reported loss, and BatchNorm
-        batch statistics are all global under GSPMD).  Traced with
-        ``jax.make_jaxpr(axis_env=[(data_axis, K)])`` — no hardware, no
-        compilation — by ``lint()``/``cost_report()`` and the
-        ``python -m mxnet_tpu.analysis --cost`` budget models."""
-        fwd = self._fwd
-        axis = self._data_axis
-        if self._reduced:
-            from .. import precision as _precision
-            dtype = self._dtype
-
-            def _to_compute(v):
-                if hasattr(v, "dtype") and jnp.issubdtype(
-                        v.dtype, jnp.floating):
-                    return v.astype(dtype)
-                return v
-
-            def replica_step(train_vals, states, aux_vals, x, y, key,
-                             lr, t):
-                # analysis twin of the reduced jitted step, seeded with
-                # the neutral loss-scale constants (scale=1 keeps the
-                # traced algebra identical; the live scale only changes
-                # a scalar multiply).  8-arg so lint_trainer/cost_report
-                # keep their one calling convention.
-                scale = jnp.float32(1.0)
-                x_c = _to_compute(x)
-                aux_c = tuple(_to_compute(a) for a in aux_vals)
-
-                def loss_of(tv):
-                    tv_c = tuple(_to_compute(w) for w in tv)
-                    outs, muts = fwd(tv_c, aux_c, (x_c, y), key)
-                    raw = outs[0].astype(jnp.float32)
-                    return raw * scale, (raw, muts)
-
-                (_, (loss_val, muts)), grads = jax.value_and_grad(
-                    loss_of, has_aux=True)(train_vals)
-                # grads are f32 through the cast transpose — the
-                # collective reduces f32 (tightened DST004 contract)
-                grads = self._reduce_grads(grads)
-                loss_val = jax.lax.pmean(loss_val, axis)
-                muts = tuple(jax.lax.pmean(m.astype(jnp.float32), axis)
-                             for m in muts)
-                fin = _precision.all_finite(grads)
-                inv = (1.0 / scale).astype(jnp.float32)
-                new_vals, new_states = self._apply_groups(
-                    train_vals, states, grads, lr, t,
-                    inv_scale=inv, ok=fin.astype(jnp.float32))
-                return loss_val, new_vals, new_states, muts
-
-            return replica_step
-
-        n_acc = self._grad_accum
-        if n_acc > 1:
-            # analysis twin of the grad_accum jitted step: the SAME
-            # accumulate_grads spelling, then the step's ONE gradient
-            # reduction — accumulation happens per replica, the
-            # collective count is unchanged (DST001 still counts one
-            # pmean per trainable)
-            def replica_step(train_vals, states, aux_vals, x, y, key,
-                             lr, t):
-                def grad_of(tv, xi, yi):
-                    def loss_of(t_):
-                        outs, muts = fwd(t_, aux_vals, (xi, yi), key)
-                        return outs[0], muts
-                    return jax.value_and_grad(loss_of, has_aux=True)(tv)
-
-                grads_sum, loss_sum, muts_stack = \
-                    accumulate_grads(grad_of, train_vals, x, y, n_acc)
-                grads = tuple(g / n_acc for g in grads_sum)
-                loss_val = loss_sum / n_acc
-                muts = tuple(m.mean(axis=0) for m in muts_stack)
-                grads = self._reduce_grads(grads)
-                loss_val = jax.lax.pmean(loss_val, axis)
-                muts = tuple(jax.lax.pmean(m, axis) for m in muts)
-                new_vals, new_states = self._apply_groups(
-                    train_vals, states, grads, lr, t)
-                return loss_val, new_vals, new_states, muts
-
-            return replica_step
-
-        def replica_step(train_vals, states, aux_vals, x, y, key, lr, t):
-            def loss_of(tv):
-                outs, muts = fwd(tv, aux_vals, (x, y), key)
-                return outs[0], muts
-
-            (loss_val, muts), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(train_vals)
-            grads = self._reduce_grads(grads)
-            loss_val = jax.lax.pmean(loss_val, axis)
-            muts = tuple(jax.lax.pmean(m, axis) for m in muts)
-            new_vals, new_states = self._apply_groups(
-                train_vals, states, grads, lr, t)
-            return loss_val, new_vals, new_states, muts
-
-        return replica_step
+        the step ``_step_fn`` runs, seen from one shard of the data
+        axis, with the cross-replica collectives written out (grads, the
+        reported loss, and BatchNorm batch statistics are all global
+        under GSPMD).  Traced with ``jax.make_jaxpr(axis_env=[(data_axis,
+        K)])`` over ``_trace_args``: no hardware, no compilation.  By
+        ``lint()``/``cost_report()`` and the ``python -m
+        mxnet_tpu.analysis --cost`` budget models."""
+        return self._pure_step(self._data_axis)
 
     # -- static analysis hooks (mxnet_tpu.analysis) ------------------------
     def lint(self, data_shape=None, label_shape=None,
@@ -1547,8 +1307,6 @@ class DataParallelTrainer:
         bytes from the per-replica trace.  Never executes or compiles.
         A zero=1 trainer reports over the real runtime spelling
         (``zero_report``), whose collectives are explicit."""
-        import numpy as _onp
-
         from ..analysis import cost as _cost
 
         if self._plan is not None:
@@ -1561,61 +1319,20 @@ class DataParallelTrainer:
                 declared_axis_size=declared_axis_size)
             return report
 
-        if not self._ready:
-            if data_shape is None:
-                raise ValueError(
-                    "trainer has not stepped yet: pass data_shape (and "
-                    "label_shape)")
-            x0 = NDArray(jnp.zeros(tuple(data_shape),
-                                   _onp.dtype(data_dtype)))
-            y0 = NDArray(jnp.zeros(
-                tuple(label_shape or (data_shape[0],)),
-                _onp.dtype(label_dtype)))
-            self._setup(x0, y0)
-        data_shape = tuple(data_shape)
-        label_shape = tuple(label_shape or (data_shape[0],))
-        train_vals = tuple(self._params_by_name[n].data()._data
-                           for n in self._train_names)
-        aux_vals = tuple(self._params_by_name[n].data()._data
-                         for n in self._aux_names)
-        states = tuple(self._states_raw)
-        x = jax.ShapeDtypeStruct(data_shape, _onp.dtype(data_dtype))
-        y = jax.ShapeDtypeStruct(label_shape, _onp.dtype(label_dtype))
-        key = jax.ShapeDtypeStruct((2,), _onp.uint32)
-        fwd = self._fwd
-
-        def pure_step(train_vals, states, aux_vals, x, y, key, lr, t):
-            def loss_of(tv):
-                outs, muts = fwd(tv, aux_vals, (x, y), key)
-                return outs[0], muts
-
-            (loss_val, muts), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(train_vals)
-            new_vals, new_states = self._apply_groups(
-                train_vals, states, grads, lr, t)
-            return loss_val, new_vals, new_states, muts
-
+        shapes = (data_shape, label_shape, data_dtype, label_dtype)
+        args = self._trace_args(*shapes)
         report = _cost.analyze_fn(
-            pure_step, train_vals, states, aux_vals, x, y, key,
-            jnp.float32(0.01), jnp.int32(1),
+            self._pure_step(), *args,
             donate_argnums=(0, 1), host_argnums=(3, 4))
         # loss is the only fetched output; new params/states stay put
         report.transfer_d2h_bytes = 4
         # collective bytes from the per-replica spelling (the full-batch
         # jaxpr has no explicit collectives — GSPMD inserts them)
-        axis_sizes = dict(zip(self._mesh.axis_names,
-                              self._mesh.devices.shape))
-        ksize = int(declared_axis_size
-                    or axis_sizes.get(self._data_axis, 1))
-        shard = max(data_shape[0] // max(ksize, 1), 1)
-        xs = jax.ShapeDtypeStruct((shard,) + data_shape[1:],
-                                  _onp.dtype(data_dtype))
-        ys = jax.ShapeDtypeStruct((shard,) + label_shape[1:],
-                                  _onp.dtype(label_dtype))
+        ksize = int(declared_axis_size or self._zero_axis_size())
         try:
             rep = _cost.analyze_fn(
-                self._build_replica_step(), train_vals, states, aux_vals,
-                xs, ys, key, jnp.float32(0.01), jnp.int32(1),
+                self._build_replica_step(),
+                *self._trace_args(*shapes, axis_size=ksize),
                 axis_env=[(self._data_axis, ksize)])
             report.collective_bytes_per_axis = \
                 rep.collective_bytes_per_axis
@@ -1638,51 +1355,15 @@ class DataParallelTrainer:
         free; never executes or compiles.  A mesh_plan trainer returns
         its ``mesh_report`` ShardReport instead — the per-replica
         EXPLICIT mixed-axis schedule, priced per axis."""
-        import numpy as _onp
-
         from ..analysis import shard_prop as _sp
 
         if self._plan is not None:
             _, _, shard = self.mesh_report(data_shape=data_shape)
             return shard
 
-        if not self._ready:
-            if data_shape is None:
-                raise ValueError(
-                    "trainer has not stepped yet: pass data_shape (and "
-                    "label_shape)")
-            x0 = NDArray(jnp.zeros(tuple(data_shape),
-                                   _onp.dtype(data_dtype)))
-            y0 = NDArray(jnp.zeros(
-                tuple(label_shape or (data_shape[0],)),
-                _onp.dtype(label_dtype)))
-            self._setup(x0, y0)
-        data_shape = tuple(data_shape)
-        label_shape = tuple(label_shape or (data_shape[0],))
-        train_vals = tuple(self._params_by_name[n].data()._data
-                           for n in self._train_names)
-        aux_vals = tuple(self._params_by_name[n].data()._data
-                         for n in self._aux_names)
-        states = tuple(self._states_raw)
-        x = jax.ShapeDtypeStruct(data_shape, _onp.dtype(data_dtype))
-        y = jax.ShapeDtypeStruct(label_shape, _onp.dtype(label_dtype))
-        key = jax.ShapeDtypeStruct((2,), _onp.uint32)
-        fwd = self._fwd
-
-        def pure_step(train_vals, states, aux_vals, x, y, key, lr, t):
-            def loss_of(tv):
-                outs, muts = fwd(tv, aux_vals, (x, y), key)
-                return outs[0], muts
-
-            (loss_val, muts), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(train_vals)
-            new_vals, new_states = self._apply_groups(
-                train_vals, states, grads, lr, t)
-            return loss_val, new_vals, new_states, muts
-
-        closed = jax.make_jaxpr(pure_step)(
-            train_vals, states, aux_vals, x, y, key,
-            jnp.float32(0.01), jnp.int32(1))
+        args = self._trace_args(data_shape, label_shape, data_dtype,
+                                label_dtype)
+        closed = jax.make_jaxpr(self._pure_step())(*args)
         axis_sizes = dict(zip(self._mesh.axis_names,
                               self._mesh.devices.shape))
         axis_sizes[self._data_axis] = int(
@@ -1698,8 +1379,9 @@ class DataParallelTrainer:
             in_specs += [spec] * len(jax.tree_util.tree_leaves(raw))
         in_specs += [self._param_spec_fn(
             n, self._params_by_name[n].shape) for n in self._aux_names]
-        in_specs += [PartitionSpec(self._data_axis),
-                     PartitionSpec(self._data_axis), None, None, None]
+        # x, y, then the key and the scalars
+        in_specs += [PartitionSpec(self._data_axis)] * 2 \
+            + [None] * (len(args) - 5)
         return _sp.propagate(closed, mesh, in_specs,
                              subject="DataParallelTrainer")
 
@@ -1715,8 +1397,6 @@ class DataParallelTrainer:
         than ``FUSION_HINT_MIN_PCT`` of step bytes, the dispatch /
         collective phases are context-tagged ``fusable`` so ``telemetry
         doctor`` names the fusion knob (docs/fusion.md)."""
-        import numpy as _onp
-
         from ..analysis import fusion as _fusion
 
         if self._plan is not None:
@@ -1733,45 +1413,9 @@ class DataParallelTrainer:
             report = _fusion.fusion_from_jaxpr(closed,
                                                axis_sizes=axis_sizes)
         else:
-            if not self._ready:
-                if data_shape is None:
-                    raise ValueError(
-                        "trainer has not stepped yet: pass data_shape "
-                        "(and label_shape)")
-                x0 = NDArray(jnp.zeros(tuple(data_shape),
-                                       _onp.dtype(data_dtype)))
-                y0 = NDArray(jnp.zeros(
-                    tuple(label_shape or (data_shape[0],)),
-                    _onp.dtype(label_dtype)))
-                self._setup(x0, y0)
-            data_shape = tuple(data_shape)
-            label_shape = tuple(label_shape or (data_shape[0],))
-            train_vals = tuple(self._params_by_name[n].data()._data
-                               for n in self._train_names)
-            aux_vals = tuple(self._params_by_name[n].data()._data
-                             for n in self._aux_names)
-            states = tuple(self._states_raw)
-            x = jax.ShapeDtypeStruct(data_shape, _onp.dtype(data_dtype))
-            y = jax.ShapeDtypeStruct(label_shape,
-                                     _onp.dtype(label_dtype))
-            key = jax.ShapeDtypeStruct((2,), _onp.uint32)
-            fwd = self._fwd
-
-            def pure_step(train_vals, states, aux_vals, x, y, key, lr,
-                          t):
-                def loss_of(tv):
-                    outs, muts = fwd(tv, aux_vals, (x, y), key)
-                    return outs[0], muts
-
-                (loss_val, muts), grads = jax.value_and_grad(
-                    loss_of, has_aux=True)(train_vals)
-                new_vals, new_states = self._apply_groups(
-                    train_vals, states, grads, lr, t)
-                return loss_val, new_vals, new_states, muts
-
-            report = _fusion.fusion_from_fn(
-                pure_step, train_vals, states, aux_vals, x, y, key,
-                jnp.float32(0.01), jnp.int32(1))
+            args = self._trace_args(data_shape, label_shape, data_dtype,
+                                    label_dtype)
+            report = _fusion.fusion_from_fn(self._pure_step(), *args)
 
         self._last_fusion_report = report
         # doctor follow-through: a dominant dispatch/collective phase
@@ -1784,49 +1428,6 @@ class DataParallelTrainer:
                 if phase not in context:
                     attr.set_context(phase, "fusable")
         return report
-
-    def _build_grad_step(self):
-        """Dist split-step, part 1: loss + local gradients (no update) —
-        the grads cross the process boundary through the kvstore between
-        the two jits (reference: executor backward -> kv.push,
-        python/mxnet/module/executor_group.py:583)."""
-        fwd = self._fwd
-
-        def pure_grads(train_vals, aux_vals, x, y, key):
-            def loss_of(tv):
-                outs, muts = fwd(tv, aux_vals, (x, y), key)
-                return outs[0], muts
-
-            (loss_val, muts), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(train_vals)
-            # flatten inside the jit: the host sees one fused f32 vector
-            # (grads + the loss scalar riding along) ready to push
-            flat = jnp.concatenate(
-                [g.ravel().astype(jnp.float32) for g in grads]
-                + [loss_val.reshape(1).astype(jnp.float32)])
-            return flat, muts
-
-        return jax.jit(pure_grads)
-
-    def _build_update_step(self):
-        """Dist split-step, part 2: scale the pulled grad-sum, split it
-        back per-param, apply the optimizer — all in one jit (reference:
-        kv.pull -> updater, python/mxnet/model.py:157)."""
-        sizes = self._flat_sizes
-        scale = 1.0 / self._kv.num_workers
-
-        def pure_update(train_vals, states, flat_sum, lr, t):
-            mean = flat_sum * scale
-            grads, off = [], 0
-            for tv, n in zip(train_vals, sizes):
-                grads.append(mean[off:off + n].reshape(tv.shape)
-                             .astype(tv.dtype))
-                off += n
-            new_vals, new_states = self._apply_groups(
-                train_vals, states, tuple(grads), lr, t)
-            return mean[-1], new_vals, new_states
-
-        return jax.jit(pure_update, donate_argnums=(0, 1))
 
     # -- public API --------------------------------------------------------
     @property
@@ -1894,14 +1495,56 @@ class DataParallelTrainer:
         return jnp.float32(lr_host), jnp.int32(self._step_count)
 
     def _step_args(self, train_vals, aux_vals, x, y, rng, lr, count):
-        """Positional arguments of the replicated tier's compiled step
-        (``_build_step``) — the one assembly :meth:`step` dispatches
-        with and :meth:`lower_step` lowers with."""
+        """Positional arguments of the gluon tiers' step (``_pure_step``;
+        the split tiers take theirs from it) — the one assembly
+        :meth:`step` dispatches with, :meth:`lower_step` lowers with
+        and ``_trace_args`` traces with."""
         args = (train_vals, tuple(self._states_raw), aux_vals, x, y, rng,
                 lr, count)
         if self._reduced:
             args += (self._ls_scale, self._ls_good, self._ls_skipped)
         return args
+
+    def _setup_from_shapes(self, data_shape, label_shape=None,
+                           data_dtype="float32", label_dtype="int32"):
+        """``(data_shape, label_shape)`` as tuples, the label's defaulting
+        to one per row; a trainer that has not stepped is set up from
+        zeros of that geometry first (the analysis hooks need the
+        parameters and optimizer states, never a real batch)."""
+        if data_shape is None:
+            raise ValueError("pass data_shape (and label_shape): the "
+                             "step is traced at a declared batch geometry")
+        data_shape = tuple(data_shape)
+        label_shape = tuple(label_shape or (data_shape[0],))
+        if not self._ready:
+            self._setup(
+                NDArray(jnp.zeros(data_shape, np.dtype(data_dtype))),
+                NDArray(jnp.zeros(label_shape, np.dtype(label_dtype))))
+        return data_shape, label_shape
+
+    def _trace_args(self, data_shape, label_shape=None,
+                    data_dtype="float32", label_dtype="int32",
+                    axis_size=None):
+        """``_step_args`` to TRACE the replicated tier's step with
+        (``_pure_step``, ``_build_replica_step``): the live parameters
+        and optimizer states, and shapes in place of the batch and the
+        key.  With ``axis_size`` the batch is one replica's shard at that
+        declared size of the data axis, else the whole batch.  The one
+        assembly behind ``cost_report``, ``shard_report``,
+        ``fusion_report``, ``analysis/dist_lint.lint_trainer`` and the
+        replicated twins of the ZeRO-1 proofs."""
+        data_shape, label_shape = self._setup_from_shapes(
+            data_shape, label_shape, data_dtype, label_dtype)
+        rows = data_shape[0] if axis_size is None else \
+            max(data_shape[0] // max(int(axis_size), 1), 1)
+        return self._step_args(
+            *self._live_vals(),
+            jax.ShapeDtypeStruct((rows,) + data_shape[1:],
+                                 np.dtype(data_dtype)),
+            jax.ShapeDtypeStruct((rows,) + label_shape[1:],
+                                 np.dtype(label_dtype)),
+            jax.ShapeDtypeStruct((2,), np.uint32),
+            jnp.float32(0.01), jnp.int32(1))
 
     def _put_batch(self, arr, sharding):
         """``device_put`` with a fast path: a committed ``jax.Array``
@@ -2020,10 +1663,9 @@ class DataParallelTrainer:
         ``step.backpressure``); the ``on_step`` mark closes the previous
         step's attribution window and stores the flight-ring progress
         cursor, and each child's duration feeds its phase."""
-        if self._plan is not None:
-            return self._step_mesh_tier(data, label)
         if not self._ready:
-            self._setup(data, label)
+            (self._setup if self._plan is None else self._setup_mesh)(
+                data, label)
         return self._under_step_span(self._step, data, label)
 
     def _under_step_span(self, body, data, label):
@@ -2041,8 +1683,10 @@ class DataParallelTrainer:
                         functools.partial(_trace.span, step=number))
 
     def _step(self, data, label, attr, span):
-        """The step itself.  ``attr`` is the armed ``StepAttribution`` or
-        None; ``span(name)`` opens a child span of this step, or is
+        """The step itself, the one host driver of every tier: h2d ->
+        prepare -> the tier's enqueue -> commit -> run-ahead
+        bookkeeping.  ``attr`` is the armed ``StepAttribution`` or None;
+        ``span(name)`` opens a child span of this step, or is
         ``_no_span``."""
         from .. import _rng
         in_flight = self._unfinished() if attr else 0
@@ -2065,57 +1709,72 @@ class DataParallelTrainer:
             self._opt.num_update = self._step_count
             lr_host = (self._opt.lr_scheduler(self._step_count)
                        if self._opt.lr_scheduler else self._opt.lr)
-            train_vals, aux_vals = self._live_vals()
             rng = _rng.next_key()
             lr, count = self._step_scalars(lr_host)
-            if self._kv is None and not self._zero:
-                # jax.jit itself retraces and caches per input
-                # shape/dtype
-                if self._step_fn is None:
-                    self._step_fn = self._build_step()
-                    if attr and self._grad_accum > 1:
-                        attr.set_context("dispatch", "grad_accum")
-                args = self._step_args(train_vals, aux_vals, x, y, rng,
-                                       lr, count)
+            if self._plan is not None:
+                args = (tuple(self._mesh_params[n]
+                              for n in self._mesh_param_names),
+                        self._mesh_state_leaves, x, y, rng, lr, count)
+            else:
+                args = self._step_args(*self._live_vals(), x, y, rng, lr,
+                                       count)
         if attr:
             # step bookkeeping (arg tuples, lr, the key) is host work; the
             # small programs it enqueues can be held back like the step
             attr.add_phase("dispatch", self._own_cost(
                 self._prepare_split, sp.seconds, in_flight))
 
-        if self._kv is not None:
-            loss_val, new_vals, new_states, muts = self._dist_step(
-                train_vals, aux_vals, x, y, rng, lr, count, attr, span)
-            self._states_raw = list(new_states)
-        elif self._zero:
-            # split step: grads + reduce-scatter, then sharded update +
-            # all-gather — states updated inside (they live as one
-            # sharded flat tree, not per-group)
-            loss_val, new_vals, muts = self._zero_step(
-                train_vals, aux_vals, x, y, rng, lr, count, attr, span)
-        else:
-            out, own = self._enqueue(span, self._step_fn, *args)
-            if attr:
-                # the jitted call's own cost is host work; what the
-                # runtime held it back for is a wait on the device
-                attr.add_phase("dispatch", own)
-            if self._reduced:
-                (loss_val, new_vals, new_states, muts, self._ls_scale,
-                 self._ls_good, self._ls_skipped) = out
-            else:
-                loss_val, new_vals, new_states, muts = out
-            self._states_raw = list(new_states)
+        # the tier enqueues its program(s), keeps the new optimizer
+        # states and bills the calls' own cost to its phases
+        loss_val, new_vals, muts = self._tier_enqueue()(args, attr, span)
 
         sp = span("step.commit")
         with sp:
-            for name, val in zip(self._train_names, new_vals):
-                self._params_by_name[name]._data._set_data(val)
-            for name, val in zip(self._fwd.mut_names or (), muts):
-                self._params_by_name[name]._data._set_data(val)
+            if self._plan is not None:
+                self._mesh_params.update(zip(self._mesh_param_names,
+                                             new_vals))
+            else:
+                for name, val in zip(self._train_names, new_vals):
+                    self._params_by_name[name]._data._set_data(val)
+                for name, val in zip(self._fwd.mut_names or (), muts):
+                    self._params_by_name[name]._data._set_data(val)
         if attr:
             attr.add_phase("dispatch", sp.seconds)
         self._track_inflight(loss_val)
         return NDArray(loss_val)
+
+    def _tier_enqueue(self):
+        """The tier this trainer runs, as its enqueue method:
+        ``enqueue(args, attr, span) -> (loss, new_vals, muts)`` over the
+        arguments ``step.prepare`` assembled."""
+        if self._plan is not None:
+            return self._enqueue_mesh
+        if self._kv is not None:
+            return self._enqueue_dist
+        return self._enqueue_zero if self._zero else \
+            self._enqueue_replicated
+
+    def _enqueue_replicated(self, args, attr, span):
+        """The replicated tier's enqueue: ONE jitted program a step
+        (``parallel/step.py::build_runtime_fn``; ``jax.jit`` itself
+        retraces and caches per input shape/dtype)."""
+        if self._step_fn is None:
+            self._step_fn = _step.build_runtime_fn(
+                self._fwd, self._apply_groups,
+                compute_dtype=self._compute_dtype,
+                grad_accum=self._grad_accum)
+            if attr and self._grad_accum > 1:
+                attr.set_context("dispatch", "grad_accum")
+        out, own = self._enqueue(span, self._step_fn, *args)
+        if attr:
+            # the jitted call's own cost is host work; what the
+            # runtime held it back for is a wait on the device
+            attr.add_phase("dispatch", own)
+        loss_val, new_vals, new_states, muts = out[:4]
+        if self._reduced:
+            self._ls_scale, self._ls_good, self._ls_skipped = out[4:]
+        self._states_raw = list(new_states)
+        return loss_val, new_vals, muts
 
     # -- checkpoint / resume (mxnet_tpu.resilience) ------------------------
     def save_checkpoint(self, directory, epoch=None, nbatch=None, keep=3):
@@ -2618,8 +2277,7 @@ class DataParallelTrainer:
         except OSError:
             log.exception("metrics dump to %s failed", path)
 
-    def _dist_step(self, train_vals, aux_vals, x, y, rng, lr, count, attr,
-                   span):
+    def _enqueue_dist(self, args, attr, span):
         """Split step for multi-process data parallelism: local grads ->
         kvstore push/pull (summed across workers by the PS sync round) ->
         average -> donated optimizer update.  Averaging the per-worker
@@ -2627,12 +2285,13 @@ class DataParallelTrainer:
         gradient exactly (equal shards), so N workers with batch B/N match
         one process with batch B to float tolerance — the property
         tests/test_dist.py asserts (reference: tests/nightly/dist_lenet.py)."""
+        train_vals, states, aux_vals, x, y, rng, lr, count = args
         if self._grad_fn is None:
-            self._grad_fn = self._build_grad_step()
-            self._update_fn = self._build_update_step()
+            self._grad_fn, self._update_fn = _step.build_split_fns(
+                self._fwd, self._apply_groups, self._flat_sizes,
+                self._kv.num_workers)
         (flat, muts), own = self._enqueue(
-            span, self._grad_fn,
-            train_vals, aux_vals, x, y, rng)
+            span, self._grad_fn, train_vals, aux_vals, x, y, rng)
         if attr:
             attr.add_phase("dispatch", own)
         sp = span("step.exchange")
@@ -2641,16 +2300,13 @@ class DataParallelTrainer:
             self._kv.pull(self._flat_key, out=self._flat_out)
         if attr:
             attr.add_phase("collective_or_ps", sp.seconds)
-        # global-batch mean loss comes back out of the update jit, so
-        # every rank's callbacks see the number the single-process run
-        # would (a local loss would diverge across ranks)
         (loss_val, new_vals, new_states), own = self._enqueue(
-            span, self._update_fn,
-            train_vals, tuple(self._states_raw), self._flat_out._data,
-            lr, count)
+            span, self._update_fn, train_vals, states,
+            self._flat_out._data, lr, count)
         if attr:
             attr.add_phase("dispatch", own)
-        return loss_val, new_vals, new_states, muts
+        self._states_raw = list(new_states)
+        return loss_val, new_vals, muts
 
     def set_learning_rate(self, lr):
         self._opt.set_learning_rate(lr)

@@ -13,7 +13,7 @@ ways, so the executed program and the analyzed program can never drift:
   optimizer state lives as ONE flat ``(padded,)`` array per state leaf,
   sharded ``P(axis)`` over the data axis: each device physically holds
   ``1/K`` of it — the ZeRO-1 memory saving is real, not modeled.  The
-  two-program split mirrors ``_dist_step``'s grad→exchange→update shape,
+  two-program split mirrors ``_enqueue_dist``'s grad→exchange→update shape,
   which is what lets the performance doctor bill the reduce-scatter/
   all-gather program to the ``collective_or_ps`` phase.
 - **analysis**: :func:`build_replica_step` composes the same two parts
@@ -143,64 +143,33 @@ def build_parts(fwd, opt, plan, state_treedef, compute_dtype=None,
     from jax import lax
 
     from .functional import functional_optimizer_update
+    from .step import local_grads
 
     axis, k, shard = plan.axis, plan.k, plan.shard
 
     if compute_dtype is not None and \
             jnp.dtype(compute_dtype) != jnp.float32:
-        if int(grad_accum or 1) > 1:
-            raise ValueError("grad_accum is not supported with a "
-                             "reduced compute dtype (see "
-                             "DataParallelTrainer)")
         return _build_parts_reduced(fwd, opt, plan, state_treedef,
-                                    jnp.dtype(compute_dtype))
+                                    jnp.dtype(compute_dtype), grad_accum)
 
-    n_acc = int(grad_accum or 1)
-    if n_acc > 1:
-        # grad_accum spelling (docs/distributed.md): the shard-local
-        # batch splits into microbatches accumulated left-to-right
-        # (functional.accumulate_grads — the SAME helper the replicated
-        # trainer jits), then ONE reduce-scatter of the summed flat
-        # gradient: the collective count and wire bytes are unchanged
-        # vs n_acc=1, which keeps DST006's one-reduction contract
-        from .functional import accumulate_grads
+    # under grad_accum (docs/distributed.md) the shard-local batch is
+    # accumulated over its microbatches first, then ONE reduce-scatter of
+    # the flat gradient: the collective count and wire bytes are
+    # unchanged, which keeps DST006's one-reduction contract
+    grads_of = local_grads(fwd, grad_accum=grad_accum)
 
-        def grads_part(train_vals, aux_vals, x, y, key):
-            def grad_of(tv, xi, yi):
-                def loss_of(t_):
-                    outs, muts = fwd(t_, aux_vals, (xi, yi), key)
-                    return outs[0], muts
-                return jax.value_and_grad(loss_of, has_aux=True)(tv)
-
-            grads_sum, loss_sum, muts_stack = accumulate_grads(
-                grad_of, train_vals, x, y, n_acc)
-            grads = tuple(g / n_acc for g in grads_sum)
-            with jax.named_scope("grad_reduce"):
-                flat_g = _flatten_pad(grads, plan, jnp)
-                g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
-                                        tiled=True) / k
-                loss_val = lax.pmean(loss_sum / n_acc, axis)
-                muts = tuple(lax.pmean(m.mean(axis=0), axis)
-                             for m in muts_stack)
-            return g_sh, loss_val, muts
-    else:
-        def grads_part(train_vals, aux_vals, x, y, key):
-            def loss_of(tv):
-                outs, muts = fwd(tv, aux_vals, (x, y), key)
-                return outs[0], muts
-
-            (loss_val, muts), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(train_vals)
-            # reduce-scatter lands exactly this rank's owned gradient
-            # shard; /k turns the psum semantics into the gradient mean
-            # every replicated spelling uses
-            with jax.named_scope("grad_reduce"):
-                flat_g = _flatten_pad(grads, plan, jnp)
-                g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
-                                        tiled=True) / k
-                loss_val = lax.pmean(loss_val, axis)
-                muts = tuple(lax.pmean(m, axis) for m in muts)
-            return g_sh, loss_val, muts
+    def grads_part(train_vals, aux_vals, x, y, key):
+        loss_val, muts, grads = grads_of(train_vals, aux_vals, x, y, key)
+        # reduce-scatter lands exactly this rank's owned gradient
+        # shard; /k turns the psum semantics into the gradient mean
+        # every replicated spelling uses
+        with jax.named_scope("grad_reduce"):
+            flat_g = _flatten_pad(grads, plan, jnp)
+            g_sh = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
+                                    tiled=True) / k
+            loss_val = lax.pmean(loss_val, axis)
+            muts = tuple(lax.pmean(m, axis) for m in muts)
+        return g_sh, loss_val, muts
 
     @jax.named_scope("optimizer_update")
     def update_part(train_vals, state_leaves, g_sh, lr, t):
@@ -235,7 +204,8 @@ def build_parts(fwd, opt, plan, state_treedef, compute_dtype=None,
     return grads_part, update_part
 
 
-def _build_parts_reduced(fwd, opt, plan, state_treedef, compute_dtype):
+def _build_parts_reduced(fwd, opt, plan, state_treedef, compute_dtype,
+                         grad_accum=1):
     """The mixed-precision halves (see :func:`build_parts` docstring):
     bf16 compute, f32 masters-in-the-shard, f32 gradient reduction,
     loss scaling with select-skip.  Split out so the f32 spelling's
@@ -246,31 +216,17 @@ def _build_parts_reduced(fwd, opt, plan, state_treedef, compute_dtype):
 
     from .. import precision as _prec
     from .functional import functional_optimizer_update
+    from .step import local_grads
 
     axis, k, shard = plan.axis, plan.k, plan.shard
 
-    def _to_compute(v):
-        # only floating leaves move to bf16 — integer labels/ids stay put
-        if hasattr(v, "dtype") and jnp.issubdtype(v.dtype, jnp.floating):
-            return v.astype(compute_dtype)
-        return v
+    # the trained values arrive in the compute dtype already: the
+    # masters live in the shard (grad_accum > 1 is refused here)
+    grads_of = local_grads(fwd, compute_dtype, grad_accum)
 
     def grads_part(train_vals, aux_vals, x, y, key, scale):
-        # the batch and any floating aux enter the forward in the
-        # compute dtype too, else f32 inputs silently promote the
-        # activations back to f32 and the bytes win evaporates
-        x_c = _to_compute(x)
-        aux_c = tuple(_to_compute(a) for a in aux_vals)
-
-        def loss_of(tv):
-            outs, muts = fwd(tv, aux_c, (x_c, y), key)
-            raw = outs[0].astype(jnp.float32)
-            # the SCALED loss drives the backward so bf16 grads don't
-            # flush; the raw loss rides aux for reporting
-            return raw * scale, (raw, muts)
-
-        (_, (loss_val, muts)), grads = jax.value_and_grad(
-            loss_of, has_aux=True)(train_vals)
+        loss_val, muts, grads = grads_of(train_vals, aux_vals, x, y, key,
+                                         scale)
         with jax.named_scope("grad_reduce"):
             if _prec.PRECISION_F32_GRAD_REDUCE:
                 # cast BEFORE the collective: the ring reduction must run

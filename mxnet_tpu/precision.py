@@ -90,6 +90,17 @@ def is_reduced(dtype):
     return jnp.dtype(dtype) == jnp.dtype("bfloat16")
 
 
+def _to_compute(v, dtype):
+    """``v`` in the compute ``dtype`` if it is a floating leaf; integer
+    leaves (labels, token ids) stay put.  The one cast every tier's step
+    makes at its forward boundary."""
+    import jax.numpy as jnp
+
+    if hasattr(v, "dtype") and jnp.issubdtype(v.dtype, jnp.floating):
+        return v.astype(dtype)
+    return v
+
+
 def init_loss_scale(init=LOSS_SCALE_INIT):
     """``(scale, good_steps)`` — the device-resident loss-scale state:
     f32 scalar scale, i32 consecutive-finite-step counter."""

@@ -57,13 +57,8 @@ def _zero1_trainer(k_devices, zero=1):
 
 def _modeled_drop_pct():
     """The runtime-tape ZeRO-1 HBM story at the pinned geometry."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
     from mxnet_tpu.analysis.cost import analyze_fn
     from mxnet_tpu.analysis.shard_fixtures import ZERO1_GEOMETRY as g
-    from mxnet_tpu.ndarray import NDArray
 
     k = 8
     data_shape = (g["batch"] * k, g["in_dim"])
@@ -74,21 +69,10 @@ def _modeled_drop_pct():
         label_dtype="int32", declared_axis_size=k)
     errors = [f for f in findings]
     tw = _zero1_trainer(1, zero=0)
-    tw._setup(NDArray(jnp.zeros(data_shape, np.float32)),
-              NDArray(jnp.zeros(label_shape, np.int32)))
-    train_vals = tuple(tw._params_by_name[n].data()._data
-                       for n in tw._train_names)
-    aux_vals = tuple(tw._params_by_name[n].data()._data
-                     for n in tw._aux_names)
-    states = tuple(tw._states_raw)
-    xs = jax.ShapeDtypeStruct((g["batch"], g["in_dim"]), np.float32)
-    ys = jax.ShapeDtypeStruct((g["batch"],), np.int32)
-    key = jax.ShapeDtypeStruct((2,), np.uint32)
+    args = tw._trace_args(data_shape, label_shape, axis_size=k)
     twin = analyze_fn(
-        tw._build_replica_step(), train_vals, states, aux_vals, xs, ys,
-        key, jnp.float32(0.01), jnp.int32(1),
-        axis_env=[("data", k)], donate_argnums=(0, 1),
-        host_argnums=(3, 4))
+        tw._build_replica_step(), *args, axis_env=[("data", k)],
+        donate_argnums=(0, 1), host_argnums=(3, 4))
     drop = twin.peak_hbm_bytes - rep.peak_hbm_bytes
     return {
         "zero1_modeled_hbm_drop_pct": round(

@@ -111,6 +111,8 @@ def build_parts(program, apply_update, state_leaf_counts, zero=0,
     import jax.numpy as jnp
     from jax import lax
 
+    from ..precision import _to_compute
+
     plan = program.plan
     batch_axes = plan.batch_axes()
     if zero and zero_plan is None:
@@ -118,19 +120,14 @@ def build_parts(program, apply_update, state_leaf_counts, zero=0,
     reduced = (compute_dtype is not None
                and jnp.dtype(compute_dtype) != jnp.float32)
 
-    def _to_compute(v):
-        if reduced and hasattr(v, "dtype") \
-                and jnp.issubdtype(v.dtype, jnp.floating):
-            return v.astype(compute_dtype)
-        return v
-
     def grads_part(train_vals, x, y, key):
         if reduced:
-            x_c = _to_compute(x)
+            x_c = _to_compute(x, compute_dtype)
 
             def loss_of(tv):
                 return program.loss_replica(
-                    tuple(_to_compute(w) for w in tv), x_c, y, key)
+                    tuple(_to_compute(w, compute_dtype) for w in tv),
+                    x_c, y, key)
 
             loss, grads = jax.value_and_grad(loss_of)(tuple(train_vals))
             loss = loss.astype(jnp.float32)

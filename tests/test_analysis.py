@@ -830,9 +830,8 @@ def test_trainer_lint_clean():
 def test_trainer_lint_catches_removed_grad_psum(monkeypatch):
     """The acceptance bug class: the gradient reduction deleted from
     DataParallelTrainer — every trainable param raises DST001."""
-    from mxnet_tpu.parallel import DataParallelTrainer
-    monkeypatch.setattr(DataParallelTrainer, "_reduce_grads",
-                        lambda self, grads: grads)
+    from mxnet_tpu.parallel import step
+    monkeypatch.setattr(step, "reduce_grads", lambda grads, axis: grads)
     tr = _make_trainer()
     findings = tr.lint(data_shape=(64, 16), label_shape=(64,))
     assert "DST001" in rules(findings)

@@ -467,7 +467,7 @@ def _make_rec(tmp_path, n=24, size=32):
     return rec, idx
 
 
-def _run_fleet(tmp_path, tag, epochs, rank1_chaos):
+def _run_fleet(tmp_path, tag, epochs, rank1_chaos, rank0_chaos=None):
     tele = str(tmp_path / ("tele_" + tag))
     os.makedirs(tele)
     rec, idx = _make_rec(tmp_path)
@@ -490,8 +490,8 @@ def _run_fleet(tmp_path, tag, epochs, rank1_chaos):
     try:
         for rank in (0, 1):
             env = _cpu_env(MXTPU_TELEMETRY_DIR=tele, DMLC_WORKER_ID=rank)
-            if rank == 1 and rank1_chaos:
-                env["MXTPU_CHAOS"] = rank1_chaos
+            if (rank0_chaos, rank1_chaos)[rank]:
+                env["MXTPU_CHAOS"] = (rank0_chaos, rank1_chaos)[rank]
             workers.append(subprocess.Popen(
                 [sys.executable, "-c", _WORKER_SRC, str(port), tele,
                  str(rank), str(epochs), rec, idx],
@@ -527,7 +527,12 @@ def test_two_worker_straggler_doctor_end_to_end(tmp_path):
       input_wait;
     - per-rank phase sums reconcile with measured wall time within the
       documented tolerance;
-    - the same run with no fault reports balanced ranks.
+    - the same run with the delay on BOTH ranks reports balanced ranks:
+      a fleet that is slow everywhere has no straggler.  (With no delay
+      at all a rank's mean step is its first step's compile over a few
+      3 ms steps, and under the load of the other test workers one
+      rank's compile takes twice the other's: the verdict was a coin.
+      Steps that sleeping sets make it a ratio load cannot move.)
     """
     pytest.importorskip("cv2")
     # one delay per dispatched batch: 6 batches/epoch x 6 epochs = 36
@@ -576,9 +581,15 @@ def test_two_worker_straggler_doctor_end_to_end(tmp_path):
     assert "input_wait" in out.stdout
     assert "preprocess_threads" in out.stdout
 
-    # (4) the identical run with no fault: balanced ranks
-    tele2 = _run_fleet(tmp_path, "clean", epochs=3, rank1_chaos=None)
+    # (4) the identical delay on both ranks: balanced ranks.  For one
+    # rank to read twice the other its compile would have to take 3.4 s
+    # (17 counted steps x 0.2 s) longer than twice the other's
+    spec = ",".join(spec.split(",")[:20])
+    tele2 = _run_fleet(tmp_path, "even", epochs=3, rank1_chaos=spec,
+                       rank0_chaos=spec)
     report2 = telemetry.doctor_report(tele2)
     assert report2["stragglers"] == []
     assert report2["events"]["straggler"] == []
     assert report2["balanced"]
+    even = report2["ranks"]
+    assert even["worker0"]["steps"] == even["worker1"]["steps"] == 18

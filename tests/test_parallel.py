@@ -104,49 +104,6 @@ def test_batchnorm_aux_updates_under_parallel_step():
     assert not np.allclose(before, after)
 
 
-def test_grouped_updates_optin_matches_default(monkeypatch):
-    """MXTPU_GROUP_UPDATES=1 (fused small-param buckets) is numerically
-    identical to per-param updates (opt-in: measured slower end-to-end on
-    resnet-50/v5e, docs/perf_resnet50_tpu.md r3)."""
-    import numpy as np
-    import jax
-    import mxnet_tpu as mx
-    from mxnet_tpu import gluon
-    from mxnet_tpu.parallel import DataParallelTrainer, make_mesh
-
-    def run(grouped):
-        if grouped:
-            monkeypatch.setenv("MXTPU_GROUP_UPDATES", "1")
-        else:
-            monkeypatch.delenv("MXTPU_GROUP_UPDATES", raising=False)
-        mx.random.seed(7)
-        net = gluon.nn.HybridSequential()
-        net.add(gluon.nn.Dense(16, activation="relu"),
-                gluon.nn.Dense(4))
-        net.initialize(mx.init.Xavier())
-        mesh = make_mesh((len(jax.devices()),), ("data",), jax.devices())
-        tr = DataParallelTrainer(
-            net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
-            {"learning_rate": 0.1, "momentum": 0.9}, mesh=mesh)
-        rng = np.random.RandomState(0)
-        X = mx.nd.array(rng.rand(16, 8).astype(np.float32))
-        y = mx.nd.array((np.arange(16) % 4).astype(np.float32))
-        for _ in range(5):
-            loss = tr.step(X, y)
-        # positional order: gluon name counters differ between the runs
-        params = [v.data().asnumpy()
-                  for v in net.collect_params().values()]
-        return float(loss.asscalar()), params, tr
-
-    loss_g, params_g, tr_g = run(True)
-    assert any(len(g) > 1 for g in tr_g._groups), tr_g._groups
-    loss_d, params_d, tr_d = run(False)
-    assert all(len(g) == 1 for g in tr_d._groups)
-    assert abs(loss_g - loss_d) < 1e-5, (loss_g, loss_d)
-    for a, b in zip(params_g, params_d):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
-
-
 def test_maxpool_custom_vjp_optin_matches_default(monkeypatch):
     """MXTPU_MAXPOOL_VJP=1 (offset-sum backward) matches
     select_and_scatter gradients on tie-free data."""
