@@ -479,6 +479,7 @@ def lint_kernel_costs(disable=(), root=None):
     # registrations; the AST names below are checked against the result
     from ..ops import pallas_kernels as _pk          # noqa: F401
     from ..ops import fused_optimizer as _fo         # noqa: F401
+    from ..ops import ssd_kernels as _ssd            # noqa: F401
 
     kernels, dynamic = pallas_kernels_used(root)
     findings = []

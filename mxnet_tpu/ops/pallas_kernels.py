@@ -9,8 +9,8 @@ the standard TPU flash pattern (see /opt/skills/guides/pallas_guide.md).
 On non-TPU backends the same kernel runs in Pallas interpret mode, so
 tests exercise the real kernel logic on the CPU mesh.  That choice is
 made in exactly one place, :func:`resolve_interpret`; every kernel in
-the package (here, ``fused_optimizer.py``, ``generated_kernels.py``)
-goes through it.
+the package (here, ``fused_optimizer.py``, ``generated_kernels.py``,
+``ssd_kernels.py``) goes through it.
 
 Training: forward AND backward are Pallas kernels.  The forward emits the
 per-row logsumexp; the backward recomputes probabilities blockwise from

@@ -1024,11 +1024,13 @@ def render_doctor(report):
         if compiled.get("ssm_layers"):
             lines.append(
                 "   %d Mamba-2 layer(s) in the traced programs, the scan in "
-                "%d chunk(s) a sequence; %d layer(s) recomputed in the "
+                "%d chunk(s) a sequence, as a Pallas kernel pair in %d of "
+                "them; %d layer(s) recomputed in the "
                 "backward pass, %d of them with their projection products "
                 "kept (%.2f GB)"
                 % (compiled["ssm_layers"],
                    compiled.get("ssm_chunks_per_seq", 0),
+                   compiled.get("ssm_kernel_layers", 0),
                    compiled.get("recomputed_layers", 0),
                    compiled.get("kept_product_layers", 0),
                    compiled.get("kept_product_bytes", 0) / 1e9))

@@ -29,8 +29,13 @@ One ``jax.monitoring`` duration listener, registered when
   programs traced so far (``transformer/hybrid.py``, once per layer and
   trace: 9 and 10 for one trace of the ten-layer ``granite-4.0-h-micro``
   step);
-- ``kept_product_layers``: of those layers, the ones traced with their
-  projection products kept for the backward pass
+- ``ssm_kernel_layers``: of the ``ssm_layers``, the ones whose chunked scan
+  was traced as the Pallas kernel pair of ``ops/ssd_kernels.py``
+  (``ssd_kernels.tiles`` decides from the scan's shapes and the compute
+  dtype: 9 in that step, 0 at a size that does not tile, where the scan is
+  ``ssd_chunked``'s einsums);
+- ``kept_product_layers``: of the ``recomputed_layers``, the ones traced
+  with their projection products kept for the backward pass
   (``transformer/hybrid.py::keeps_products`` decides from the shapes and
   the device's memory: 10 in that step on a v5e, 0 where they do not fit);
 - ``ssm_chunks_per_seq``, ``kept_product_bytes``: chunks the state-space
@@ -57,7 +62,8 @@ LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "blocked_bias_grads", "ssm_layers", "recomputed_layers",
-         "ssm_chunks_per_seq", "kept_product_layers", "kept_product_bytes")
+         "ssm_chunks_per_seq", "kept_product_layers", "kept_product_bytes",
+         "ssm_kernel_layers")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

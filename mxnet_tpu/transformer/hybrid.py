@@ -222,13 +222,15 @@ def _layer_live_bytes(cfg, mixer, batch, seq, dtype):
     shapes: every intermediate of the layer once in ``dtype``, and four
     float32 arrays of the mixer's scores (the scan's decay matrix of every
     chunk and its masked product with ``C B^T``, or one block of query rows
-    against the keys; each with its gradient)."""
+    against the keys; each with its gradient).  A scan that runs as the
+    kernel pair (``ssm.scan_kernel_tiles``) has no such array."""
     d, f = cfg.d_model, cfg.d_ff
     if mixer == "mamba":
         inner, n = cfg.ssm_inner, cfg.ssm_state
         chunks = -(-seq // cfg.ssm_chunk)
         widths = 2 * (inner + 2 * n) + 3 * inner
-        scores = batch * chunks * cfg.ssm_heads * cfg.ssm_chunk ** 2
+        scores = 0 if ssm.scan_kernel_tiles(cfg, dtype) else \
+            batch * chunks * cfg.ssm_heads * cfg.ssm_chunk ** 2
     else:
         widths = cfg.n_heads * cfg.head_dim
         scores = batch * cfg.n_heads * min(cfg.attention_block, seq) * seq
@@ -422,6 +424,8 @@ class HybridProgram(ProgramLayout):
             _compiles.count("kept_product_layers", int(keep))
             if mixer == "mamba":
                 _compiles.count("ssm_layers")
+                _compiles.count("ssm_kernel_layers",
+                                int(ssm.scan_kernel_tiles(cfg, dtype)))
             with jax.named_scope("l%d" % i):
                 h = layer(lp, h)
         _compiles.note("ssm_chunks_per_seq",

@@ -1,6 +1,14 @@
 """Mamba-2 (state-space duality, Dao & Gu, arXiv:2405.21060) as a mixer of
 ``transformer/hybrid.py``: the chunked scan whose backward the training
-step runs, in plain ``jax.numpy``/``lax`` (autodiff gives the backward).
+step runs.  :func:`ssd_chunked` has two spellings of the same terms, chosen
+while it is traced from the scan's shapes (``ops/ssd_kernels.py::tiles``, a
+pure function of chunk, heads, head width, state and dtype): where they
+tile, a Pallas kernel pair behind a ``jax.custom_vjp``, forward and
+hand-written backward, which keeps every ``L x L`` array and the carried
+state in VMEM; elsewhere plain ``jax.numpy``/``lax`` einsums and one scan
+step a chunk, whose backward autodiff gives.  ``dt``'s softplus, ``A`` and
+the cumulative sums are ``jax.numpy`` in both.  The casts are at the same
+places.
 
 One layer (docs/transformer.md "The layer table" has the leaves)::
 
@@ -32,8 +40,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import ssd_kernels
+
 __all__ = ["ssd_chunked", "ssd_recurrence", "ssd_quadratic",
-           "causal_conv1d", "mamba2_mixer", "PROJECTION"]
+           "scan_kernel_tiles", "causal_conv1d", "mamba2_mixer",
+           "PROJECTION"]
 
 # the ``checkpoint_name`` of a projection product's result: what a layer's
 # ``jax.checkpoint`` may keep for the backward pass (``hybrid.py``)
@@ -79,6 +90,13 @@ def _decay_matrix(cum):
     return jnp.exp(jnp.where(lower, diff, -jnp.inf))
 
 
+def scan_kernel_tiles(cfg, dtype):
+    """Whether :func:`ssd_chunked` spells a scan of ``cfg``'s sizes in
+    ``dtype`` as the kernel pair."""
+    return ssd_kernels.tiles(cfg.ssm_chunk, cfg.ssm_heads, cfg.ssm_head_dim,
+                             cfg.ssm_state, dtype)
+
+
 def ssd_chunked(x, dt, a_log, B, C, chunk):
     """The same map as :func:`ssd_recurrence`, ``chunk`` steps at a time.
     A sequence that is no multiple of ``chunk`` is padded at its end with
@@ -94,10 +112,14 @@ def ssd_chunked(x, dt, a_log, B, C, chunk):
     f32 = jnp.float32
     la = _log_decay(dt, a_log).reshape(b, c, chunk, h)
     cum = jnp.cumsum(la, axis=2)                          # (b, c, L, h)
-    xdt = (x.astype(f32) * dt.astype(f32)[..., None]).astype(dtype)
-    xdt = xdt.reshape(b, c, chunk, h, p)
     Bc = B.reshape(b, c, chunk, n)
     Cc = C.reshape(b, c, chunk, n)
+    if ssd_kernels.tiles(chunk, h, p, n, dtype):
+        y = ssd_kernels.ssd_scan(Cc, Bc, x.reshape(b, c, chunk, h, p),
+                                 dt.astype(f32).reshape(b, c, chunk, h), cum)
+        return y.reshape(b, c * chunk, h, p)[:, :t]
+    xdt = (x.astype(f32) * dt.astype(f32)[..., None]).astype(dtype)
+    xdt = xdt.reshape(b, c, chunk, h, p)
 
     # inside a chunk: y_i += sum_{j<=i} exp(cum_i - cum_j) (C_i . B_j) xdt_j
     scores = jnp.einsum("bcln,bcsn->bcls", Cc, Bc, preferred_element_type=f32)

@@ -172,7 +172,8 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
                                     "ssm_layers", "recomputed_layers",
                                     "ssm_chunks_per_seq",
                                     "kept_product_layers",
-                                    "kept_product_bytes"}
+                                    "kept_product_bytes",
+                                    "ssm_kernel_layers"}
 
 
 def test_the_doctor_reads_the_spans_and_the_compile_counters(
