@@ -54,8 +54,10 @@ def _leaf_gaps(program, reference, leaves):
     return worst, at, statistics.median(g for g, _ in gaps)
 
 
-def compare(program, reference):
-    """number -> (value, where it is worst) for ``NUMBERS``.
+def compare(program, reference, names=NUMBERS):
+    """number -> (value, where it is worst) for those of ``NUMBERS`` that
+    ``names`` holds (a cell's limits) and that have something to read: a
+    family without running statistics may hand in an empty ``stats_norms``.
 
     The worst leaf is a widest gap and swings: in bfloat16 a few BatchNorm
     scales and shifts of the first stage (64 to 256 elements each) read 0.2
@@ -78,11 +80,13 @@ def compare(program, reference):
             ("update_norm_gap", "update_norms", moved),
             ("stats_norm_gap", "stats_norms",
              sorted(reference["stats_norms"]))):
+        if not leaves:
+            continue
         worst, at, median = _leaf_gaps(program[key], reference[key], leaves)
         out[name] = (worst, at)
         if name + "_median" in NUMBERS:
             out[name + "_median"] = (median, "median leaf")
-    return out
+    return {k: v for k, v in out.items() if k in names}
 
 
 def verdict(numbers, limits):
@@ -92,7 +96,10 @@ def verdict(numbers, limits):
     for name in NUMBERS:
         if name not in limits:
             continue
-        value, at = numbers[name]
+        # a number the cell's limits name and the readings do not give
+        # (an empty ``stats_norms`` under a limit on ``stats_norm_gap``)
+        # has not been shown to be under its limit
+        value, at = numbers.get(name, (math.inf, "nothing to compare"))
         limit = limits[name]
         if not value <= limit:
             correct = False

@@ -12,7 +12,9 @@ Three things live here, for every configuration whose ``family`` is
   trained sequence needs: 2 FLOPs per multiply-add, training = 3 x forward;
   the matrix products (every projection and the tied head), the products of
   the chunked state-space scan, and causal attention's scores and values.
-  Recomputation is not counted.
+  Recomputation is not counted.  ``ssd_scan_forward_work`` and
+  ``ssd_scan_backward_work`` give the operations and bytes of one run of
+  the scan alone, in the same terms (``layer_metrics/ssd_scan_roofline.py``).
 * ``reference_readings`` — the plain reference: the same model, loss,
   gradients and SGD-momentum update in float32 ``jax.numpy``/``lax`` at
   matmul precision ``highest``.  It imports nothing of ``mxnet_tpu`` and is
@@ -194,14 +196,58 @@ def forward_macs_per_token(config, size):
         if mixer == "mamba":
             inner = h * p
             macs += d * (2 * inner + 2 * n + h) + inner * d
-            # C.B scores and their products with x over the (chunk + 1) / 2
-            # steps a token sees in its chunk; the state's update and read
-            macs += (n + inner) * (chunk + 1) / 2 + 2 * inner * n
+            macs += _scan_macs_per_token(n, inner, chunk)
         else:
             macs += d * (hq + 2 * hkv) * e + hq * e * d
             macs += 2 * hq * e * (t + 1) / 2      # scores and values
         macs += mlp
     return macs
+
+
+def _scan_macs_per_token(n, inner, chunk):
+    """Multiply-adds a token of the chunked scan's forward pass: the C.B
+    scores and their products with x over the (chunk + 1) / 2 steps a token
+    sees in its chunk; the state's update and read."""
+    return (n + inner) * (chunk + 1) / 2 + 2 * inner * n
+
+
+def _scan_work(config, size, backward):
+    """(FLOPs, bytes) of one run of the chunked scan over a chip's batch,
+    from shapes alone, whatever implements it: the multiply-adds
+    ``forward_macs_per_token`` counts for the scan (causal inside a chunk;
+    the backward pass twice the forward's), and every operand and result
+    once in the type the scan is handed it.  Padding, the zero half of a
+    decay matrix and an operand read twice are an implementation's loss,
+    not its work.
+
+    Forward: C, B (tokens x state) and x (tokens x heads x head width) in
+    the compute type, dt and the summed log-decays (tokens x heads) in
+    float32; y as x, and the state each chunk was handed (heads x head
+    width x state a chunk, float32), which the backward pass reads.
+    Backward: those, and dy as x; the gradient of each of the five."""
+    cfg = sized(config, size)
+    h, p, n = (int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"]),
+               int(cfg["mamba_d_state"]))
+    t = int(cfg["seq_len"])
+    chunk = min(int(cfg["mamba_chunk_size"]), t)
+    tokens = int(cfg["batch_per_chip"]) * t
+    inner = h * p
+    wide = jnp.dtype(cfg["dtype"]).itemsize
+    macs = tokens * _scan_macs_per_token(n, inner, chunk)
+    five = tokens * ((2 * n + inner) * wide + 2 * h * 4)    # C, B, x; dt, cum
+    y = tokens * inner * wide                               # dy as well
+    states = tokens // chunk * inner * n * 4
+    if not backward:
+        return 2 * macs, five + y + states
+    return 2 * 2 * macs, (five + states + y) + five
+
+
+def ssd_scan_forward_work(config, size):
+    return _scan_work(config, size, backward=False)
+
+
+def ssd_scan_backward_work(config, size):
+    return _scan_work(config, size, backward=True)
 
 
 def flops_per_item(config, size):

@@ -281,7 +281,7 @@ def main(argv=None):
     reference = family.reference_readings(config, size, args.seed, checked)
     mark("reference done")
     correct, compared = correctness.verdict(
-        correctness.compare(program_readings, reference), limits)
+        correctness.compare(program_readings, reference, limits), limits)
     correct = correct and failed == 0
 
     values = {
@@ -306,6 +306,7 @@ def main(argv=None):
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         result["metrics"] = metrics
+        import scope_reduce
         import trace_reduce
         planes = list(trace["devices"].values())
         if planes:
@@ -313,8 +314,13 @@ def main(argv=None):
                                    for p in planes) / len(planes) / 1e9
             device["window_s"] = max(trace_reduce.window_ns(p)
                                      for p in planes) / 1e9
+            # the first chip's operations, with their op_names where the
+            # trace's file is found: the breakdown names them by scope
+            first = next(iter(trace["devices"]))
+            with_scopes = scope_reduce.of_run(run) or trace
             result["breakdown"] = {
-                "device_ops": trace_reduce.top_ops(planes[0]),
+                "device_ops": trace_reduce.top_ops(
+                    with_scopes["devices"].get(first, planes[0])),
                 "idle_gaps": trace_reduce.idle_gaps(planes[0],
                                                     trace["steps"])}
     else:

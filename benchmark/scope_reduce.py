@@ -38,6 +38,7 @@ prints device time by scope prefix and phase, and every device gap over
 """
 from __future__ import annotations
 
+import functools
 import glob
 import os
 import struct
@@ -52,6 +53,14 @@ MODULES_LINE = "XLA Modules"
 SCOPE_STAT = "tf_op"
 SPAN_PREFIXES = ("train.", "step.")
 PHASES = ("forward", "backward", "update", "other")
+PHASE_TAGS = {"forward": "fwd", "backward": "bwd", "update": "upd",
+              "other": "other"}
+UNSCOPED = "(unscoped)"
+# names jax itself puts into an ``op_name`` around a ``jax.checkpoint``
+JAX_OWN_SCOPES = ("checkpoint", "rematted_computation")
+# ``telemetry/trace.py`` names a span by sixteen hexadecimal digits; where
+# all sixteen are decimal the profile hands the id back as an integer
+SPAN_ID_WIDTH = 16
 UPDATE_SCOPE = "optimizer_update"
 CONVOLUTION = "conv_general_dilated"
 GAP_NS = 100_000
@@ -274,7 +283,8 @@ def load(path):
     """{"devices": {plane: [(name, start_ns, end_ns, scope)]},
     "modules": {plane: [(name, start_ns, end_ns)]},
     "spans": [(name, start_ns, end_ns, span_id, parent_id, step)]},
-    each list sorted by start.  Parsed once per file and process."""
+    each list sorted by start, the ids as text.  Parsed once per file and
+    process."""
     path = find_trace_file(path)
     key = (os.path.abspath(path), os.path.getmtime(path))
     if key in _LOADED:
@@ -293,13 +303,23 @@ def load(path):
         else:
             for events in lines.values():
                 spans.extend(
-                    (name, start, end, stats.get("span_id"),
-                     stats.get("parent_id"), _step(stats.get("step")))
+                    (name, start, end, _span_id(stats.get("span_id")),
+                     _span_id(stats.get("parent_id")),
+                     _step(stats.get("step")))
                     for name, start, end, stats in events)
     _LOADED.clear()
     _LOADED[key] = {"devices": devices, "modules": modules,
                     "spans": sorted(spans, key=lambda e: e[1])}
     return _LOADED[key]
+
+
+def _span_id(value):
+    """A span's id as the program wrote it: text.  The profile keeps a
+    statistic that reads as a whole number as one, which drops the zeros
+    an id began with."""
+    if isinstance(value, int):
+        return "%0*d" % (SPAN_ID_WIDTH, value)
+    return value
 
 
 def _step(value):
@@ -339,6 +359,13 @@ def of_run(run):
 def path_parts(scope):
     """The ``/``-separated parts of an ``op_name``; a ``/`` inside
     parentheses does not separate."""
+    return list(_path_parts(scope))
+
+
+@functools.lru_cache(maxsize=None)
+def _path_parts(scope):
+    """A step's 160,000 traced operations carry some 500 ``op_name``s, and
+    a dozen readers ask for each: split once."""
     parts, depth, part = [], 0, []
     for ch in scope:
         if ch == "/" and depth == 0:
@@ -349,7 +376,7 @@ def path_parts(scope):
         depth -= ch == ")"
         part.append(ch)
     parts.append("".join(part))
-    return [p for p in parts if p]
+    return tuple(p for p in parts if p)
 
 
 def named_scopes(scope):
@@ -441,6 +468,33 @@ def per_step_ms(run, key):
     return times[key] / run["traced_steps"] / 1e6
 
 
+def scope_ms(run, names):
+    """Device time of the run's trace in ms per traced step, averaged over
+    the chips, of the operations whose ``named_scopes`` hold any of
+    ``names``: self time, forward, re-run and backward together.  None
+    where there is no trace or no operation under such a scope."""
+    reduced = of_run(run)
+    if not reduced:
+        return None
+    wanted = frozenset(names)
+    per_chip = [sum(ns for op, ns in self_times(ops)
+                    if wanted.intersection(named_scopes(op[3])))
+                for ops in reduced["devices"].values() if ops]
+    if not per_chip or not sum(per_chip):
+        return None
+    return sum(per_chip) / len(per_chip) / run["traced_steps"] / 1e6
+
+
+def where_from(scope):
+    """Where an operation is from, for a reader of the ledger: the
+    innermost two of the program's scopes around it and its phase
+    (``l3/gated_mlp bwd``, ``(unscoped) other``).  A re-run forward pass
+    is ``bwd``, as ``phase_of`` has it."""
+    names = [n for n in named_scopes(scope) if n not in JAX_OWN_SCOPES]
+    return "%s %s" % ("/".join(names[-2:]) or UNSCOPED,
+                      PHASE_TAGS[phase_of(scope)])
+
+
 def programs_per_step(reduced, traced_steps):
     """Device programs that ran per traced step, averaged over the chips:
     the events of the ``XLA Modules`` line / steps."""
@@ -455,7 +509,7 @@ def by_prefix(ops, depth):
     table = {}
     for op, ns in self_times(ops):
         names = named_scopes(op[3])
-        key = ("/".join(names[:depth]) or "(unscoped)", phase_of(op[3]))
+        key = ("/".join(names[:depth]) or UNSCOPED, phase_of(op[3]))
         table[key] = table.get(key, 0.0) + ns
     return table
 

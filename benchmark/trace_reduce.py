@@ -89,13 +89,45 @@ def named(intervals, part):
     return [e for e in intervals if part in e[0]]
 
 
+def kernel_runs(run, kernels):
+    """[[(kernel, ns)] a chip]: every run in the run's trace of a kernel
+    whose ``name=`` is one of ``kernels``, found in the instruction's own
+    name (an operation that reads a kernel's result names it too, among
+    its operands).  None where there is no trace, or a chip ran none."""
+    trace = run.get("trace")
+    if not trace or not trace["devices"] or not run.get("traced_steps"):
+        return None
+    per_chip = []
+    for ops in trace["devices"].values():
+        found = [(k, op[2] - op[1]) for op in ops for k in kernels
+                 if k in own_name(op[0])]
+        if not found:
+            return None
+        per_chip.append(found)
+    return per_chip
+
+
+def own_name(name):
+    """The trace names a TPU operation by its whole HLO instruction; its
+    own name is what stands before `` = `` (``%fusion.9 = ...`` ->
+    ``fusion.9``)."""
+    return name.split(" = ")[0].lstrip("%")
+
+
 def top_ops(intervals, n=10):
-    """[[name, seconds]] of the ``n`` names with most summed time.  The
-    trace names a TPU operation by its whole HLO instruction; the name kept
-    is the instruction's own (``%fusion.9 = ...`` -> ``fusion.9``)."""
+    """[[name, seconds]] of the ``n`` names with most summed time.  An
+    operation that comes with its ``op_name`` (the fourth field of
+    ``scope_reduce.load``'s operations) is named by where it is from, its
+    innermost two program scopes and its phase, before the instruction's
+    own name: ``l3/gated_mlp bwd fusion.2457``, ``(unscoped) other
+    copy.12``; one that comes without, by its own name alone."""
+    import scope_reduce
+
     total = {}
-    for name, start, end in intervals:
-        name = name.split(" = ")[0].lstrip("%")
+    for name, start, end, *scope in intervals:
+        name = own_name(name)
+        if scope:
+            name = "%s %s" % (scope_reduce.where_from(scope[0]), name)
         total[name] = total.get(name, 0) + (end - start)
     ranked = sorted(total.items(), key=lambda kv: -kv[1])[:n]
     return [[name, ns / 1e9] for name, ns in ranked]

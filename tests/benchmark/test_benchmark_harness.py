@@ -52,6 +52,24 @@ def manifest():
         return json.load(f)
 
 
+@pytest.fixture(scope="module", params=["repo", "scratch"])
+def tree(request, harness, manifest, tmp_path_factory):
+    """(root, manifest, harness) of the benchmark as the repo has it, and of
+    a scratch copy with a fourth configuration, a fourth cell and one more
+    per-layer metric appended (``manifest_rule.scratch_tree``): what does
+    not depend on which cells exist holds on both, with no test edited."""
+    if request.param == "repo":
+        yield REPO, manifest, harness
+        return
+    rule = _load(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "manifest_rule.py"), "manifest_rule")
+    root = str(tmp_path_factory.mktemp("scratch_benchmark"))
+    grown = rule.scratch_tree(root)
+    yield root, grown, _load(os.path.join(root, "benchmark", "run.py"),
+                             "benchmark_run_scratch")
+    rule.forget(root)
+
+
 @pytest.fixture()
 def keep_jax_config():
     """``run.main`` turns the persistent compilation cache on for its
@@ -69,7 +87,8 @@ def keep_jax_config():
 
 
 # -- the manifest finds everything by name ---------------------------------
-def test_manifest_names_and_units(manifest):
+def test_manifest_names_and_units(tree):
+    root, manifest, _ = tree
     metrics = manifest["end_to_end"] + manifest["per_layer"]
     names = [m["name"] for m in metrics]
     assert len(set(names)) == len(names)
@@ -87,11 +106,13 @@ def test_manifest_names_and_units(manifest):
         assert NAME.match(entry["name"])
         assert 1 <= len(entry["why"]) <= 200 and "\n" not in entry["why"]
     for path in manifest["paths"]:
-        assert os.path.isdir(os.path.join(REPO, path))
+        assert os.path.isdir(os.path.join(root, path))
     assert manifest["command"][1].startswith(manifest["paths"][0] + "/")
 
 
-def test_manifest_finds_every_file(manifest, harness):
+def test_manifest_finds_every_file(tree):
+    root, manifest, harness = tree
+    bench = os.path.join(root, "benchmark")
     configs = {c["name"]: c for c in manifest["configs"]}
     used = set()
     for cell in manifest["workloads"]:
@@ -106,13 +127,13 @@ def test_manifest_finds_every_file(manifest, harness):
         assert len(entry["source"]) <= 200
         for folder, name in (("families", config["family"]),
                              ("traffic_kinds", traffic["kind"])):
-            assert os.path.isfile(os.path.join(BENCH, folder, name + ".py"))
+            assert os.path.isfile(os.path.join(bench, folder, name + ".py"))
         family = harness.load_module("families", config["family"])
         for needed in ("build", "flops_per_item", "reference_readings"):
             assert callable(getattr(family, needed))
         assert callable(harness.load_module(
             "traffic_kinds", traffic["kind"]).batches)
-        limits = _load(os.path.join(BENCH, "correctness.py"),
+        limits = _load(os.path.join(bench, "correctness.py"),
                        "correctness").load_limits(cell["name"])
         assert set(limits) <= set(REHEARSAL_LIMITS) and len(limits) >= 4
         assert {"setup_s", "train_throughput"} <= {m["name"]
@@ -121,7 +142,8 @@ def test_manifest_finds_every_file(manifest, harness):
     assert used == set(configs)
 
 
-def test_every_per_layer_metric_has_a_reader(manifest, harness):
+def test_every_per_layer_metric_has_a_reader(tree):
+    root, manifest, harness = tree
     cells = {w["name"] for w in manifest["workloads"]}
     layers = set()
     for m in manifest["per_layer"]:
@@ -134,13 +156,14 @@ def test_every_per_layer_metric_has_a_reader(manifest, harness):
         layers.add(m["layer"])
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    with open(os.path.join(REPO, "PERF.md")) as f:
+    with open(os.path.join(root, "PERF.md")) as f:
         perf = f.read()
     for layer in layers:
         assert layer in perf, "PERF.md's list of layers lacks %r" % layer
 
 
-def test_readers_say_nothing_when_there_is_nothing_to_read(manifest, harness):
+def test_readers_say_nothing_when_there_is_nothing_to_read(tree):
+    _, manifest, harness = tree
     family = harness.load_module("families", "gluon_resnet_v1")
     run = {"trace": None, "traced_steps": 0, "attribution": None,
            "memory_peak_bytes": 0, "peaks": None, "family": family,

@@ -224,19 +224,21 @@ def test_a_broken_step_is_not_correct(harness, monkeypatch, capsys,
 
 # -- what the manifest reports in the new cell --------------------------------
 def test_manifest_appends_the_granite_cell_and_moves_nothing(harness):
-    """The accepted sixteen keep their places (new entries go last, and
-    ``test_scope_reduce.py`` pins the last ten: PERF.md section 7(a)); the
-    Granite cell is appended to those whose readers find something in a
-    language model's step."""
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
+    """The manifest starts with what was accepted (``manifest_rule.py``:
+    names, fields and relative order; what is new comes after), and in that
+    table the Granite configuration and cell follow the two ResNets', and
+    the cell is listed by the accepted metrics whose readers find something
+    in a language model's step."""
+    rule = _load(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "manifest_rule.py"), "manifest_rule")
+    manifest, accepted = rule.load_manifest(), rule.load_accepted()
+    assert rule.departures(manifest, accepted) == []
     cell = GRANITE + ".tokens"
-    assert [w["name"] for w in manifest["workloads"]][-1] == cell
-    assert [c["name"] for c in manifest["configs"]][-1] == GRANITE
+    old = ["resnet50_v1.synthetic", "resnet18_v1.synthetic"]
+    assert [w["name"] for w in accepted["workloads"]] == old + [cell]
+    assert [c["name"] for c in accepted["configs"]][2] == GRANITE
     silent = {"conv_device_ms", "fused_update_us"}
-    assert len(manifest["per_layer"]) == 16
-    for m in manifest["per_layer"]:
-        old = ["resnet50_v1.synthetic", "resnet18_v1.synthetic"]
+    for m in accepted["per_layer"][:16]:
         assert m["workloads"] == old + ([] if m["name"] in silent else [cell])
     names = {m["name"] for m in harness.resolve(manifest, cell)[4]}
     assert "step_mfu" in names and "scoped_device_share" in names
