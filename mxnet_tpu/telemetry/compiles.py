@@ -40,7 +40,18 @@ One ``jax.monitoring`` duration listener, registered when
   the device's memory: 10 in that step on a v5e, 0 where they do not fit);
 - ``ssm_chunks_per_seq``, ``kept_product_bytes``: chunks the state-space
   scan of the last traced program cuts a sequence into, and the bytes of
-  the products it keeps (2.16e9 in that step; no sums: the newest values).
+  the products it keeps (2.16e9 in that step; no sums: the newest values);
+- ``latent_attention_layers``, ``moe_layers``, ``mtp_modules``: layers whose
+  mixer is latent attention, layers whose feed-forward is sparse experts
+  (the prediction module's layer counts in both) and prediction modules in
+  the programs traced so far (6, 5 and 1 for one trace of the
+  ``JoyAI-LLM-Flash`` step);
+- ``experts_held``, ``router_width``, ``moe_grouped_rows``,
+  ``moe_expected_rows``: of the last traced program with sparse experts,
+  the experts held here and the router's width (16 of 256), the static
+  rows of the buffer the grouped products are handed, a layer a step, and
+  the rows an even router sends here (``tokens x experts a token x held /
+  width``: 24,576 and 8,192 in that step; no sums: the newest values).
 
 Always on: the listener fires only when something is traced, lowered or
 compiled, which a steady step never does.  ``telemetry.enable()`` calls
@@ -63,7 +74,9 @@ BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "blocked_bias_grads", "ssm_layers", "recomputed_layers",
          "ssm_chunks_per_seq", "kept_product_layers", "kept_product_bytes",
-         "ssm_kernel_layers")
+         "ssm_kernel_layers", "latent_attention_layers", "moe_layers",
+         "experts_held", "router_width", "moe_grouped_rows",
+         "moe_expected_rows", "mtp_modules")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

@@ -25,8 +25,8 @@ fixture — see ``analysis/budget_models.py`` and
 """
 from .model import TransformerLM, TransformerLMConfig, MeshProgram
 from .hybrid import HybridLM, HybridLMConfig, HybridProgram
-from . import layers, ssm, step
+from . import layers, mla, moe, ssm, step
 
 __all__ = ["TransformerLM", "TransformerLMConfig", "MeshProgram",
            "HybridLM", "HybridLMConfig", "HybridProgram",
-           "layers", "ssm", "step"]
+           "layers", "mla", "moe", "ssm", "step"]

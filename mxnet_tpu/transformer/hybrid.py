@@ -1,7 +1,8 @@
-"""A language model whose layers come from a table: Mamba-2 state-space
-mixers beside grouped-query attention, each followed by a gated
-feed-forward, on the ``mesh_plan`` path (docs/transformer.md "The layer
-table").
+"""A language model whose layers come from a table, a pair a layer: a mixer
+(Mamba-2 state-space, grouped-query attention, or latent attention with
+rotary positions) and a feed-forward (gated, or sparse experts of which this
+chip holds a share), on the ``mesh_plan`` path (docs/transformer.md "The
+layer table").
 
 :class:`HybridLM` is a block for ``DataParallelTrainer(block, None, 'sgd',
 mesh_plan=MeshPlan())``: ``mesh_program(plan)`` gives a
@@ -19,6 +20,18 @@ equations are those of the ``granitemoehybrid`` family (dense, no experts)::
                query heads
     mamba: ``transformer/ssm.py``
 
+and those of the ``deepseek_v3`` family (``joyai_llm_flash``): no
+multipliers, ``latent_attention`` mixers (``transformer/mla.py``), a leading
+dense layer and ``sparse_experts`` after it (``transformer/moe.py``: told
+``expert_shard = (index, of)``, the layer routes over all the experts and
+computes the part its own give), an untied head, and ``mtp_modules`` 0 or 1
+next-next-token module, whose loss is added with ``mtp_weight``::
+
+    u_i = W_eh [RMSNorm_h(h_L,i) ; RMSNorm_e(E[y_i])]   h_L before its final norm
+    u -> one more latent_attention + sparse_experts layer -> its own final
+    RMSNorm -> the same head; target y_{i+1}; a row's last position left out
+    loss = main + mtp_weight * module loss
+
 No layer is divided: a plan with a ``model``, ``sequence`` or ``pipe`` axis
 is refused.  The ``data`` axis works as for every mesh program (the step
 wrapper owns the one gradient exchange).  ``vocab_size`` is the number of
@@ -26,6 +39,7 @@ embedding rows held here: ids, logits and the loss are over those rows.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -35,25 +49,36 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..telemetry import compiles as _compiles
 from . import layers as L
-from . import ssm
+from . import mla, moe, ssm
+from .layers import gated_mlp, rms_norm
 from .model import ProgramLayout
 
 __all__ = ["HybridLMConfig", "HybridLM", "HybridProgram", "LAYER_LEAVES",
            "causal_gqa_attention", "rms_norm", "gated_mlp",
            "kept_product_bytes", "keeps_products"]
 
-MIXERS = ("mamba", "attention")
+MIXERS = ("mamba", "attention", "latent_attention")
+FEED_FORWARDS = ("gated_mlp", "sparse_experts")
+# the model_types whose keys and equations are deepseek_v3's
+DEEPSEEK_FAMILY = ("deepseek_v3", "joyai_llm_flash")
 # what a training step holds on the mesh tier, bytes a parameter: float32
 # master 4, compute copy 2, float32 gradient 4, float32 momentum 4
 RESIDENT_BYTES_PER_PARAM = 14
 # the share of the device's memory the reckoning of `keeps_products` may fill
 ROOM = 0.9
+# the `checkpoint_name` of latent attention's result, which a layer's
+# checkpoint always keeps (the grouped-query mixer tags nothing with it)
+ATTENTION_OUT = "attention_out"
 
 
 class HybridLMConfig:
-    """Sizes of a :class:`HybridLM`.  ``layer_types`` is the table: one
-    mixer kind a layer.  :meth:`from_hf` reads the keys of a published
-    ``granitemoehybrid`` ``config.json``."""
+    """Sizes of a :class:`HybridLM`.  ``layer_types`` and ``ffn_types`` are
+    the table: one mixer of :data:`MIXERS` and one feed-forward of
+    :data:`FEED_FORWARDS` a layer (``ffn_types`` None: ``gated_mlp``
+    throughout).  ``n_routed_experts`` is the router's width, the published
+    count; ``expert_shard = (index, of)`` says which ``n_routed_experts /
+    of`` of them are held here.  :meth:`from_hf` reads the keys of a
+    published ``config.json`` by its ``model_type``."""
 
     def __init__(self, vocab_size=64, d_model=32, layer_types=("mamba",
                  "attention"), d_ff=64, n_heads=4, n_kv_heads=2, head_dim=8,
@@ -61,10 +86,18 @@ class HybridLMConfig:
                  ssm_state=8, ssm_conv=4, ssm_chunk=8, norm_eps=1e-5,
                  embedding_multiplier=1.0, residual_multiplier=1.0,
                  logits_scaling=1.0, seq_len=32, attention_block=512,
-                 init_seed=0, init_scale=0.02):
+                 init_seed=0, init_scale=0.02, ffn_types=None,
+                 tie_embeddings=True, q_lora_rank=16, kv_lora_rank=8,
+                 qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8,
+                 rope_theta=10000.0, moe_d_ff=16, n_routed_experts=8,
+                 experts_per_token=2, expert_shard=(0, 1),
+                 n_shared_experts=1, routed_scaling=1.0, norm_topk_prob=True,
+                 mtp_modules=0, mtp_weight=0.3):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.layer_types = tuple(layer_types)
+        self.ffn_types = (("gated_mlp",) * len(self.layer_types)
+                          if ffn_types is None else tuple(ffn_types))
         self.d_ff = int(d_ff)
         self.n_heads = int(n_heads)
         self.n_kv_heads = int(n_kv_heads)
@@ -85,22 +118,66 @@ class HybridLMConfig:
         self.attention_block = int(attention_block)
         self.init_seed = int(init_seed)
         self.init_scale = float(init_scale)
+        self.tie_embeddings = bool(tie_embeddings)
+        self.q_lora_rank = int(q_lora_rank)
+        self.kv_lora_rank = int(kv_lora_rank)
+        self.qk_nope_dim = int(qk_nope_dim)
+        self.qk_rope_dim = int(qk_rope_dim)
+        self.v_head_dim = int(v_head_dim)
+        self.rope_theta = float(rope_theta)
+        self.moe_d_ff = int(moe_d_ff)
+        self.n_routed_experts = int(n_routed_experts)
+        self.experts_per_token = int(experts_per_token)
+        self.expert_shard = tuple(int(v) for v in expert_shard)
+        self.n_shared_experts = int(n_shared_experts)
+        self.routed_scaling = float(routed_scaling)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.mtp_modules = int(mtp_modules)
+        self.mtp_weight = float(mtp_weight)
         unknown = set(self.layer_types) - set(MIXERS)
         if unknown or not self.layer_types:
             raise ValueError("layer_types must name mixers of %s, got %r"
                              % (MIXERS, layer_types))
+        if (len(self.ffn_types) != len(self.layer_types)
+                or set(self.ffn_types) - set(FEED_FORWARDS)):
+            raise ValueError(
+                "ffn_types must name one feed-forward of %s a layer, got %r"
+                % (FEED_FORWARDS, ffn_types))
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads %d must divide by n_kv_heads %d"
                              % (self.n_heads, self.n_kv_heads))
+        index, of = self.expert_shard
+        if self.n_routed_experts % of or not 0 <= index < of:
+            raise ValueError(
+                "expert_shard %r does not divide %d routed experts"
+                % (self.expert_shard, self.n_routed_experts))
+        if self.qk_rope_dim % 2:
+            raise ValueError("qk_rope_dim %d is odd" % self.qk_rope_dim)
+        if self.mtp_modules not in (0, 1):
+            raise ValueError("mtp_modules is 0 or 1, got %d"
+                             % self.mtp_modules)
 
     @classmethod
     def from_hf(cls, config, **sizes):
-        """From the keys of a ``granitemoehybrid`` ``config.json``: the first
-        ``num_hidden_layers`` entries of ``layer_types`` are the layers.
-        ``sizes`` are the arguments the file does not hold (``seq_len``,
-        ``attention_block``, ...)."""
+        """From the keys of a published ``config.json``, by its
+        ``model_type``: ``granitemoehybrid`` (dense), or the ``deepseek_v3``
+        family (``joyai_llm_flash``).  ``sizes`` are the arguments the file
+        does not hold (``seq_len``, ``attention_block``, ``expert_shard``,
+        ...).  What is not implemented is refused by name."""
+        family = config.get("model_type", "granitemoehybrid")
+        if family == "granitemoehybrid":
+            return cls._from_granite(config, **sizes)
+        if family in DEEPSEEK_FAMILY:
+            return cls._from_deepseek(config, **sizes)
+        raise ValueError("model_type %r is not implemented" % family)
+
+    @classmethod
+    def _from_granite(cls, config, **sizes):
+        """The first ``num_hidden_layers`` entries of ``layer_types`` are
+        the layers."""
         if config.get("num_local_experts"):
-            raise ValueError("sparse experts are not implemented")
+            raise ValueError("granitemoehybrid's sparse experts "
+                             "(num_local_experts) are not implemented")
         if config.get("position_embedding_type", "nope") != "nope":
             raise ValueError("only position_embedding_type 'nope' is "
                              "implemented")
@@ -130,19 +207,99 @@ class HybridLMConfig:
             residual_multiplier=config["residual_multiplier"],
             logits_scaling=config["logits_scaling"], **sizes)
 
+    @classmethod
+    def _from_deepseek(cls, config, **sizes):
+        """``num_hidden_layers`` layers of latent attention, the first
+        ``first_k_dense_replace`` with the dense feed-forward and the rest
+        with sparse experts.  ``n_routed_experts`` is the router's width, as
+        published; which of the experts are held here is no key of a
+        ``config.json``: ``expert_shard=(index, of)`` among ``sizes`` says
+        (absent: all of them)."""
+        for key, only in (("n_group", 1), ("topk_group", 1),
+                          ("rope_scaling", None), ("scoring_func", "sigmoid"),
+                          ("topk_method", "noaux_tc"), ("moe_layer_freq", 1),
+                          ("hidden_act", "silu"), ("attention_bias", False),
+                          ("rope_interleave", True)):
+            if config.get(key, only) != only:
+                raise ValueError("only %s %r is implemented, got %r"
+                                 % (key, only, config[key]))
+        if config.get("q_lora_rank") is None:
+            raise ValueError("a null q_lora_rank is not implemented")
+        modules = int(config.get("num_nextn_predict_layers", 0))
+        if modules > 1:
+            raise ValueError("only num_nextn_predict_layers 0 or 1 is "
+                             "implemented, got %d" % modules)
+        layers = int(config["num_hidden_layers"])
+        dense = min(int(config["first_k_dense_replace"]), layers)
+        return cls(
+            vocab_size=config["vocab_size"], d_model=config["hidden_size"],
+            layer_types=("latent_attention",) * layers,
+            ffn_types=("gated_mlp",) * dense
+            + ("sparse_experts",) * (layers - dense),
+            d_ff=config["intermediate_size"],
+            n_heads=config["num_attention_heads"],
+            n_kv_heads=config["num_attention_heads"],
+            q_lora_rank=config["q_lora_rank"],
+            kv_lora_rank=config["kv_lora_rank"],
+            qk_nope_dim=config["qk_nope_head_dim"],
+            qk_rope_dim=config["qk_rope_head_dim"],
+            v_head_dim=config["v_head_dim"],
+            rope_theta=config["rope_theta"],
+            moe_d_ff=config["moe_intermediate_size"],
+            n_routed_experts=config["n_routed_experts"],
+            experts_per_token=config["num_experts_per_tok"],
+            n_shared_experts=config["n_shared_experts"],
+            routed_scaling=config["routed_scaling_factor"],
+            norm_topk_prob=config["norm_topk_prob"],
+            tie_embeddings=config["tie_word_embeddings"],
+            mtp_modules=modules,
+            mtp_weight=config.get("mtp_loss_weight", 0.3),
+            norm_eps=config["rms_norm_eps"], **sizes)
+
     @property
     def ssm_inner(self):
         return self.ssm_heads * self.ssm_head_dim
 
+    @property
+    def experts_held(self):
+        return self.n_routed_experts // self.expert_shard[1]
+
+    @property
+    def layers(self):
+        """The table: ``(mixer, feed-forward)`` of every layer."""
+        return tuple(zip(self.layer_types, self.ffn_types))
+
+    @property
+    def blocks(self):
+        """``(prefix of the leaves' names, mixer, feed-forward)`` of every
+        layer of the main model."""
+        return tuple(("l%d_" % i,) + pair
+                     for i, pair in enumerate(self.layers))
+
+    @property
+    def mtp_blocks(self):
+        """The same of the prediction modules' layers."""
+        return (("mtp_", "latent_attention", "sparse_experts"),
+                ) * self.mtp_modules
+
     def describe(self):
-        return {k: getattr(self, k) for k in
-                ("vocab_size", "d_model", "layer_types", "d_ff", "n_heads",
-                 "n_kv_heads", "head_dim", "ssm_heads", "ssm_head_dim",
-                 "ssm_state", "ssm_conv", "ssm_chunk", "seq_len",
-                 "init_seed")}
+        """The sizes that shape the program: the table, and the widths of
+        the kinds it holds."""
+        keys = ["vocab_size", "d_model", "layer_types", "ffn_types", "d_ff",
+                "n_heads", "n_kv_heads", "head_dim", "ssm_heads",
+                "ssm_head_dim", "ssm_state", "ssm_conv", "ssm_chunk",
+                "seq_len", "init_seed", "tie_embeddings", "mtp_modules"]
+        if "latent_attention" in self.layer_types:
+            keys += ["q_lora_rank", "kv_lora_rank", "qk_nope_dim",
+                     "qk_rope_dim", "v_head_dim", "rope_theta"]
+        if "sparse_experts" in self.ffn_types:
+            keys += ["moe_d_ff", "n_routed_experts", "experts_per_token",
+                     "expert_shard", "experts_held", "n_shared_experts",
+                     "routed_scaling"]
+        return {k: getattr(self, k) for k in keys}
 
 
-def _layer_leaves(cfg, mixer):
+def _layer_leaves(cfg, mixer, ffn="gated_mlp"):
     """[(kind, shape)] of one layer's leaves, in declaration order."""
     d, f = cfg.d_model, cfg.d_ff
     if mixer == "mamba":
@@ -152,17 +309,27 @@ def _layer_leaves(cfg, mixer):
                ("ssm_conv_b", (inner + 2 * n,)),
                ("ssm_dt_bias", (h,)), ("ssm_a_log", (h,)), ("ssm_d", (h,)),
                ("ssm_norm", (inner,)), ("ssm_out", (inner, d))]
+    elif mixer == "latent_attention":
+        mix = mla.leaves(cfg)
     else:
         hq, hkv, e = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         mix = [("wq", (d, hq, e)), ("wk", (d, hkv, e)), ("wv", (d, hkv, e)),
                ("wo", (hq, e, d))]
-    return [("norm1", (d,))] + mix + [
-        ("norm2", (d,)), ("mlp_in", (d, 2 * f)), ("mlp_out", (f, d))]
+    feed = (moe.leaves(cfg) if ffn == "sparse_experts"
+            else [("mlp_in", (d, 2 * f)), ("mlp_out", (f, d))])
+    return [("norm1", (d,))] + mix + [("norm2", (d,))] + feed
 
 
-# mixer kind -> the kinds of its layer's leaves (docs/transformer.md)
-LAYER_LEAVES = {mixer: tuple(k for k, _ in _layer_leaves(
-    HybridLMConfig(), mixer)) for mixer in MIXERS}
+# (mixer, feed-forward) -> the kinds of its layer's leaves
+# (docs/transformer.md)
+LAYER_LEAVES = {(mixer, ffn): tuple(k for k, _ in _layer_leaves(
+    HybridLMConfig(), mixer, ffn)) for mixer in MIXERS
+    for ffn in FEED_FORWARDS}
+
+
+def _block_leaves(p, prefix, mixer, ffn):
+    """kind -> array: one block's leaves out of ``p`` (name -> array)."""
+    return {kind: p[prefix + kind] for kind in LAYER_LEAVES[mixer, ffn]}
 
 
 class HybridLM:
@@ -182,49 +349,51 @@ class HybridLM:
         return HybridProgram(self.cfg, plan, params=self._params)
 
 
-def rms_norm(x, weight, eps):
-    """``x / sqrt(mean(x^2) + eps) * weight``, the mean in float32."""
-    xf = x.astype(jnp.float32)
-    xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-                        + eps)
-    return (xf * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def gated_mlp(lp, x):
-    """``(silu(a) * b) W_out`` with ``[a, b] = x W_in``.  ``@ W_out``'s
-    result carries no tag: it is the layer's output, which nothing in the
-    layer's backward pass reads."""
-    a, b = jnp.split(checkpoint_name(x @ lp["mlp_in"], ssm.PROJECTION), 2,
-                     axis=-1)
-    return (jax.nn.silu(a) * b) @ lp["mlp_out"]
-
-
 def _product_widths(cfg, mixer):
-    """Widths of the tagged projection products of one layer."""
+    """Widths of the tagged projection products of one mixer."""
     if mixer == "mamba":
-        mix = [2 * cfg.ssm_inner + 2 * cfg.ssm_state + cfg.ssm_heads,
-               cfg.d_model]
+        return [2 * cfg.ssm_inner + 2 * cfg.ssm_state + cfg.ssm_heads,
+                cfg.d_model]
+    if mixer == "latent_attention":
+        return mla.product_widths(cfg)
+    return [cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim,
+            cfg.n_kv_heads * cfg.head_dim, cfg.d_model]
+
+
+def _product_elements(cfg, mixer, ffn, tokens):
+    """Elements of one layer's tagged projection products over ``tokens``
+    tokens: the mixer's and the feed-forward's a token, and for sparse
+    experts the held experts' over the buffer of routed rows."""
+    if ffn == "sparse_experts":
+        feed = (tokens * 2 * cfg.n_shared_experts * cfg.moe_d_ff
+                + moe.buffer_rows(cfg, tokens) * 2 * cfg.moe_d_ff)
     else:
-        mix = [cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim,
-               cfg.n_kv_heads * cfg.head_dim, cfg.d_model]
-    return mix + [2 * cfg.d_ff]
+        feed = tokens * 2 * cfg.d_ff
+    return tokens * sum(_product_widths(cfg, mixer)) + feed
 
 
 def kept_product_bytes(cfg, batch, seq, dtype):
     """Bytes of every layer's tagged projection products over a local
     chunk of ``batch x seq`` tokens in ``dtype``."""
-    widths = sum(sum(_product_widths(cfg, m)) for m in cfg.layer_types)
-    return batch * seq * widths * jnp.dtype(dtype).itemsize
+    return sum(_product_elements(cfg, mixer, ffn, batch * seq)
+               for _, mixer, ffn in cfg.blocks + cfg.mtp_blocks
+               ) * jnp.dtype(dtype).itemsize
 
 
-def _layer_live_bytes(cfg, mixer, batch, seq, dtype):
+def _layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn="gated_mlp"):
     """One layer's live set while its backward pass runs, reckoned from
     shapes: every intermediate of the layer once in ``dtype``, and four
     float32 arrays of the mixer's scores (the scan's decay matrix of every
     chunk and its masked product with ``C B^T``, or one block of query rows
-    against the keys; each with its gradient).  A scan that runs as the
-    kernel pair (``ssm.scan_kernel_tiles``) has no such array."""
-    d, f = cfg.d_model, cfg.d_ff
+    against the keys, of every sequence or in latent attention of one; each
+    with its gradient).  A scan that runs as the
+    kernel pair (``ssm.scan_kernel_tiles``) has no such array.  Sparse
+    experts add what the buffer of routed rows holds (the rows gathered,
+    the gate's halves and product, the rows' results in both types) and the
+    float32 sum they are added into: the router's layout decides the rows,
+    and both branches of its ``lax.cond`` work in a buffer of that size."""
+    d = cfg.d_model
+    tokens = batch * seq
     if mixer == "mamba":
         inner, n = cfg.ssm_inner, cfg.ssm_state
         chunks = -(-seq // cfg.ssm_chunk)
@@ -232,11 +401,24 @@ def _layer_live_bytes(cfg, mixer, batch, seq, dtype):
         scores = 0 if ssm.scan_kernel_tiles(cfg, dtype) else \
             batch * chunks * cfg.ssm_heads * cfg.ssm_chunk ** 2
     else:
-        widths = cfg.n_heads * cfg.head_dim
-        scores = batch * cfg.n_heads * min(cfg.attention_block, seq) * seq
-    widths += sum(_product_widths(cfg, mixer)) + 4 * d + f
-    return (batch * seq * widths * jnp.dtype(dtype).itemsize
-            + 4 * scores * 4)
+        scores = cfg.n_heads * min(cfg.attention_block, seq) * seq
+        if mixer == "latent_attention":
+            # q and k with their rotary parts turned, v, the output; a
+            # block of query rows is of one sequence
+            widths = cfg.n_heads * (2 * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+                                    + 2 * cfg.v_head_dim)
+        else:
+            widths = cfg.n_heads * cfg.head_dim
+            scores *= batch
+    elements = _product_elements(cfg, mixer, ffn, tokens) \
+        + tokens * (widths + 4 * d)
+    if ffn == "sparse_experts":
+        elements += (tokens * (cfg.n_shared_experts * cfg.moe_d_ff + 2 * d)
+                     + moe.buffer_rows(cfg, tokens)
+                     * (4 * d + 3 * cfg.moe_d_ff))
+    else:
+        elements += tokens * cfg.d_ff
+    return elements * jnp.dtype(dtype).itemsize + 4 * scores * 4
 
 
 def keeps_products(cfg, n_params, batch, seq, dtype, bytes_limit):
@@ -249,8 +431,8 @@ def keeps_products(cfg, n_params, batch, seq, dtype, bytes_limit):
     one shape on one kind of device compiles one program."""
     if bytes_limit is None:
         return True
-    live = max(_layer_live_bytes(cfg, m, batch, seq, dtype)
-               for m in set(cfg.layer_types))
+    live = max(_layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn)
+               for mixer, ffn in {b[1:] for b in cfg.blocks + cfg.mtp_blocks})
     held = (RESIDENT_BYTES_PER_PARAM * n_params
             + kept_product_bytes(cfg, batch, seq, dtype) + live)
     return held <= ROOM * bytes_limit
@@ -275,20 +457,84 @@ def _attend_rows(q, k, v, scale, start):
     return jnp.einsum("bkgqs,bske->bqkge", probs.astype(v.dtype), v)
 
 
-def causal_gqa_attention(q, k, v, scale, block):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attend_whole(q, k, v, scale, block):
+    """Blockwise causal attention with a backward pass of its own, for many
+    blocks over long keys: q (b, t, kv, g, e); k, v (b, t, kv, e).  A
+    block's sequences run in turn (``lax.map``): at the same live scores the
+    blocks are twice as long, and the keys' gradient is added to half as
+    often."""
+    out = []
+    for start in range(0, q.shape[1], block):
+        if out:
+            # a block waits for the one before: its prefix of the keys is
+            # cut when it runs, not ahead with every other block's
+            k, v, out[-1] = lax.optimization_barrier((k, v, out[-1]))
+        seen = start + block
+        out.append(lax.map(
+            lambda row, start=start: _attend_rows(
+                *(a[None] for a in row), scale, start)[0],
+            (q[:, start:seen], k[:, :seen], v[:, :seen])))
+    return jnp.concatenate(out, axis=1)
+
+
+def _attend_whole_fwd(q, k, v, scale, block):
+    return _attend_whole(q, k, v, scale, block), (q, k, v)
+
+
+def _attend_whole_bwd(scale, block, kept, g):
+    """A block at a time: its scores again, its queries' gradient, and its
+    keys' and values' gradients added in place to one array each.  Autodiff
+    of the blocks would hold every block's padded part until the last is
+    there (7.5 GB a layer at 32 blocks over 8k keys of 32 heads)."""
+    q, k, v = kept
+    dk, dv, dq = jnp.zeros_like(k), jnp.zeros_like(v), []
+    for start in range(0, q.shape[1], block):
+        seen = start + block
+        k, v, dk, dv = lax.optimization_barrier((k, v, dk, dv))
+
+        def pull_row(row, start=start):
+            q_row, k_row, v_row, g_row = (a[None] for a in row)
+            _, pull = jax.vjp(
+                lambda q, k, v: _attend_rows(q, k, v, scale, start),
+                q_row, k_row, v_row)
+            return tuple(a[0] for a in pull(g_row))
+
+        dq_rows, dk_seen, dv_seen = lax.map(
+            pull_row, (q[:, start:seen], k[:, :seen], v[:, :seen],
+                       g[:, start:seen]))
+        dq.append(dq_rows)
+        dk = dk.at[:, :seen].add(dk_seen)
+        dv = dv.at[:, :seen].add(dv_seen)
+    return jnp.concatenate(dq, axis=1), dk, dv
+
+
+_attend_whole.defvjp(_attend_whole_fwd, _attend_whole_bwd)
+
+
+def causal_gqa_attention(q, k, v, scale, block, whole_keys=False):
     """Causal grouped-query attention, ``block`` query rows at a time so
     that no more than ``block x t`` scores a head are live, and no key past
-    a block's last row is read.  q (b, t, heads, e); k, v (b, t, kv_heads,
-    e), each key-value head serving ``heads / kv_heads`` query heads in
-    order.  The scores are recomputed in the backward pass."""
+    a block's last row is read.  q (b, t, heads, e); k (b, t, kv_heads, e);
+    v (b, t, kv_heads, e or a width of its own), each key-value head serving
+    ``heads / kv_heads`` query heads in order.  The scores are recomputed in
+    the backward pass: by autodiff through a ``jax.checkpoint`` a block,
+    which keeps the block's prefix of the keys and values and sums the
+    blocks' parts of their gradient at the end; or, with ``whole_keys``
+    (many blocks over long keys: latent attention at 8k), by
+    :func:`_attend_whole`'s own backward pass, a block's sequences in turn,
+    which keeps the keys and values once."""
     b, t, heads, e = q.shape
     kv = k.shape[2]
     q = q.reshape(b, t, kv, heads // kv, e)
+    if whole_keys:
+        out = _attend_whole(q, k, v, scale, block)
+        return out.reshape(b, t, heads, v.shape[-1])
     rows = jax.checkpoint(_attend_rows, static_argnums=(3, 4))
     out = [rows(q[:, start:start + block], k[:, :start + block],
                 v[:, :start + block], scale, start)
            for start in range(0, t, block)]
-    return jnp.concatenate(out, axis=1).reshape(b, t, heads, e)
+    return jnp.concatenate(out, axis=1).reshape(b, t, heads, v.shape[-1])
 
 
 class HybridProgram(ProgramLayout):
@@ -309,11 +555,23 @@ class HybridProgram(ProgramLayout):
                     "1, got %d" % (axis, plan.size(axis)))
         self.cfg = cfg
         self.plan = plan
-        specs = [("embed", "embed", (cfg.vocab_size, cfg.d_model))]
-        for i, mixer in enumerate(cfg.layer_types):
-            specs += [("l%d_%s" % (i, kind), kind, shape)
-                      for kind, shape in _layer_leaves(cfg, mixer)]
-        specs.append(("norm_f", "norm_f", (cfg.d_model,)))
+        d = cfg.d_model
+
+        def block(prefix, mixer, ffn):
+            return [(prefix + kind, kind, shape)
+                    for kind, shape in _layer_leaves(cfg, mixer, ffn)]
+
+        specs = [("embed", "embed", (cfg.vocab_size, d))]
+        for entry in cfg.blocks:
+            specs += block(*entry)
+        specs.append(("norm_f", "norm_f", (d,)))
+        if not cfg.tie_embeddings:
+            specs.append(("head", "head", (cfg.vocab_size, d)))
+        for entry in cfg.mtp_blocks:
+            specs += [("mtp_norm_h", "norm_h", (d,)),
+                      ("mtp_norm_e", "norm_e", (d,)),
+                      ("mtp_eh_proj", "eh_proj", (2 * d, d))]
+            specs += block(*entry) + [("mtp_norm_f", "norm_f", (d,))]
         self.param_names = [n for n, _, _ in specs]
         self._kinds = {n: k for n, k, _ in specs}
         self._shapes = {n: s for n, _, s in specs}
@@ -329,8 +587,10 @@ class HybridProgram(ProgramLayout):
     # -- init -------------------------------------------------------------
     def _draw_leaf(self, key, kind, shape):
         """One leaf from ``key``, by its kind: projections normal over the
-        root of their fan-in; the embedding normal times ``init_scale``;
-        norms and ``D`` one; the convolution uniform within one over the
+        root of their fan-in; the embedding and an untied head normal times
+        ``init_scale``; norms and ``D`` one; the router's choosing bias
+        uniform within 0.1 (so that choosing by ``s + b`` and weighing by
+        ``s`` differ); the convolution uniform within one over the
         root of its width, as ``torch.nn.Conv1d`` draws it; ``A_log`` the
         log of 1..heads and ``dt_bias`` the inverse softplus of a step
         log-uniform in [0.001, 0.1], as the ``mamba2`` modelling code
@@ -338,8 +598,10 @@ class HybridProgram(ProgramLayout):
         f32 = jnp.float32
         if kind.startswith("norm") or kind in ("ssm_norm", "ssm_d"):
             return jnp.ones(shape, f32)
-        if kind == "embed":
+        if kind in ("embed", "head"):
             return jax.random.normal(key, shape, f32) * self.cfg.init_scale
+        if kind == "router_bias":
+            return jax.random.uniform(key, shape, f32, -0.1, 0.1)
         if kind in ("ssm_conv_w", "ssm_conv_b"):
             bound = self.cfg.ssm_conv ** -0.5
             return jax.random.uniform(key, shape, f32, -bound, bound)
@@ -349,7 +611,8 @@ class HybridProgram(ProgramLayout):
             dt = jnp.exp(jax.random.uniform(
                 key, shape, f32, math.log(1e-3), math.log(1e-1)))
             return dt + jnp.log(-jnp.expm1(-dt))
-        fan_in = shape[0] * shape[1] if kind == "wo" else shape[0]
+        fan_in = {"wo": shape[0] * shape[1], "router": shape[-1],
+                  "moe_in": shape[1], "moe_out": shape[1]}.get(kind, shape[0])
         return jax.random.normal(key, shape, f32) / math.sqrt(fan_in)
 
     def init_params(self, seed=None):
@@ -379,8 +642,21 @@ class HybridProgram(ProgramLayout):
         return checkpoint_name(jnp.einsum("bthe,hed->btd", o, lp["wo"]),
                                ssm.PROJECTION)
 
-    def _layer(self, mixer, lp, h):
-        """One layer over its leaves ``lp`` (kind -> array)."""
+    def _latent_attention(self, lp, x):
+        cfg = self.cfg
+        q, k, v = mla.queries_keys_values(lp, x, cfg)
+        # kept for the backward pass whatever else is: 1/24 of the products'
+        # bytes saves the layer's re-run a third of attention's passes
+        o = checkpoint_name(
+            causal_gqa_attention(q, k, v, q.shape[-1] ** -0.5,
+                                 cfg.attention_block, whole_keys=True),
+            ATTENTION_OUT)
+        with jax.named_scope("mla_out_proj"):
+            return checkpoint_name(
+                jnp.einsum("bthe,hed->btd", o, lp["wo"]), ssm.PROJECTION)
+
+    def _mix(self, mixer, lp, h):
+        """A layer's first half: the residual stream after its mixer."""
         cfg = self.cfg
         scale = jnp.asarray(cfg.residual_multiplier, h.dtype)
         if mixer == "mamba":
@@ -388,20 +664,65 @@ class HybridProgram(ProgramLayout):
                 m = ssm.mamba2_mixer(
                     lp, rms_norm(h, lp["norm1"], cfg.norm_eps), cfg)
         else:
+            attend = (self._latent_attention if mixer == "latent_attention"
+                      else self._attention)
             with jax.named_scope("attention"):
-                m = self._attention(
-                    lp, rms_norm(h, lp["norm1"], cfg.norm_eps))
-        h = h + scale * m
-        with jax.named_scope("gated_mlp"):
-            m = gated_mlp(lp, rms_norm(h, lp["norm2"], cfg.norm_eps))
+                m = attend(lp, rms_norm(h, lp["norm1"], cfg.norm_eps))
         return h + scale * m
+
+    def _feed(self, ffn, lp, h):
+        """A layer's second half: the stream after its feed-forward."""
+        cfg = self.cfg
+        scale = jnp.asarray(cfg.residual_multiplier, h.dtype)
+        if ffn == "sparse_experts":
+            with jax.named_scope("sparse_experts"):
+                m = moe.sparse_experts(
+                    lp, rms_norm(h, lp["norm2"], cfg.norm_eps), cfg)
+        else:
+            with jax.named_scope("gated_mlp"):
+                m = gated_mlp(lp, rms_norm(h, lp["norm2"], cfg.norm_eps))
+        return h + scale * m
+
+    def _layer(self, mixer, ffn, lp, h):
+        """One layer over its leaves ``lp`` (kind -> array)."""
+        return self._feed(ffn, lp, self._mix(mixer, lp, h))
+
+    def _embed(self, p, ids):
+        with jax.named_scope("embed"):
+            h = jnp.take(p["embed"], ids, axis=0)
+            return h * jnp.asarray(self.cfg.embedding_multiplier, h.dtype)
+
+    def _token_losses(self, p, h, norm, y):
+        """Cross-entropy of every position of ``h`` (b, t, d) against ``y``
+        over the rows held, through the final norm ``norm`` and the head
+        (the embedding where it is tied), float32 (b, t).  Where a
+        prediction module makes the heads two, each is a
+        ``jax.checkpoint``: the backward pass re-runs its logits, and the
+        step never holds both heads' (1 GB each at 16k tokens over 16k
+        rows)."""
+        cfg = self.cfg
+
+        def losses(h, weight, table):
+            hf = rms_norm(h, weight, cfg.norm_eps)
+            logits = jnp.einsum("btd,vd->btv", hf, table,
+                                preferred_element_type=jnp.float32)
+            logits = logits / cfg.logits_scaling
+            return L.vocab_parallel_cross_entropy(logits, y, self.plan)
+
+        if cfg.mtp_modules:
+            losses = jax.checkpoint(losses)
+        with jax.named_scope("lm_head_loss"):
+            return losses(h, p[norm],
+                          p["embed" if cfg.tie_embeddings else "head"])
 
     def loss_replica(self, train_vals, x, y, key):
         """Mean token cross-entropy of the local ``(b, t)`` chunk over the
-        held vocabulary; ``train_vals`` follow ``param_names``.  Every layer
-        is one ``jax.checkpoint``; :func:`keeps_products` decides, while this
-        is traced, whether the layers' projection products are among its
-        residuals (docs/transformer.md "The layer table")."""
+        held vocabulary, and ``mtp_weight`` times the prediction module's
+        where the configuration has one; ``train_vals`` follow
+        ``param_names``.  Every layer is one ``jax.checkpoint``;
+        :func:`keeps_products` decides, while this is traced, whether the
+        layers' projection products are among its residuals
+        (docs/transformer.md "The layer table")."""
         cfg = self.cfg
         p = dict(zip(self.param_names, train_vals))
         dtype = p["embed"].dtype
@@ -410,34 +731,93 @@ class HybridProgram(ProgramLayout):
         # one checkpoint a layer, with or without the tagged products among
         # its residuals; everything else of a layer is re-run either way
         policy = jax.checkpoint_policies.save_only_these_names(
-            *([ssm.PROJECTION] if keep else []))
-        with jax.named_scope("embed"):
-            h = jnp.take(p["embed"], x, axis=0)
-            h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
-        for i, mixer in enumerate(cfg.layer_types):
-            lp = {kind: p["l%d_%s" % (i, kind)]
-                  for kind in LAYER_LEAVES[mixer]}
+            ATTENTION_OUT, *([ssm.PROJECTION] if keep else []))
+
+        def run(prefix, mixer, ffn, h):
+            lp = _block_leaves(p, prefix, mixer, ffn)
             layer = jax.checkpoint(
-                lambda lp, h, mixer=mixer: self._layer(mixer, lp, h),
-                policy=policy)
+                lambda lp, h: self._layer(mixer, ffn, lp, h), policy=policy)
             _compiles.count("recomputed_layers")
             _compiles.count("kept_product_layers", int(keep))
             if mixer == "mamba":
                 _compiles.count("ssm_layers")
                 _compiles.count("ssm_kernel_layers",
                                 int(ssm.scan_kernel_tiles(cfg, dtype)))
+            _compiles.count("latent_attention_layers",
+                            int(mixer == "latent_attention"))
+            _compiles.count("moe_layers", int(ffn == "sparse_experts"))
+            return layer(lp, h)
+
+        h = self._embed(p, x)
+        for i, entry in enumerate(cfg.blocks):
             with jax.named_scope("l%d" % i):
-                h = layer(lp, h)
+                h = run(*entry, h)
         _compiles.note("ssm_chunks_per_seq",
                        -(-x.shape[1] // cfg.ssm_chunk))
         _compiles.note("kept_product_bytes",
                        kept_product_bytes(cfg, *x.shape, dtype) * keep)
-        with jax.named_scope("lm_head_loss"):
-            hf = rms_norm(h, p["norm_f"], cfg.norm_eps)
-            logits = jnp.einsum("btd,vd->btv", hf, p["embed"],
-                                preferred_element_type=jnp.float32)
-            logits = logits / cfg.logits_scaling
-            return L.vocab_parallel_cross_entropy(logits, y, self.plan).mean()
+        if "sparse_experts" in cfg.ffn_types:
+            _compiles.note("experts_held", cfg.experts_held)
+            _compiles.note("router_width", cfg.n_routed_experts)
+            _compiles.note("moe_grouped_rows", moe.buffer_rows(cfg, x.size))
+            _compiles.note("moe_expected_rows",
+                           int(moe.expected_rows(cfg, x.size)))
+        loss = self._token_losses(p, h, "norm_f", y).mean()
+        for entry in cfg.mtp_blocks:
+            _compiles.count("mtp_modules")
+            with jax.named_scope("mtp_module"):
+                # position i reads the stream before the final norm and the
+                # token after it, and is asked for the one after that
+                u = jnp.concatenate(
+                    [rms_norm(h, p["mtp_norm_h"], cfg.norm_eps),
+                     rms_norm(self._embed(p, y), p["mtp_norm_e"],
+                              cfg.norm_eps)], axis=-1) @ p["mtp_eh_proj"]
+                u = run(*entry, u)
+                # a row's last position has no token two ahead
+                ahead = jnp.pad(y[:, 1:], ((0, 0), (0, 1)))
+                asked = (jnp.arange(y.shape[1]) < y.shape[1] - 1).astype(
+                    jnp.float32)
+                losses = self._token_losses(p, u, "mtp_norm_f", ahead)
+                loss = loss + cfg.mtp_weight * (
+                    jnp.sum(losses * asked) / (y.shape[0] * asked.sum()))
+        return loss
+
+    def routing_report(self, train_vals, x):
+        """Where the routers of the main model's expert layers send the
+        tokens ``x`` (b, t) under the weights ``train_vals``: for every such
+        layer the rows routed here, against the buffer's and an even
+        router's, and the load of each expert held with its largest over its
+        mean.  A host-side diagnostic outside the step
+        (docs/observability.md "Inside the step")."""
+        cfg = self.cfg
+
+        @jax.jit
+        def loads(train_vals, x):
+            p = dict(zip(self.param_names, train_vals))
+            h, out = self._embed(p, x), []
+            for prefix, mixer, ffn in cfg.blocks:
+                lp = _block_leaves(p, prefix, mixer, ffn)
+                h = self._mix(mixer, lp, h)
+                if ffn == "sparse_experts":
+                    out.append(moe.held_loads(
+                        lp, rms_norm(h, lp["norm2"], cfg.norm_eps), cfg))
+                h = self._feed(ffn, lp, h)
+            return out
+
+        names = [prefix[:-1] for prefix, _, ffn in cfg.blocks
+                 if ffn == "sparse_experts"]
+        report = []
+        for name, load in zip(names, jax.device_get(
+                loads(tuple(train_vals), x))):
+            load = [int(v) for v in load]
+            mean = sum(load) / len(load)
+            report.append({
+                "layer": name, "rows": sum(load),
+                "buffer_rows": moe.buffer_rows(cfg, x.size),
+                "expected_rows": moe.expected_rows(cfg, x.size),
+                "load": load,
+                "max_over_mean": max(load) / mean if mean else 0.0})
+        return report
 
     def describe(self):
         return {"config": self.cfg.describe(), "plan": self.plan.describe(),

@@ -38,9 +38,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-__all__ = ["TP_ROW_PSUM", "layer_norm", "column_parallel_dense",
-           "row_parallel_out", "copy_to_model", "complete_psum",
+from .ssm import PROJECTION
+
+__all__ = ["TP_ROW_PSUM", "layer_norm", "rms_norm", "gated_mlp",
+           "column_parallel_dense", "row_parallel_out", "copy_to_model",
+           "complete_psum",
            "vocab_parallel_embedding", "vocab_parallel_cross_entropy",
            "sequence_offset"]
 
@@ -124,6 +128,23 @@ def layer_norm(x, scale, bias, eps=1e-5):
     mu = x.mean(axis=-1, keepdims=True)
     var = ((x - mu) ** 2).mean(axis=-1, keepdims=True)
     return (x - mu) * lax.rsqrt(var + eps) * scale + bias
+
+
+def rms_norm(x, weight, eps):
+    """``x / sqrt(mean(x^2) + eps) * weight``, the mean in float32."""
+    xf = x.astype(jnp.float32)
+    xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                        + eps)
+    return (xf * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def gated_mlp(lp, x):
+    """``(silu(a) * b) W_out`` with ``[a, b] = x W_in``.  ``@ W_out``'s
+    result carries no tag: it is the layer's output, which nothing in the
+    layer's backward pass reads."""
+    a, b = jnp.split(checkpoint_name(x @ lp["mlp_in"], PROJECTION), 2,
+                     axis=-1)
+    return (jax.nn.silu(a) * b) @ lp["mlp_out"]
 
 
 def column_parallel_dense(x, w_local, b_local=None):

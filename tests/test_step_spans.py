@@ -173,7 +173,11 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
                                     "ssm_chunks_per_seq",
                                     "kept_product_layers",
                                     "kept_product_bytes",
-                                    "ssm_kernel_layers"}
+                                    "ssm_kernel_layers",
+                                    "latent_attention_layers", "moe_layers",
+                                    "experts_held", "router_width",
+                                    "moe_grouped_rows", "moe_expected_rows",
+                                    "mtp_modules"}
 
 
 def test_the_doctor_reads_the_spans_and_the_compile_counters(
