@@ -12,12 +12,36 @@ made in exactly one place, :func:`resolve_interpret`; every kernel in
 the package (here, ``fused_optimizer.py``, ``generated_kernels.py``,
 ``ssd_kernels.py``) goes through it.
 
-Training: forward AND backward are Pallas kernels.  The forward emits the
-per-row logsumexp; the backward recomputes probabilities blockwise from
-(q, k, lse) with the standard two-kernel split (dq over k-blocks, dk/dv
+Training: forward AND backward are Pallas kernels, one family
+(:func:`flash_forward`, :func:`flash_backward`, :func:`flash_mha`) for
+``transformer/hybrid.py``'s causal attention, ``parallel/ring_attention.py``'s
+hops and the ``_contrib_flash_attention`` operator.  Queries and keys of
+``e_qk`` columns, values and output of ``e_v``; ``heads / kv_heads`` query
+heads read one key-value head inside one grid step, so ``dk`` and ``dv`` sum
+over the group in VMEM; the blocks are arguments (:func:`flash_tiles` gives
+``HybridLM`` its blocks from the shapes, or declines them).  The forward
+emits the per-row logsumexp; the backward recomputes probabilities blockwise
+from (q, k, lse) with the standard two-kernel split (dq over k-blocks, dk/dv
 over q-blocks), so the (T×T) score matrix never exists in HBM in either
-direction — backward HBM is O(T·D), matching the flash-attention paper's
-recomputation scheme.
+direction.  Under causality a block wholly above the diagonal neither runs
+nor is fetched (its grid step asks for the block the last step that ran
+held), and only the blocks the diagonal crosses pay for the mask.
+
+**Precision, operand by operand** (the einsum spelling's,
+``hybrid._attend_rows``, no lower and no other).  Every product takes its
+operands in the arrays' dtype (bfloat16 in the benchmark's cells, float32
+in the tests: nothing is cast up first) and accumulates in float32
+(``preferred_element_type``): ``q k^T``, ``p v``, ``do v^T``, ``p^T do``,
+``ds k``, ``ds^T q``.  float32 whatever the dtype: the scores and their
+scaling, the mask, the running maximum and sum, the exponentials, the
+accumulator of ``o`` and its division by the row sums, ``lse``, ``delta =
+rowsum(do * o)``, ``dp``, ``ds = p (dp - delta)``, the accumulators of
+``dq``, ``dk``, ``dv`` and their scaling.  ``p`` and ``ds`` are cast to the
+operands' dtype only as the left operand of the next product; ``o`` and the
+three gradients are cast once, when they are written.  Where it differs
+from the einsums: the probabilities are normalised after the second product
+(``(p v) / l`` for ``softmax v``), and ``dp`` is not rounded to bfloat16 on
+its way into ``ds`` as autodiff's is.
 """
 from __future__ import annotations
 
@@ -68,345 +92,465 @@ def _attention_reference(q, k, v, causal, scale):
     return jnp.einsum("bts,bsd->btd", p, v)
 
 
+# ---------------------------------------------------------------------------
+# The flash family: one forward and two backward kernels over q (b, heads,
+# t_q, e_qk), k (b, kv_heads, t_k, e_qk), v (b, kv_heads, t_k, e_v), the
+# grid (b, kv head, block of queries, block of keys) (the backward kernel of
+# the keys: keys outside, queries inside).  A grid step holds the ``heads /
+# kv_heads`` query heads its key-value head serves, one after another; the
+# per-row statistics lse and delta travel as rows (b, heads, 1, t_q).
+# ---------------------------------------------------------------------------
+_A_B = (((1,), (0,)), ((), ()))              # a b
+_A_BT = (((1,), (1,)), ((), ()))             # a b^T
+LANES = 128
+# blocks the kernels are offered, largest first: a grid step costs some
+# 0.35 us whether it runs or is skipped, a 512 x 512 tile of scores 1-2 us
+FLASH_BLOCKS = (1024, 512, 256, 128)
+# v5e's scoped VMEM, which a kernel's blocks, scratch and tiles share
+FLASH_VMEM_BYTES = 16 * 2 ** 20
+
+
+def _dot(a, b, dims=_A_B):
+    """A product of operands in their own dtype, accumulated in float32."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _flash_vmem_bytes(block_q, block_k, group, e_qk, e_v, itemsize):
+    """VMEM of the hungriest of the three kernels at these blocks, reckoned
+    from shapes: the blocks of q, do and their results and of k and v, each
+    twice (the pipeline's two buffers) and as wide as whole lane tiles; the
+    float32 accumulators and running statistics; one and a half float32
+    tiles of scores, or three where the operands are float32 (Mosaic
+    reuses the tiles' room from one query head of the step to the next).
+    Held against what the chip's compiler took and refused at the cells'
+    widths (``tests/test_ssd_kernel.py`` compiles both cells' shapes at the
+    blocks this allows)."""
+    lanes = lambda e: -(-e // LANES) * LANES
+    wide = lanes(e_qk) + lanes(e_v)
+    blocks = 2 * itemsize * (2 * group * block_q + 2 * block_k) * wide
+    scratch = 4 * max(group * block_q, block_k) * (wide + 2 * LANES)
+    return blocks + scratch + 3 * itemsize * block_q * block_k
+
+
+def flash_tiles(t, heads, kv_heads, e_qk, e_v, dtype):
+    """``(block_q, block_k)`` with which the kernels run causal attention of
+    ``t`` positions over themselves, or None where they do not take it:
+    bfloat16 or float32, a whole number of query heads a key-value head,
+    widths that are multiples of 64 (half a lane tile: narrower heads leave
+    most of the 128-wide array idle) and a length that is a multiple of 128.
+    The blocks are of :data:`FLASH_BLOCKS`, divide the length and fit
+    :data:`FLASH_VMEM_BYTES` by :func:`_flash_vmem_bytes`: the longest block
+    of keys first (on a v5e the forward kernel gains more from it), then
+    the longest block of queries.  A pure function of shapes and dtype: one
+    shape traces one spelling."""
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        return None
+    if heads % kv_heads or e_qk % 64 or e_v % 64 or t % LANES:
+        return None
+    fits = lambda block_q, block_k: _flash_vmem_bytes(
+        block_q, block_k, heads // kv_heads, e_qk, e_v,
+        dtype.itemsize) <= FLASH_VMEM_BYTES
+    divide = [block for block in FLASH_BLOCKS if t % block == 0]
+    return next(((block_q, block_k) for block_k in divide
+                 for block_q in divide if fits(block_q, block_k)), None)
+
+
+def _seen(i, j, block_q, block_k, shape, q_axis, causal, t_q=None, t_k=None):
+    """The mask of the tile (block ``i`` of the queries, ``j`` of the keys)
+    of ``shape``, the queries along ``q_axis``: a query sees the keys up to
+    its own position under ``causal``, and nothing past ``t_q`` or ``t_k``
+    counts (given where the axis' last block is ragged)."""
+    iota = jax.lax.broadcasted_iota
+    qpos = i * block_q + iota(jnp.int32, shape, q_axis)
+    kpos = j * block_k + iota(jnp.int32, shape, 1 - q_axis)
+    terms = ([qpos >= kpos] if causal else []) \
+        + ([qpos < t_q] if t_q else []) + ([kpos < t_k] if t_k else [])
+    return functools.reduce(jnp.logical_and, terms)
+
+
+def _as_row(col):
+    """(n, 1) -> (1, n)."""
+    return col.reshape(1, col.shape[0])
+
+
+def _as_col(row):
+    """(1, n) -> (n, 1)."""
+    return row.reshape(row.shape[1], 1)
+
+
+def _rows_before(x, first, limit):
+    """``x`` with the rows from position ``limit`` on zeroed (``first`` the
+    position of row 0): what the grid pads a ragged last block with need not
+    be numbers, and 0 x NaN is NaN."""
+    rows = first + jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
+    return jnp.where(rows < limit, x, jnp.zeros_like(x))
+
+
+def _by_case(i, j, step, *, causal, ragged, block_q, block_k):
+    """Run ``step(masked)`` for the tile (block ``i`` of the queries, ``j``
+    of the keys): not at all where causality hides every key of it, with
+    the mask where the diagonal crosses it or it is the ragged ``last``
+    block of its axis (``ragged``: (index, last index) or None), without
+    elsewhere."""
+    from jax.experimental import pallas as pl
+
+    crossed = j * block_k + block_k - 1 > i * block_q if causal else False
+    if ragged is not None:
+        crossed = crossed | (ragged[0] == ragged[1])
+    if crossed is False:
+        return step(False)
+    runs = j * block_k <= i * block_q + block_q - 1 if causal else True
+    pl.when(runs & crossed)(lambda: step(True))
+    pl.when(runs & jnp.logical_not(crossed))(lambda: step(False))
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-               causal, scale, block_q, block_k, num_k_blocks, t_k):
+               causal, scale, block_q, block_k, t_k):
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(2)
-    qi = pl.program_id(1)
+    i, j = pl.program_id(2), pl.program_id(3)
+    last = pl.num_programs(3) - 1
+    group = q_ref.shape[1]
+    ragged = t_k % block_k != 0
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _step():
-        q = q_ref[0]                                   # (Bq, D)
-        k = k_ref[0]                                   # (Bk, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (Bq, Bk)
-        kpos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        # mask the ragged tail of the last K block (grid padding)
-        valid = kpos < t_k
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = valid & (qpos >= kpos)
-        s = jnp.where(valid, s, _NEG_INF)
-        m_prev = m_scr[:, :1]                          # (Bq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_safe = jnp.where(m_new <= _NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(s <= _NEG_INF / 2, 0.0, p)
-        corr = jnp.exp(jnp.where(m_prev <= _NEG_INF / 2, _NEG_INF, m_prev)
-                       - m_safe)
-        corr = jnp.where(m_prev <= _NEG_INF / 2, 0.0, corr)
-        l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=-1,
-                                                     keepdims=True)
-        # zero padded V rows: p is 0 there, but 0 × garbage/NaN = NaN
-        vrow_ok = (ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)) < t_k
-        v_blk = jnp.where(vrow_ok, v_ref[0], 0.0)
-        pv = jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * corr + pv
-        m_scr[:, :1] = m_new
+    def step(masked):
+        k, v = k_ref[0, 0], v_ref[0, 0]              # (Bk, e_qk), (Bk, e_v)
+        if masked:
+            seen = _seen(i, j, block_q, block_k, (block_q, block_k), 0,
+                         causal, t_k=ragged and t_k)
+            if ragged:
+                v = _rows_before(v, j * block_k, t_k)
+        for g in range(group):
+            s = _dot(q_ref[0, g], k, _A_BT) * scale  # (Bq, Bk) float32
+            if masked:
+                s = jnp.where(seen, s, _NEG_INF)
+            # the mask is finite and key 0 is seen by every row in the
+            # first block: no row's maximum stays at the mask's value
+            m_prev = m_scr[g, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[g, :, :1] = l_scr[g, :, :1] * corr \
+                + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[g] = acc_scr[g] * corr + _dot(p.astype(v.dtype), v, _A_B)
+            m_scr[g, :, :1] = m_new
 
-    if causal:
-        # skip blocks strictly above the diagonal
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _step()
-    else:
-        _step()
+    _by_case(i, j, step, causal=causal, block_q=block_q, block_k=block_k,
+             ragged=(j, last) if ragged else None)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(j == last)
     def _finish():
-        denom = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
-        # per-row logsumexp for the backward recompute: lse = m + log(l).
-        # The 8-row broadcast satisfies the TPU (8, 128) tile constraint on
-        # the (BH, 8, T) lse buffer.
-        row = (m_scr[:, :1] + jnp.log(denom))[:, 0]
-        lse_ref[0] = jnp.broadcast_to(row[None, :], lse_ref[0].shape)
+        for g in range(group):
+            l = l_scr[g, :, :1]
+            o_ref[0, g] = (acc_scr[g] / l).astype(o_ref.dtype)
+            # the rows' logsumexp, for the backward kernels: m + log(l)
+            lse_ref[0, g] = _as_row(m_scr[g, :, :1] + jnp.log(l))
 
 
-def _flash_attention_fwd_impl(q, k, v, causal, scale, block_q, block_k,
-                              interpret):
-    """q/k/v: (BH, T, D) → (BH, T, D)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    BH, T, D = q.shape
-    Tk = k.shape[1]
-    block_q = min(block_q, T)
-    block_k = min(block_k, Tk)
-    nq = pl.cdiv(T, block_q)
-    nk = pl.cdiv(Tk, block_k)
-
-    kernel = functools.partial(
-        _fa_kernel, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, num_k_blocks=nk, t_k=Tk)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=(_sds((BH, T, D), q.dtype, q),
-                   _sds((BH, 8, T), jnp.float32, q)),
-        grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i))),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, D), jnp.float32),     # output accumulator
-        ],
-        name="_fa_kernel",
-        interpret=resolve_interpret(interpret),
-    )(q, k, v)
-
-
-# ---------------------------------------------------------------------------
-# Backward kernels: probabilities are recomputed blockwise from (q, k, lse);
-# delta = rowsum(dO ⊙ O) folds the softmax normalization gradient.
-# ---------------------------------------------------------------------------
 def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                  dq_scr, *, causal, scale, block_q, block_k, num_k_blocks,
-                  t_q, t_k):
+                  dq_scr, *, causal, scale, block_q, block_k, t_k):
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    i, j = pl.program_id(2), pl.program_id(3)
+    last = pl.num_programs(3) - 1
+    group = q_ref.shape[1]
+    ragged = t_k % block_k != 0
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        qpos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        kpos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = (qpos < t_q) & (kpos < t_k)
-        if causal:
-            valid = valid & (qpos >= kpos)
-        lse = lse_ref[0, 0][:, None]                   # (Bq, 1)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-        # zero the grid-padding garbage before it enters a matmul
-        # (0 x inf/NaN = NaN would otherwise leak through p's zeros)
-        qrow_ok = (qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)) < t_q
-        krow_ok = (ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)) < t_k
-        do_blk = jnp.where(qrow_ok, do_ref[0].astype(jnp.float32), 0.0)
-        v_blk = jnp.where(krow_ok, v_ref[0].astype(jnp.float32), 0.0)
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (Bq, Bk)
-        ds = jnp.where(valid, p * (dp - delta_ref[0, 0][:, None]), 0.0)
-        k_blk = jnp.where(krow_ok, k.astype(jnp.float32), 0.0)
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    def step(masked):
+        k, v = k_ref[0, 0], v_ref[0, 0]
+        if masked:
+            seen = _seen(i, j, block_q, block_k, (block_q, block_k), 0,
+                         causal, t_k=ragged and t_k)
+            if ragged:
+                k = _rows_before(k, j * block_k, t_k)
+                v = _rows_before(v, j * block_k, t_k)
+        for g in range(group):
+            s = _dot(q_ref[0, g], k, _A_BT) * scale
+            p = jnp.exp(s - _as_col(lse_ref[0, g]))   # the probabilities
+            if masked:
+                p = jnp.where(seen, p, 0.0)
+            dp = _dot(do_ref[0, g], v, _A_BT)
+            ds = p * (dp - _as_col(delta_ref[0, g]))
+            dq_scr[g] += _dot(ds.astype(k.dtype), k, _A_B)
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _step()
-    else:
-        _step()
+    _by_case(i, j, step, causal=causal, block_q=block_q, block_k=block_k,
+             ragged=(j, last) if ragged else None)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(j == last)
     def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dk_ref, dv_ref, dk_scr, dv_scr, *, causal, scale,
-                   block_q, block_k, num_q_blocks, t_q, t_k):
+                   block_q, block_k, t_q):
+    """Every tile transposed, keys down and queries across: the rows' lse
+    and delta are read as they are stored, and no product transposes its
+    left operand."""
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    j, i = pl.program_id(2), pl.program_id(3)
+    last = pl.num_programs(3) - 1
+    group = q_ref.shape[1]
+    ragged = t_q % block_q != 0
 
-    @pl.when(qi == 0)
+    @pl.when(i == 0)
     def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _step():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        qpos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        kpos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = (qpos < t_q) & (kpos < t_k)
-        if causal:
-            valid = valid & (qpos >= kpos)
-        lse = lse_ref[0, 0][:, None]
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)     # (Bq, Bk)
-        qrow_ok = (qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)) < t_q
-        krow_ok = (ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)) < t_k
-        do = jnp.where(qrow_ok, do_ref[0].astype(jnp.float32), 0.0)
-        q_blk = jnp.where(qrow_ok, q.astype(jnp.float32), 0.0)
-        v_blk = jnp.where(krow_ok, v_ref[0].astype(jnp.float32), 0.0)
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (Bk, D)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (Bq, Bk)
-        ds = jnp.where(valid, p * (dp - delta_ref[0, 0][:, None]), 0.0)
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (Bk, D)
+    def step(masked):
+        k, v = k_ref[0, 0], v_ref[0, 0]
+        if masked:
+            seen = _seen(i, j, block_q, block_k, (block_k, block_q), 1,
+                         causal, t_q=ragged and t_q)
+        for g in range(group):
+            q, do = q_ref[0, g], do_ref[0, g]
+            if masked and ragged:
+                q = _rows_before(q, i * block_q, t_q)
+                do = _rows_before(do, i * block_q, t_q)
+            s = _dot(k, q, _A_BT) * scale            # (Bk, Bq) float32
+            p = jnp.exp(s - lse_ref[0, g])
+            if masked:
+                p = jnp.where(seen, p, 0.0)
+            dv_scr[...] += _dot(p.astype(do.dtype), do, _A_B)
+            dp = _dot(v, do, _A_BT)
+            ds = p * (dp - delta_ref[0, g])
+            if masked and ragged:
+                ds = jnp.where(seen, ds, 0.0)        # delta's padding
+            dk_scr[...] += _dot(ds.astype(q.dtype), q, _A_B)
 
-    if causal:
-        # skip q blocks entirely above the diagonal for this k block
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _step()
-    else:
-        _step()
+    _by_case(i, j, step, causal=causal, block_q=block_q, block_k=block_k,
+             ragged=(i, last) if ragged else None)
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(i == last)
     def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[0, 0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _tile_rows(x):
-    """(BH, T) → (BH, 8, T): the sublane-broadcast tile layout the kernels
-    read per-row scalars from."""
-    BH, T = x.shape
-    return jnp.broadcast_to(x[:, None, :], (BH, 8, T))
+def _flash_specs(q, k, v, causal, block_q, block_k, keys_outside=False):
+    """Blocks, grid and block specs of a call.  Under causality the steps
+    that are skipped ask for the block the last step that ran held, so the
+    pipeline fetches nothing for them."""
+    from jax.experimental import pallas as pl
+
+    b, heads, t_q, e_qk = q.shape
+    _, kv_heads, t_k, e_v = v.shape
+    group = heads // kv_heads
+    block_q, block_k = min(block_q, t_q), min(block_k, t_k)
+    n_q, n_k = pl.cdiv(t_q, block_q), pl.cdiv(t_k, block_k)
+    if keys_outside:
+        grid = (b, kv_heads, n_k, n_q)
+        q_of = lambda j, i: jnp.maximum(i, j * block_k // block_q) \
+            if causal else i
+        k_of = lambda j, i: j
+    else:
+        grid = (b, kv_heads, n_q, n_k)
+        q_of = lambda i, j: i
+        k_of = lambda i, j: jnp.minimum(
+            j, (i * block_q + block_q - 1) // block_k) if causal else j
+    spec = lambda rows, width, of: pl.BlockSpec(
+        (1, rows[0], rows[1], width),
+        lambda n, h, x, y: (n, h, of(x, y), 0))
+    specs = {"q": spec((group, block_q), e_qk, q_of),
+             "o": spec((group, block_q), e_v, q_of),
+             "k": spec((1, block_k), e_qk, k_of),
+             "v": spec((1, block_k), e_v, k_of),
+             "row": pl.BlockSpec((1, group, 1, block_q),
+                                 lambda n, h, x, y: (n, h, 0, q_of(x, y)))}
+    return block_q, block_k, grid, group, specs
+
+
+def _flash_params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=(
+        "parallel", "parallel", "parallel", "arbitrary"))
+
+
+# jax traces and lowers every ``pallas_call`` call site on its own (0.7 s a
+# site on a v5e's host, PERF.md section 7); as ``jit``s the sites of one
+# shape share one trace and one lowered function
+_STATIC = ("causal", "scale", "block_q", "block_k", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def flash_forward(q, k, v, *, causal, scale, block_q=128, block_k=128,
+                  interpret=None):
+    """``(o, lse)``: attention of q (b, heads, t_q, e_qk) over k (b,
+    kv_heads, t_k, e_qk) and v (b, kv_heads, t_k, e_v), and the float32
+    logsumexp of every row's scores, (b, heads, t_q)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, t_q, _ = q.shape
+    t_k, e_v = v.shape[2:]
+    block_q, block_k, grid, group, specs = _flash_specs(
+        q, k, v, causal, block_q, block_k)
+    o, lse = pl.pallas_call(
+        functools.partial(_fa_kernel, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k, t_k=t_k),
+        out_shape=(_sds((b, heads, t_q, e_v), q.dtype, q),
+                   _sds((b, heads, 1, t_q), jnp.float32, q)),
+        grid=grid,
+        in_specs=[specs["q"], specs["k"], specs["v"]],
+        out_specs=(specs["o"], specs["row"]),
+        scratch_shapes=[
+            pltpu.VMEM((group, block_q, LANES), jnp.float32),  # running max
+            pltpu.VMEM((group, block_q, LANES), jnp.float32),  # running sum
+            pltpu.VMEM((group, block_q, e_v), jnp.float32),    # accumulator
+        ],
+        compiler_params=_flash_params(),
+        name="_fa_kernel",
+        interpret=resolve_interpret(interpret),
+    )(q, k, v)
+    return o, lse[:, :, 0]
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def flash_backward(q, k, v, do, lse, delta, *, causal, scale, block_q=128,
+                   block_k=128, interpret=None):
+    """``(dq, dk, dv)`` from the forward pass's operands, the output's
+    cotangent ``do`` (b, heads, t_q, e_v) and the rows' ``lse`` and
+    ``delta = rowsum(do * o)`` (b, heads, t_q) float32: the probabilities
+    are rebuilt a tile at a time in each of two kernels, one over the keys
+    of a block of queries, one over the queries of a block of keys."""
+    return (_flash_dq(q, k, v, do, lse, delta, causal, scale, block_q,
+                      block_k, interpret),
+            *_flash_dkv(q, k, v, do, lse, delta, causal, scale, block_q,
+                        block_k, interpret))
+
+
+def _flash_dq(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
+              interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    block_q, block_k, grid, group, specs = _flash_specs(
+        q, k, v, causal, block_q, block_k)
+    return pl.pallas_call(
+        functools.partial(_fa_dq_kernel, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k,
+                          t_k=k.shape[2]),
+        out_shape=_sds(q.shape, q.dtype, q),
+        grid=grid,
+        in_specs=[specs[n] for n in ("q", "k", "v", "o", "row", "row")],
+        out_specs=specs["q"],
+        scratch_shapes=[pltpu.VMEM((group, block_q, q.shape[-1]),
+                                   jnp.float32)],
+        compiler_params=_flash_params(),
+        name="_fa_dq_kernel",
+        interpret=resolve_interpret(interpret),
+    )(q, k, v, do, lse[:, :, None], delta[:, :, None])
+
+
+def _flash_dkv(q, k, v, do, lse, delta, causal, scale, block_q, block_k,
+               interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    block_q, block_k, grid, _, specs = _flash_specs(
+        q, k, v, causal, block_q, block_k, keys_outside=True)
+    return pl.pallas_call(
+        functools.partial(_fa_dkv_kernel, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k,
+                          t_q=q.shape[2]),
+        out_shape=(_sds(k.shape, k.dtype, q), _sds(v.shape, v.dtype, q)),
+        grid=grid,
+        in_specs=[specs[n] for n in ("q", "k", "v", "o", "row", "row")],
+        out_specs=(specs["k"], specs["v"]),
+        scratch_shapes=[pltpu.VMEM((block_k, k.shape[-1]), jnp.float32),
+                        pltpu.VMEM((block_k, v.shape[-1]), jnp.float32)],
+        compiler_params=_flash_params(),
+        name="_fa_dkv_kernel",
+        interpret=resolve_interpret(interpret),
+    )(q, k, v, do, lse[:, :, None], delta[:, :, None])
 
 
 def flash_delta(o, do):
-    """softmax-normalization gradient delta = rowsum(dO ⊙ O), (BH, T) f32."""
+    """The softmax's part of the scores' gradient, ``rowsum(do * o)`` in
+    float32 over the last axis."""
     return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_result(q, k, v, o, lse, causal, scale, blocks):
+    """``o``, the forward kernel's result, as a function of ``q``, ``k``
+    and ``v`` whose backward pass is the two kernels.  The forward kernel
+    ran outside: its ``o`` and ``lse`` come in as arguments and are the
+    residuals as they stand, so that a ``jax.checkpoint`` around the caller
+    that keeps them (by name) does not run the forward kernel again to have
+    them (:func:`flash_mha`)."""
+    return o
+
+
+def _flash_result_fwd(q, k, v, o, lse, causal, scale, blocks):
+    return o, (q, k, v, o, lse)
+
+
+def _flash_result_bwd(causal, scale, blocks, kept, do):
+    q, k, v, o, lse = kept
+    return flash_backward(q, k, v, do, lse, flash_delta(o, do),
+                          causal=causal, scale=scale, block_q=blocks[0],
+                          block_k=blocks[1]) + (None, None)
+
+
+_flash_result.defvjp(_flash_result_fwd, _flash_result_bwd)
+
+
+def flash_mha(q, k, v, causal, scale, blocks=(128, 128), kept=lambda a: a):
+    """Differentiable attention through the kernels: q (b, heads, t_q,
+    e_qk), k (b, kv_heads, t_k, e_qk), v (b, kv_heads, t_k, e_v) -> (b,
+    heads, t_q, e_v), each key-value head serving ``heads / kv_heads`` query
+    heads in order.  ``kept`` is applied to the two arrays the backward
+    pass reads besides the operands (``o`` and ``lse``): a caller under a
+    ``jax.checkpoint`` names them there for its policy to keep."""
+    stop = jax.lax.stop_gradient
+    o, lse = flash_forward(stop(q), stop(k), stop(v), causal=bool(causal),
+                           scale=float(scale), block_q=blocks[0],
+                           block_k=blocks[1])
+    return _flash_result(q, k, v, kept(o), kept(lse), bool(causal),
+                         float(scale), tuple(blocks))
+
+
+# -- the family over (BH, T, D): ring attention's hops and the operator -------
+def flash_forward_with_lse(q, k, v, causal, scale, interpret=None):
+    """(out, lse) with lse (BH, T) f32 — building block for ring attention."""
+    out, lse = flash_forward(q[:, None], k[:, None], v[:, None],
+                             causal=causal, scale=scale, interpret=interpret)
+    return out[:, 0], lse[:, 0]
 
 
 def flash_dq(q, k, v, do, lse, delta, causal, scale, block_q=128,
              block_k=128, interpret=None):
     """dq for one (q-block × k-chunk) pairing; lse/delta are (BH, T) f32."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    BH, T, D = q.shape
-    Tk = k.shape[1]
-    block_q = min(block_q, T)
-    block_k = min(block_k, Tk)
-    nq = pl.cdiv(T, block_q)
-    nk = pl.cdiv(Tk, block_k)
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
-    row_q = pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i))
-    return pl.pallas_call(
-        functools.partial(_fa_dq_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k, num_k_blocks=nk,
-                          t_q=T, t_k=Tk),
-        out_shape=_sds((BH, T, D), q.dtype, q),
-        grid=(BH, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_q, row_q],
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        name="_fa_dq_kernel",
-        interpret=resolve_interpret(interpret),
-    )(q, k, v, do, _tile_rows(lse), _tile_rows(delta))
+    return _flash_dq(*(a[:, None] for a in (q, k, v, do, lse, delta)),
+                     causal, scale, block_q, block_k, interpret)[:, 0]
 
 
 def flash_dkv(q, k, v, do, lse, delta, causal, scale, block_q=128,
               block_k=128, interpret=None):
-    """(dk, dv) for one (q-chunk × k-block) pairing; k-major grid so q is
-    the accumulation axis."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    BH, T, D = q.shape
-    Tk = k.shape[1]
-    block_q = min(block_q, T)
-    block_k = min(block_k, Tk)
-    nq = pl.cdiv(T, block_q)
-    nk = pl.cdiv(Tk, block_k)
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    row_q = pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i))
-    return pl.pallas_call(
-        functools.partial(_fa_dkv_kernel, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k, num_q_blocks=nq,
-                          t_q=T, t_k=Tk),
-        out_shape=(_sds((BH, Tk, D), k.dtype, q),
-                   _sds((BH, Tk, D), v.dtype, q)),
-        grid=(BH, nk, nq),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_q, row_q],
-        out_specs=(k_spec, k_spec),
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        name="_fa_dkv_kernel",
-        interpret=resolve_interpret(interpret),
-    )(q, k, v, do, _tile_rows(lse), _tile_rows(delta))
+    """(dk, dv) for one (q-chunk × k-block) pairing."""
+    dk, dv = _flash_dkv(*(a[:, None] for a in (q, k, v, do, lse, delta)),
+                        causal, scale, block_q, block_k, interpret)
+    return dk[:, 0], dv[:, 0]
 
 
-def flash_forward_with_lse(q, k, v, causal, scale, interpret=None):
-    """(out, lse) with lse (BH, T) f32 — building block for ring attention."""
-    out, lse8 = _flash_attention_fwd_impl(q, k, v, causal, scale,
-                                          block_q=128, block_k=128,
-                                          interpret=interpret)
-    return out, lse8[:, 0, :]
-
-
-def _flash_attention_bwd_impl(q, k, v, o, lse, do, causal, scale, block_q,
-                              block_k, interpret):
-    delta = flash_delta(o, do)
-    lse2 = lse[:, 0, :]
-    dq = flash_dq(q, k, v, do, lse2, delta, causal, scale, block_q, block_k,
-                  interpret)
-    dk, dv = flash_dkv(q, k, v, do, lse2, delta, causal, scale, block_q,
-                       block_k, interpret)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_core(q, k, v, causal, scale):
-    out, _ = _flash_attention_fwd_impl(q, k, v, causal, scale,
-                                       block_q=128, block_k=128,
-                                       interpret=None)
-    return out
-
-
-def _flash_fwd(q, k, v, causal, scale):
-    out, lse = _flash_attention_fwd_impl(q, k, v, causal, scale,
-                                         block_q=128, block_k=128,
-                                         interpret=None)
-    return out, (q, k, v, out, lse)
-
-
-def _flash_bwd(causal, scale, res, g):
-    q, k, v, o, lse = res
-    return _flash_attention_bwd_impl(q, k, v, o, lse, g, causal, scale,
-                                     block_q=128, block_k=128,
-                                     interpret=None)
-
-
-_flash_core.defvjp(_flash_fwd, _flash_bwd)
+    """Differentiable attention over (BH, T, D)."""
+    return flash_mha(q[:, None], k[:, None], v[:, None], causal, scale)[:, 0]
 
 
 @register("_contrib_flash_attention", arg_names=["query", "key", "value"],
@@ -416,17 +560,11 @@ def flash_attention(query, key, value, causal=False, scale=None):
 
     Memory O(T) instead of O(T²); the per-(batch, head) score blocks live
     only in VMEM.  Works on any backend (interpret mode off-TPU)."""
-    B, T, H, D = query.shape
-    Tk = key.shape[1]
     if scale is None:
-        scale = D ** -0.5
-
-    def to_bh(x, t):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, t, x.shape[-1])
-
-    out = _flash_core(to_bh(query, T), to_bh(key, Tk), to_bh(value, Tk),
-                      bool(causal), float(scale))
-    return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+        scale = query.shape[-1] ** -0.5
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)
+    return heads_first(flash_mha(*map(heads_first, (query, key, value)),
+                                 bool(causal), float(scale)))
 
 
 # ---------------------------------------------------------------------------
@@ -775,19 +913,27 @@ def _out_bytes(eqn):
     return sum(_nbytes(v.aval) for v in eqn.outvars)
 
 
+def _flash_sizes(eqn):
+    """(score pairs, e_qk, e_v, blocks of queries, blocks of keys) of a
+    flash call: q (b, heads, t_q, e_qk), v (b, kv_heads, t_k, e_v); causal
+    masking is not discounted (an upper bound)."""
+    q, _, v = (a.aval for a in eqn.invars[:3])
+    b, heads, t_q, e_qk = (int(x) for x in q.shape)
+    t_k, e_v = (int(x) for x in v.shape[2:])
+    grid = _grid_of(eqn)
+    n_q, n_k = grid[2:] if len(grid) == 4 else (1, 1)
+    return b * heads * t_q * t_k, e_qk, e_v, n_q, n_k
+
+
 @_declare_cost("_fa_kernel")
 def _cost_fa_fwd(eqn):
     q, k, v = (a.aval for a in eqn.invars[:3])
-    bh, t, d = (int(x) for x in q.shape)
-    tk = int(k.shape[1])
-    grid = _grid_of(eqn)
-    nq = grid[1] if len(grid) == 3 else 1
+    pairs, e_qk, e_v, n_q, _ = _flash_sizes(eqn)
     return {
-        # qk^T and pv dots (causal masking not discounted: upper bound)
-        "flops": 4 * bh * t * tk * d,
-        "transcendentals": bh * t * tk + bh * t,      # exp + final log
+        "flops": 2 * pairs * (e_qk + e_v),            # q k^T and p v
+        "transcendentals": pairs + pairs // int(v.shape[2]),  # exp, log
         # q resident across the inner k sweep; k/v re-fetched per q block
-        "bytes_read": _nbytes(q) + nq * (_nbytes(k) + _nbytes(v)),
+        "bytes_read": _nbytes(q) + n_q * (_nbytes(k) + _nbytes(v)),
         "bytes_written": _out_bytes(eqn),             # out + lse
     }
 
@@ -795,16 +941,13 @@ def _cost_fa_fwd(eqn):
 @_declare_cost("_fa_dq_kernel")
 def _cost_fa_dq(eqn):
     q, k, v, do = (a.aval for a in eqn.invars[:4])
-    bh, t, d = (int(x) for x in q.shape)
-    tk = int(k.shape[1])
-    grid = _grid_of(eqn)
-    nq = grid[1] if len(grid) == 3 else 1
+    pairs, e_qk, e_v, n_q, _ = _flash_sizes(eqn)
     rows = sum(_nbytes(a.aval) for a in eqn.invars[4:6])   # lse, delta
     return {
-        "flops": 6 * bh * t * tk * d,                 # s, dp, ds·k dots
-        "transcendentals": bh * t * tk,               # p recompute
+        "flops": 2 * pairs * (2 * e_qk + e_v),        # s, dp, ds k
+        "transcendentals": pairs,                     # p recompute
         "bytes_read": _nbytes(q) + _nbytes(do) + rows
-        + nq * (_nbytes(k) + _nbytes(v)),
+        + n_q * (_nbytes(k) + _nbytes(v)),
         "bytes_written": _out_bytes(eqn),             # dq
     }
 
@@ -812,17 +955,15 @@ def _cost_fa_dq(eqn):
 @_declare_cost("_fa_dkv_kernel")
 def _cost_fa_dkv(eqn):
     q, k, v, do = (a.aval for a in eqn.invars[:4])
-    bh, t, d = (int(x) for x in q.shape)
-    tk = int(k.shape[1])
-    grid = _grid_of(eqn)
-    nk = grid[1] if len(grid) == 3 else 1
+    # the grid is keys outside, queries inside
+    pairs, e_qk, e_v, n_k, _ = _flash_sizes(eqn)
     rows = sum(_nbytes(a.aval) for a in eqn.invars[4:6])
     return {
-        "flops": 8 * bh * t * tk * d,          # s, dv, dp, dk dots
-        "transcendentals": bh * t * tk,
+        "flops": 4 * pairs * (e_qk + e_v),            # s, dv, dp, dk
+        "transcendentals": pairs,
         "bytes_read": _nbytes(k) + _nbytes(v)
-        + nk * (_nbytes(q) + _nbytes(do) + rows),
-        "bytes_written": _out_bytes(eqn),      # dk + dv
+        + n_k * (_nbytes(q) + _nbytes(do) + rows),
+        "bytes_written": _out_bytes(eqn),             # dk + dv
     }
 
 

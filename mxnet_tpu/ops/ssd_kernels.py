@@ -62,16 +62,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..analysis.cost import declare_kernel_cost as _declare_cost
-from .pallas_kernels import _nbytes, _out_bytes, _sds, resolve_interpret
+from .pallas_kernels import (LANES, _A_BT, _dot, _nbytes, _out_bytes, _sds,
+                             resolve_interpret)
 
 __all__ = ["ssd_scan", "tiles", "heads_per_step"]
 
-LANES = 128
 # heads a grid step holds: a sublane tile of the (heads, time) decays
 STEP_HEADS = 8
 
-_A_B = (((1,), (0,)), ((), ()))             # a b
-_A_BT = (((1,), (1,)), ((), ()))            # a b^T
 _AT_B = (((0,), (0,)), ((), ()))            # a^T b
 
 
@@ -114,10 +112,6 @@ def _by_head(members, shape, value):
         out = rows if out is None else jnp.where(
             lax.broadcasted_iota(jnp.int32, shape, 0) < to, rows, out)
     return out
-
-
-def _dot(a, b, dims=_A_B):
-    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
 def _decay_t(ccol_ref, cum, k):

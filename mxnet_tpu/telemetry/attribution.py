@@ -1034,6 +1034,12 @@ def render_doctor(report):
                    compiled.get("recomputed_layers", 0),
                    compiled.get("kept_product_layers", 0),
                    compiled.get("kept_product_bytes", 0) / 1e9))
+        if compiled.get("attention_layers"):
+            lines.append(
+                "   %d attention layer(s) in the traced programs, the scores "
+                "as Pallas flash kernels in %d of them"
+                % (compiled["attention_layers"],
+                   compiled.get("flash_attention_layers", 0)))
         if rec.get("anomalies"):
             lines.append("   %d step-time anomaly event(s) flagged"
                          % rec["anomalies"])

@@ -46,6 +46,13 @@ One ``jax.monitoring`` duration listener, registered when
   (the prediction module's layer counts in both) and prediction modules in
   the programs traced so far (6, 5 and 1 for one trace of the
   ``JoyAI-LLM-Flash`` step);
+- ``attention_layers``, ``flash_attention_layers``: layers whose mixer is
+  grouped-query or latent attention in the programs traced so far, and of
+  them the ones whose scores were traced as the flash kernels of
+  ``ops/pallas_kernels.py`` (``flash_tiles`` decides from the sequence, the
+  heads, the widths and the compute dtype: 6 of 6 for one trace of the
+  ``JoyAI-LLM-Flash`` step, 1 of 1 for ``granite-4.0-h-micro``'s, 0 at the
+  rehearsal sizes, where attention is blocks of rows in ``jax.numpy``);
 - ``experts_held``, ``router_width``, ``moe_grouped_rows``,
   ``moe_expected_rows``: of the last traced program with sparse experts,
   the experts held here and the router's width (16 of 256), the static
@@ -76,7 +83,8 @@ _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "ssm_chunks_per_seq", "kept_product_layers", "kept_product_bytes",
          "ssm_kernel_layers", "latent_attention_layers", "moe_layers",
          "experts_held", "router_width", "moe_grouped_rows",
-         "moe_expected_rows", "mtp_modules")
+         "moe_expected_rows", "mtp_modules", "attention_layers",
+         "flash_attention_layers")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

@@ -39,7 +39,6 @@ embedding rows held here: ids, logits and the loss are over those rows.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -47,6 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import pallas_kernels
 from ..telemetry import compiles as _compiles
 from . import layers as L
 from . import mla, moe, ssm
@@ -66,8 +66,10 @@ DEEPSEEK_FAMILY = ("deepseek_v3", "joyai_llm_flash")
 RESIDENT_BYTES_PER_PARAM = 14
 # the share of the device's memory the reckoning of `keeps_products` may fill
 ROOM = 0.9
-# the `checkpoint_name` of latent attention's result, which a layer's
-# checkpoint always keeps (the grouped-query mixer tags nothing with it)
+# the `checkpoint_name` of what attention's backward pass reads besides its
+# operands (the result and, from the flash kernels, the rows' logsumexp),
+# which a layer's checkpoint always keeps: 1/24 of the products' bytes saves
+# the layer's re-run attention's forward pass
 ATTENTION_OUT = "attention_out"
 
 
@@ -386,8 +388,9 @@ def _layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn="gated_mlp"):
     float32 arrays of the mixer's scores (the scan's decay matrix of every
     chunk and its masked product with ``C B^T``, or one block of query rows
     against the keys, of every sequence or in latent attention of one; each
-    with its gradient).  A scan that runs as the
-    kernel pair (``ssm.scan_kernel_tiles``) has no such array.  Sparse
+    with its gradient).  A scan that runs as the kernel pair
+    (``ssm.scan_kernel_tiles``) has no such array, nor has attention that
+    runs as the flash kernels (:func:`attention_kernel_blocks`).  Sparse
     experts add what the buffer of routed rows holds (the rows gathered,
     the gate's halves and product, the rows' results in both types) and the
     float32 sum they are added into: the router's layout decides the rows,
@@ -401,7 +404,8 @@ def _layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn="gated_mlp"):
         scores = 0 if ssm.scan_kernel_tiles(cfg, dtype) else \
             batch * chunks * cfg.ssm_heads * cfg.ssm_chunk ** 2
     else:
-        scores = cfg.n_heads * min(cfg.attention_block, seq) * seq
+        scores = 0 if attention_kernel_blocks(cfg, mixer, seq, dtype) else \
+            cfg.n_heads * min(cfg.attention_block, seq) * seq
         if mixer == "latent_attention":
             # q and k with their rotary parts turned, v, the output; a
             # block of query rows is of one sequence
@@ -457,84 +461,60 @@ def _attend_rows(q, k, v, scale, start):
     return jnp.einsum("bkgqs,bske->bqkge", probs.astype(v.dtype), v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _attend_whole(q, k, v, scale, block):
-    """Blockwise causal attention with a backward pass of its own, for many
-    blocks over long keys: q (b, t, kv, g, e); k, v (b, t, kv, e).  A
-    block's sequences run in turn (``lax.map``): at the same live scores the
-    blocks are twice as long, and the keys' gradient is added to half as
-    often."""
-    out = []
-    for start in range(0, q.shape[1], block):
-        if out:
-            # a block waits for the one before: its prefix of the keys is
-            # cut when it runs, not ahead with every other block's
-            k, v, out[-1] = lax.optimization_barrier((k, v, out[-1]))
-        seen = start + block
-        out.append(lax.map(
-            lambda row, start=start: _attend_rows(
-                *(a[None] for a in row), scale, start)[0],
-            (q[:, start:seen], k[:, :seen], v[:, :seen])))
-    return jnp.concatenate(out, axis=1)
+def attention_kernel_blocks(cfg, mixer, seq, dtype):
+    """The blocks with which a ``mixer`` (``attention`` or
+    ``latent_attention``) of ``cfg``'s sizes runs ``seq`` positions through
+    the flash kernels, or None where :func:`causal_gqa_attention` spells it
+    as blocks of rows (``pallas_kernels.flash_tiles`` on the mixer's heads
+    and widths)."""
+    if mixer == "latent_attention":
+        return pallas_kernels.flash_tiles(
+            seq, cfg.n_heads, cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim,
+            cfg.v_head_dim, dtype)
+    return pallas_kernels.flash_tiles(seq, cfg.n_heads, cfg.n_kv_heads,
+                                      cfg.head_dim, cfg.head_dim, dtype)
 
 
-def _attend_whole_fwd(q, k, v, scale, block):
-    return _attend_whole(q, k, v, scale, block), (q, k, v)
+def causal_gqa_attention(q, k, v, scale, block):
+    """Causal grouped-query attention.  q (b, t, heads, e); k (b, t,
+    kv_heads, e); v (b, t, kv_heads, e or a width of its own), each
+    key-value head serving ``heads / kv_heads`` query heads in order.
 
+    Where ``pallas_kernels.flash_tiles`` takes the shapes (it is asked, and
+    nothing else is): the flash kernels, forward and hand-written backward,
+    so that no array of scores or probabilities reaches HBM in either
+    direction.  The forward kernel runs outside what is differentiated; its
+    result ``o`` and the rows' logsumexp carry the name
+    :data:`ATTENTION_OUT`, and the backward kernels read them with ``q``,
+    ``k`` and ``v``: a layer's ``jax.checkpoint`` that keeps that name
+    re-runs the projections for the backward pass and not the kernel.
 
-def _attend_whole_bwd(scale, block, kept, g):
-    """A block at a time: its scores again, its queries' gradient, and its
-    keys' and values' gradients added in place to one array each.  Autodiff
-    of the blocks would hold every block's padded part until the last is
-    there (7.5 GB a layer at 32 blocks over 8k keys of 32 heads)."""
-    q, k, v = kept
-    dk, dv, dq = jnp.zeros_like(k), jnp.zeros_like(v), []
-    for start in range(0, q.shape[1], block):
-        seen = start + block
-        k, v, dk, dv = lax.optimization_barrier((k, v, dk, dv))
-
-        def pull_row(row, start=start):
-            q_row, k_row, v_row, g_row = (a[None] for a in row)
-            _, pull = jax.vjp(
-                lambda q, k, v: _attend_rows(q, k, v, scale, start),
-                q_row, k_row, v_row)
-            return tuple(a[0] for a in pull(g_row))
-
-        dq_rows, dk_seen, dv_seen = lax.map(
-            pull_row, (q[:, start:seen], k[:, :seen], v[:, :seen],
-                       g[:, start:seen]))
-        dq.append(dq_rows)
-        dk = dk.at[:, :seen].add(dk_seen)
-        dv = dv.at[:, :seen].add(dv_seen)
-    return jnp.concatenate(dq, axis=1), dk, dv
-
-
-_attend_whole.defvjp(_attend_whole_fwd, _attend_whole_bwd)
-
-
-def causal_gqa_attention(q, k, v, scale, block, whole_keys=False):
-    """Causal grouped-query attention, ``block`` query rows at a time so
-    that no more than ``block x t`` scores a head are live, and no key past
-    a block's last row is read.  q (b, t, heads, e); k (b, t, kv_heads, e);
-    v (b, t, kv_heads, e or a width of its own), each key-value head serving
-    ``heads / kv_heads`` query heads in order.  The scores are recomputed in
-    the backward pass: by autodiff through a ``jax.checkpoint`` a block,
-    which keeps the block's prefix of the keys and values and sums the
-    blocks' parts of their gradient at the end; or, with ``whole_keys``
-    (many blocks over long keys: latent attention at 8k), by
-    :func:`_attend_whole`'s own backward pass, a block's sequences in turn,
-    which keeps the keys and values once."""
+    Elsewhere (the rehearsal sizes, odd widths): ``block`` query rows at a
+    time, so that no more than ``block x t`` scores a head are live and no
+    key past a block's last row is read; the scores are recomputed in the
+    backward pass by autodiff through a ``jax.checkpoint`` a block, which
+    keeps the block's prefix of the keys and values.  ``o`` carries the name
+    there too.  Both multiply operands of the arrays' dtype into float32,
+    take the mask, the maximum, the exponential and the sums in float32, and
+    cast the probabilities to the values' dtype for the second product."""
     b, t, heads, e = q.shape
     kv = k.shape[2]
+    kept = lambda a: checkpoint_name(a, ATTENTION_OUT)
+    blocks = pallas_kernels.flash_tiles(t, heads, kv, e, v.shape[-1],
+                                        q.dtype)
+    if blocks:
+        # the kernels' layout is heads before time; XLA gives the
+        # projections' results that layout where they are made
+        heads_first = lambda a: a.transpose(0, 2, 1, 3)
+        return heads_first(pallas_kernels.flash_mha(
+            *map(heads_first, (q, k, v)), True, scale, blocks, kept=kept))
     q = q.reshape(b, t, kv, heads // kv, e)
-    if whole_keys:
-        out = _attend_whole(q, k, v, scale, block)
-        return out.reshape(b, t, heads, v.shape[-1])
     rows = jax.checkpoint(_attend_rows, static_argnums=(3, 4))
     out = [rows(q[:, start:start + block], k[:, :start + block],
                 v[:, :start + block], scale, start)
            for start in range(0, t, block)]
-    return jnp.concatenate(out, axis=1).reshape(b, t, heads, v.shape[-1])
+    return kept(jnp.concatenate(out, axis=1).reshape(b, t, heads,
+                                                     v.shape[-1]))
 
 
 class HybridProgram(ProgramLayout):
@@ -645,12 +625,8 @@ class HybridProgram(ProgramLayout):
     def _latent_attention(self, lp, x):
         cfg = self.cfg
         q, k, v = mla.queries_keys_values(lp, x, cfg)
-        # kept for the backward pass whatever else is: 1/24 of the products'
-        # bytes saves the layer's re-run a third of attention's passes
-        o = checkpoint_name(
-            causal_gqa_attention(q, k, v, q.shape[-1] ** -0.5,
-                                 cfg.attention_block, whole_keys=True),
-            ATTENTION_OUT)
+        o = causal_gqa_attention(q, k, v, q.shape[-1] ** -0.5,
+                                 cfg.attention_block)
         with jax.named_scope("mla_out_proj"):
             return checkpoint_name(
                 jnp.einsum("bthe,hed->btd", o, lp["wo"]), ssm.PROJECTION)
@@ -743,6 +719,10 @@ class HybridProgram(ProgramLayout):
                 _compiles.count("ssm_layers")
                 _compiles.count("ssm_kernel_layers",
                                 int(ssm.scan_kernel_tiles(cfg, dtype)))
+            else:
+                _compiles.count("attention_layers")
+                _compiles.count("flash_attention_layers", int(bool(
+                    attention_kernel_blocks(cfg, mixer, x.shape[1], dtype))))
             _compiles.count("latent_attention_layers",
                             int(mixer == "latent_attention"))
             _compiles.count("moe_layers", int(ffn == "sparse_experts"))

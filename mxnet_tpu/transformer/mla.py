@@ -15,14 +15,17 @@ rotary, carried by a decoupled part of every query head and by one key of
 
 The scores and the output product are ``hybrid.py``'s, through
 :func:`~.hybrid.causal_gqa_attention` with as many key-value heads as heads:
-``nope + rope`` query-key columns against ``v_head_dim`` value columns.  In
-training nothing is cached, so the key-values are expanded from the latent;
-a latent cache is serving's.
+``nope + rope`` query-key columns against ``v_head_dim`` value columns, as
+the flash kernels of ``ops/pallas_kernels.py`` where ``flash_tiles`` takes
+the shapes (two widths are theirs to take) and as blocks of rows elsewhere.
+In training nothing is cached, so the key-values are expanded from the
+latent; a latent cache is serving's.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from .layers import rms_norm
@@ -52,25 +55,34 @@ def product_widths(cfg):
             h * (cfg.qk_nope_dim + cfg.v_head_dim), cfg.d_model]
 
 
-def rope_interleaved(x, theta, offset=0):
-    """Rotary positions over ``x`` (b, t, ..., r) in float32: the pair of
-    columns ``(2j, 2j+1)`` of position ``p`` turns by ``p theta^(-2j/r)``
-    (``rope_interleave``: the pairs are neighbours, not the two halves).
-    The turned pair stays where it was, so the scores are those of the HF
-    code, which moves the pairs apart first in queries and keys alike."""
+def rope_interleaved(x, theta, offset=0, axis=1):
+    """Rotary positions over ``x`` (b, t, ..., r; the positions along
+    ``axis``) in float32: the pair of columns ``(2j, 2j+1)`` of position
+    ``p`` turns by ``p theta^(-2j/r)`` (``rope_interleave``: the pairs are
+    neighbours, not the two halves).  The turned pair stays where it was, so
+    the scores are those of the HF code, which moves the pairs apart first
+    in queries and keys alike."""
     r = x.shape[-1]
     f32 = jnp.float32
+    axis %= x.ndim
     turns = theta ** (-jnp.arange(0, r, 2, dtype=f32) / r)
-    angle = (offset + jnp.arange(x.shape[1], dtype=f32))[:, None] * turns
-    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (r,)
+    angle = (offset + jnp.arange(x.shape[axis], dtype=f32))[:, None] * turns
+    shape = tuple(n if i in (axis, x.ndim - 1) else 1
+                  for i, n in enumerate(x.shape))
     cos = jnp.repeat(jnp.cos(angle), 2, axis=-1).reshape(shape)
     sin = jnp.repeat(jnp.sin(angle), 2, axis=-1).reshape(shape)
-    xf = x.astype(f32)
-    even = jnp.arange(r) % 2 == 0
-    # the pair's other column, signed: (-x[2j+1], x[2j])
-    other = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
-                      jnp.roll(xf, 1, axis=-1))
-    return (xf * cos + other * sin).astype(x.dtype)
+    # the pair's other column, signed: (-x[2j+1], x[2j]), as a product with
+    # a signed permutation (exact: one term of +-1 a column).  As two rolls
+    # along the columns it is a shuffle of lanes where the columns are the
+    # minor axis, which is where the flash kernels want them: 6.3 ms a layer
+    # forward at the JoyAI cell against 2.7 in the layout XLA chose before
+    columns = np.arange(r)
+    swap = np.zeros((r, r), np.float32)
+    swap[columns + 1 - 2 * (columns % 2), columns] = 2 * (columns % 2) - 1
+    other = jnp.einsum("...r,rs->...s", x, jnp.asarray(swap, x.dtype),
+                       preferred_element_type=f32,
+                       precision=jax.lax.Precision.HIGHEST)
+    return (x.astype(f32) * cos + other * sin).astype(x.dtype)
 
 
 def queries_keys_values(lp, x, cfg):
@@ -78,24 +90,29 @@ def queries_keys_values(lp, x, cfg):
     and the normed residual stream ``x`` (b, t, d): q, k (b, t, heads,
     nope + rope) with the rotary part turned, v (b, t, heads, v_head_dim).
     The low-rank products carry the tag of a projection product the
-    backward pass may keep."""
+    backward pass may keep.  The heads' products are made heads before time
+    (``bhte``), the layout the flash kernels read, and turned and joined so:
+    what is returned are views, and ``causal_gqa_attention``'s transposes
+    undo them (made time before heads, each of q, k and their gradients
+    cost a transposing copy of 200 MB a layer at the JoyAI cell: PERF.md
+    section 6, PR 36)."""
     nope, rank = cfg.qk_nope_dim, cfg.kv_lora_rank
     with jax.named_scope("mla_q_proj"):
         c_q = checkpoint_name(x @ lp["wq_a"], PROJECTION)
         c_q = rms_norm(c_q, lp["norm_q"], cfg.norm_eps)
-        q = checkpoint_name(jnp.einsum("btr,rhe->bthe", c_q, lp["wq_b"]),
+        q = checkpoint_name(jnp.einsum("btr,rhe->bhte", c_q, lp["wq_b"]),
                             PROJECTION)
     with jax.named_scope("mla_kv_proj"):
         c_kv = checkpoint_name(x @ lp["wkv_a"], PROJECTION)
         k_r = c_kv[..., rank:]
         c_kv = rms_norm(c_kv[..., :rank], lp["norm_kv"], cfg.norm_eps)
-        kv = checkpoint_name(jnp.einsum("btr,rhe->bthe", c_kv, lp["wkv_b"]),
+        kv = checkpoint_name(jnp.einsum("btr,rhe->bhte", c_kv, lp["wkv_b"]),
                              PROJECTION)
     with jax.named_scope("mla_rope"):
-        q_r = rope_interleaved(q[..., nope:], cfg.rope_theta)
-        k_r = rope_interleaved(k_r[:, :, None, :], cfg.rope_theta)
+        q_r = rope_interleaved(q[..., nope:], cfg.rope_theta, axis=2)
+        k_r = rope_interleaved(k_r[:, None], cfg.rope_theta, axis=2)
         q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
         k = jnp.concatenate(
             [kv[..., :nope],
              jnp.broadcast_to(k_r, kv.shape[:3] + k_r.shape[-1:])], axis=-1)
-    return q, k, kv[..., nope:]
+    return tuple(a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., nope:]))

@@ -360,3 +360,50 @@ def test_the_chips_compiler_takes_both_kernels_at_the_cells_widths(
         .lower(*args).compile().as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
     assert not re.findall(r"f32\[[0-9,]*256,256\]", text)
+
+
+# the flash kernels of ``ops/pallas_kernels.py`` are compiled here and not in
+# ``tests/test_flash_hybrid.py``: one file describes the chip, so one worker
+# of a test run loads its compiler
+@pytest.mark.parametrize("b,t,heads,kv_heads,e_qk,e_v", [
+    (2, 8192, 32, 32, 192, 128),                 # JoyAI-LLM-Flash.tokens
+    (1, 4096, 32, 8, 64, 64),                    # granite-4.0-h-micro.tokens
+], ids=["JoyAI-LLM-Flash", "granite-4.0-h-micro"])
+def test_the_chips_compiler_takes_the_flash_kernels_at_the_cells_widths(
+        one_chip, monkeypatch, request, b, t, heads, kv_heads, e_qk, e_v):
+    """One layer's causal attention of each language-model cell, under the
+    layer's checkpoint and its policy, compiled for a v5e that is described,
+    not attached: value and gradient hold the forward kernel once (the
+    re-run reads the kept ``o`` and ``lse``) and each backward kernel once,
+    and no float32 or bfloat16 array of a block of query rows by the keys."""
+    import re
+    from mxnet_tpu.ops import pallas_kernels
+    from mxnet_tpu.transformer import hybrid
+    monkeypatch.setattr(pallas_kernels, "resolve_interpret",
+                        lambda *a: False)
+    forget = lambda: [fn.clear_cache() for fn in (
+        pallas_kernels.flash_forward, pallas_kernels.flash_backward)]
+    forget()
+    request.addfinalizer(forget)
+    bf16 = jnp.bfloat16
+    assert pallas_kernels.flash_tiles(t, heads, kv_heads, e_qk, e_v, bf16)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, bf16, sharding=one_chip)
+
+    layer = jax.checkpoint(
+        lambda q, k, v: hybrid.causal_gqa_attention(
+            q * 2, k * 2, v * 2, e_qk ** -0.5, 512),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            hybrid.ATTENTION_OUT))
+    loss = lambda *a: layer(*a).astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        sds(b, t, heads, e_qk), sds(b, t, kv_heads, e_qk),
+        sds(b, t, kv_heads, e_v)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    for kernel in ("_fa_kernel", "_fa_dq_kernel", "_fa_dkv_kernel"):
+        assert sum("/%s/pallas_call" % kernel in c for c in calls) == 1
+    assert len(calls) == 3
+    assert not re.findall(
+        r"(?:f32|bf16)\[[0-9,]*(?:512|1024|%d),%d\]" % (t, t), text)

@@ -177,7 +177,8 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
                                     "latent_attention_layers", "moe_layers",
                                     "experts_held", "router_width",
                                     "moe_grouped_rows", "moe_expected_rows",
-                                    "mtp_modules"}
+                                    "mtp_modules", "attention_layers",
+                                    "flash_attention_layers"}
 
 
 def test_the_doctor_reads_the_spans_and_the_compile_counters(
