@@ -58,7 +58,14 @@ One ``jax.monitoring`` duration listener, registered when
   the experts held here and the router's width (16 of 256), the static
   rows of the buffer the grouped products are handed, a layer a step, and
   the rows an even router sends here (``tokens x experts a token x held /
-  width``: 24,576 and 8,192 in that step; no sums: the newest values).
+  width``: 24,576 and 8,192 in that step; no sums: the newest values);
+- ``linear_attention_layers``, ``kda_chunks_per_seq``, ``moe_groups_kept``:
+  layers whose mixer is delta-rule linear attention (``transformer/kda.py``)
+  in the programs traced so far (6 for one trace of the seven-layer
+  ``Ling-3.0-flash`` step), the chunks its scan cuts a sequence of the last
+  traced program into (256 at 16,384 tokens), and the groups of experts a
+  token may choose from in the last traced program with sparse experts (4 of
+  8 in that step; 0 where the router has no group step).
 
 Always on: the listener fires only when something is traced, lowered or
 compiled, which a steady step never does.  ``telemetry.enable()`` calls
@@ -84,7 +91,8 @@ _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "ssm_kernel_layers", "latent_attention_layers", "moe_layers",
          "experts_held", "router_width", "moe_grouped_rows",
          "moe_expected_rows", "mtp_modules", "attention_layers",
-         "flash_attention_layers")
+         "flash_attention_layers", "linear_attention_layers",
+         "kda_chunks_per_seq", "moe_groups_kept")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

@@ -1,8 +1,8 @@
 """A language model whose layers come from a table, a pair a layer: a mixer
-(Mamba-2 state-space, grouped-query attention, or latent attention with
-rotary positions) and a feed-forward (gated, or sparse experts of which this
-chip holds a share), on the ``mesh_plan`` path (docs/transformer.md "The
-layer table").
+(Mamba-2 state-space, grouped-query attention, latent attention with rotary
+positions, or delta-rule linear attention with a decay per channel) and a
+feed-forward (gated, or sparse experts of which this chip holds a share), on
+the ``mesh_plan`` path (docs/transformer.md "The layer table").
 
 :class:`HybridLM` is a block for ``DataParallelTrainer(block, None, 'sgd',
 mesh_plan=MeshPlan())``: ``mesh_program(plan)`` gives a
@@ -32,6 +32,13 @@ next-next-token module, whose loss is added with ``mtp_weight``::
     RMSNorm -> the same head; target y_{i+1}; a row's last position left out
     loss = main + mtp_weight * module loss
 
+and those of the ``bailing_hybrid`` family (``Ling-3.0-flash``): of every
+``layer_group_size`` layers the last is ``latent_attention`` without the
+low-rank query path (``q_lora_rank`` None: one product, no norm) and the
+others ``linear_attention`` (``transformer/kda.py``), both with a head-wise
+output gate (``attention_gate``); a router that chooses within the best
+``topk_group`` of ``n_group`` groups of experts; no prediction module.
+
 No layer is divided: a plan with a ``model``, ``sequence`` or ``pipe`` axis
 is refused.  The ``data`` axis works as for every mesh program (the step
 wrapper owns the one gradient exchange).  ``vocab_size`` is the number of
@@ -49,15 +56,15 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import pallas_kernels
 from ..telemetry import compiles as _compiles
 from . import layers as L
-from . import mla, moe, ssm
+from . import kda, mla, moe, ssm
 from .layers import gated_mlp, rms_norm
 from .model import ProgramLayout
 
-__all__ = ["HybridLMConfig", "HybridLM", "HybridProgram", "LAYER_LEAVES",
+__all__ = ["HybridLMConfig", "HybridLM", "HybridProgram",
            "causal_gqa_attention", "rms_norm", "gated_mlp",
            "kept_product_bytes", "keeps_products"]
 
-MIXERS = ("mamba", "attention", "latent_attention")
+MIXERS = ("mamba", "attention", "latent_attention", "linear_attention")
 FEED_FORWARDS = ("gated_mlp", "sparse_experts")
 # the model_types whose keys and equations are deepseek_v3's
 DEEPSEEK_FAMILY = ("deepseek_v3", "joyai_llm_flash")
@@ -79,8 +86,16 @@ class HybridLMConfig:
     :data:`FEED_FORWARDS` a layer (``ffn_types`` None: ``gated_mlp``
     throughout).  ``n_routed_experts`` is the router's width, the published
     count; ``expert_shard = (index, of)`` says which ``n_routed_experts /
-    of`` of them are held here.  :meth:`from_hf` reads the keys of a
-    published ``config.json`` by its ``model_type``."""
+    of`` of them are held here; the router chooses within the best
+    ``topk_group`` of ``n_group`` equal groups of them (1 and 1: no group
+    step).  ``q_lora_rank`` None is latent attention without the low-rank
+    query path; ``attention_gate`` gives latent attention the head-wise
+    output gate that ``linear_attention`` always has.  ``n_heads`` heads of
+    ``kda_head_dim`` key and value columns, the convolution's width
+    ``kda_conv``, the scan's ``kda_chunk`` and the gate's bound
+    ``kda_lower_bound`` are the ``linear_attention`` mixer's
+    (``transformer/kda.py``).  :meth:`from_hf` reads the keys of a published
+    ``config.json`` by its ``model_type``."""
 
     def __init__(self, vocab_size=64, d_model=32, layer_types=("mamba",
                  "attention"), d_ff=64, n_heads=4, n_kv_heads=2, head_dim=8,
@@ -94,7 +109,9 @@ class HybridLMConfig:
                  rope_theta=10000.0, moe_d_ff=16, n_routed_experts=8,
                  experts_per_token=2, expert_shard=(0, 1),
                  n_shared_experts=1, routed_scaling=1.0, norm_topk_prob=True,
-                 mtp_modules=0, mtp_weight=0.3):
+                 mtp_modules=0, mtp_weight=0.3, n_group=1, topk_group=1,
+                 attention_gate=False, kda_head_dim=8,
+                 kda_conv=4, kda_chunk=8, kda_lower_bound=-5.0):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.layer_types = tuple(layer_types)
@@ -121,7 +138,7 @@ class HybridLMConfig:
         self.init_seed = int(init_seed)
         self.init_scale = float(init_scale)
         self.tie_embeddings = bool(tie_embeddings)
-        self.q_lora_rank = int(q_lora_rank)
+        self.q_lora_rank = None if q_lora_rank is None else int(q_lora_rank)
         self.kv_lora_rank = int(kv_lora_rank)
         self.qk_nope_dim = int(qk_nope_dim)
         self.qk_rope_dim = int(qk_rope_dim)
@@ -136,6 +153,13 @@ class HybridLMConfig:
         self.norm_topk_prob = bool(norm_topk_prob)
         self.mtp_modules = int(mtp_modules)
         self.mtp_weight = float(mtp_weight)
+        self.n_group = int(n_group)
+        self.topk_group = int(topk_group)
+        self.attention_gate = bool(attention_gate)
+        self.kda_head_dim = int(kda_head_dim)
+        self.kda_conv = int(kda_conv)
+        self.kda_chunk = int(kda_chunk)
+        self.kda_lower_bound = float(kda_lower_bound)
         unknown = set(self.layer_types) - set(MIXERS)
         if unknown or not self.layer_types:
             raise ValueError("layer_types must name mixers of %s, got %r"
@@ -153,24 +177,45 @@ class HybridLMConfig:
             raise ValueError(
                 "expert_shard %r does not divide %d routed experts"
                 % (self.expert_shard, self.n_routed_experts))
+        groups = self.n_group
+        if (self.n_routed_experts % groups
+                or not 1 <= self.topk_group <= groups
+                or self.experts_per_token
+                > self.topk_group * (self.n_routed_experts // groups)):
+            raise ValueError(
+                "%d of %d groups of %d routed experts cannot give %d a token"
+                % (self.topk_group, groups, self.n_routed_experts,
+                   self.experts_per_token))
         if self.qk_rope_dim % 2:
             raise ValueError("qk_rope_dim %d is odd" % self.qk_rope_dim)
         if self.mtp_modules not in (0, 1):
             raise ValueError("mtp_modules is 0 or 1, got %d"
                              % self.mtp_modules)
+        sub = min(kda.SUB_BLOCK, self.kda_chunk)
+        if ("linear_attention" in self.layer_types
+                and -self.kda_lower_bound * sub > kda.MAX_EXPONENT):
+            raise ValueError(
+                "kda_lower_bound %g lets a sub-block of %d tokens decay past "
+                "e^-%g" % (self.kda_lower_bound, sub, kda.MAX_EXPONENT))
 
     @classmethod
     def from_hf(cls, config, **sizes):
         """From the keys of a published ``config.json``, by its
-        ``model_type``: ``granitemoehybrid`` (dense), or the ``deepseek_v3``
-        family (``joyai_llm_flash``).  ``sizes`` are the arguments the file
-        does not hold (``seq_len``, ``attention_block``, ``expert_shard``,
-        ...).  What is not implemented is refused by name."""
+        ``model_type``: ``granitemoehybrid`` (dense), the ``deepseek_v3``
+        family (``joyai_llm_flash``), or ``bailing_hybrid``
+        (``Ling-3.0-flash``: ``layer_group_size``, the ``kda_*`` keys,
+        ``n_group``/``topk_group``, a null ``q_lora_rank``).  ``sizes`` are
+        the arguments the file does not hold (``seq_len``,
+        ``attention_block``, ``expert_shard``, ``kda_chunk``, ...).  What is
+        not implemented is refused by name, in the one form ``only <key>
+        <value> is implemented, got <value>``."""
         family = config.get("model_type", "granitemoehybrid")
         if family == "granitemoehybrid":
             return cls._from_granite(config, **sizes)
         if family in DEEPSEEK_FAMILY:
             return cls._from_deepseek(config, **sizes)
+        if family == "bailing_hybrid":
+            return cls._from_bailing(config, **sizes)
         raise ValueError("model_type %r is not implemented" % family)
 
     @classmethod
@@ -216,17 +261,16 @@ class HybridLMConfig:
         with sparse experts.  ``n_routed_experts`` is the router's width, as
         published; which of the experts are held here is no key of a
         ``config.json``: ``expert_shard=(index, of)`` among ``sizes`` says
-        (absent: all of them)."""
-        for key, only in (("n_group", 1), ("topk_group", 1),
-                          ("rope_scaling", None), ("scoring_func", "sigmoid"),
+        (absent: all of them).  ``n_group``/``topk_group`` above 1 give the
+        router its group step, a null ``q_lora_rank`` the query path of one
+        product."""
+        for key, only in (("rope_scaling", None), ("scoring_func", "sigmoid"),
                           ("topk_method", "noaux_tc"), ("moe_layer_freq", 1),
                           ("hidden_act", "silu"), ("attention_bias", False),
                           ("rope_interleave", True)):
             if config.get(key, only) != only:
                 raise ValueError("only %s %r is implemented, got %r"
                                  % (key, only, config[key]))
-        if config.get("q_lora_rank") is None:
-            raise ValueError("a null q_lora_rank is not implemented")
         modules = int(config.get("num_nextn_predict_layers", 0))
         if modules > 1:
             raise ValueError("only num_nextn_predict_layers 0 or 1 is "
@@ -241,12 +285,14 @@ class HybridLMConfig:
             d_ff=config["intermediate_size"],
             n_heads=config["num_attention_heads"],
             n_kv_heads=config["num_attention_heads"],
-            q_lora_rank=config["q_lora_rank"],
+            q_lora_rank=config.get("q_lora_rank"),
             kv_lora_rank=config["kv_lora_rank"],
             qk_nope_dim=config["qk_nope_head_dim"],
             qk_rope_dim=config["qk_rope_head_dim"],
             v_head_dim=config["v_head_dim"],
             rope_theta=config["rope_theta"],
+            n_group=config.get("n_group", 1),
+            topk_group=config.get("topk_group", 1),
             moe_d_ff=config["moe_intermediate_size"],
             n_routed_experts=config["n_routed_experts"],
             experts_per_token=config["num_experts_per_tok"],
@@ -256,6 +302,75 @@ class HybridLMConfig:
             tie_embeddings=config["tie_word_embeddings"],
             mtp_modules=modules,
             mtp_weight=config.get("mtp_loss_weight", 0.3),
+            norm_eps=config["rms_norm_eps"], **sizes)
+
+    @classmethod
+    def _from_bailing(cls, config, **sizes):
+        """``num_hidden_layers`` layers, of every ``layer_group_size`` the
+        last with latent attention and the others with delta-rule linear
+        attention; the first ``first_k_dense_replace`` with the dense
+        feed-forward and the rest with sparse experts.  ``num_experts`` is
+        the router's width, as published; ``expert_shard=(index, of)`` among
+        ``sizes`` says which are held here (absent: all of them).  The five
+        places where the family's keys admit two readings are fixed as
+        docs/transformer.md "The layer table" says."""
+        layers = int(config["num_hidden_layers"])
+        for key, only in (
+                ("use_kda_lora", False), ("no_kda_lora", True),
+                ("kda_safe_gate", True), ("linear_silu", True),
+                ("use_nGPT", False), ("value_norm", False),
+                ("up_proj_norm", False), ("scale_router_input", False),
+                ("score_function", "sigmoid"), ("scoring_func", "sigmoid"),
+                ("topk_method", "noaux_tc"), ("rope_scaling", None),
+                ("rope_interleave", True), ("hidden_act", "silu"),
+                ("use_bias", False), ("use_qkv_bias", False),
+                ("use_qk_norm", True), ("use_mla_nope", False),
+                ("group_norm_size", 1),
+                ("gated_attention_proj_granularity_type", "head_wise"),
+                ("moe_router_enable_expert_bias", True),
+                ("q_lora_rank", None), ("num_kv_heads_for_linear_attn", 0),
+                ("num_nextn_predict_layers", 0),
+                ("rotary_dim", config["qk_rope_head_dim"]),
+                ("moe_shared_expert_intermediate_size",
+                 config["moe_intermediate_size"])):
+            if config.get(key, only) != only:
+                raise ValueError("only %s %r is implemented, got %r"
+                                 % (key, only, config[key]))
+        # a clamp on the gated product of an expert or the shared expert
+        for key in ("expert_swiglu_limit_list",
+                    "share_expert_swiglu_limit_list"):
+            held = list(config.get(key) or [])[:layers]
+            if any(held):
+                raise ValueError(
+                    "only %s 0 in the layers held is implemented, got %r"
+                    % (key, held))
+        group = int(config["layer_group_size"])
+        dense = min(int(config["first_k_dense_replace"]), layers)
+        heads = int(config["num_attention_heads"])
+        return cls(
+            vocab_size=config["vocab_size"], d_model=config["hidden_size"],
+            layer_types=["latent_attention" if (i + 1) % group == 0
+                         else "linear_attention" for i in range(layers)],
+            ffn_types=("gated_mlp",) * dense
+            + ("sparse_experts",) * (layers - dense),
+            d_ff=config["intermediate_size"], n_heads=heads,
+            n_kv_heads=heads, q_lora_rank=None, attention_gate=True,
+            kv_lora_rank=config["kv_lora_rank"],
+            qk_nope_dim=config["qk_nope_head_dim"],
+            qk_rope_dim=config["qk_rope_head_dim"],
+            v_head_dim=config["v_head_dim"],
+            rope_theta=config["rope_theta"],
+            kda_head_dim=config["head_dim"],
+            kda_conv=config["short_conv_kernel_size"],
+            kda_lower_bound=config["kda_lower_bound"],
+            moe_d_ff=config["moe_intermediate_size"],
+            n_routed_experts=config["num_experts"],
+            experts_per_token=config["num_experts_per_tok"],
+            n_shared_experts=config["num_shared_experts"],
+            routed_scaling=config["routed_scaling_factor"],
+            norm_topk_prob=config["norm_topk_prob"],
+            n_group=config["n_group"], topk_group=config["topk_group"],
+            tie_embeddings=config["tie_word_embeddings"],
             norm_eps=config["rms_norm_eps"], **sizes)
 
     @property
@@ -293,11 +408,15 @@ class HybridLMConfig:
                 "seq_len", "init_seed", "tie_embeddings", "mtp_modules"]
         if "latent_attention" in self.layer_types:
             keys += ["q_lora_rank", "kv_lora_rank", "qk_nope_dim",
-                     "qk_rope_dim", "v_head_dim", "rope_theta"]
+                     "qk_rope_dim", "v_head_dim", "rope_theta",
+                     "attention_gate"]
+        if "linear_attention" in self.layer_types:
+            keys += ["kda_head_dim", "kda_conv", "kda_chunk",
+                     "kda_lower_bound"]
         if "sparse_experts" in self.ffn_types:
             keys += ["moe_d_ff", "n_routed_experts", "experts_per_token",
                      "expert_shard", "experts_held", "n_shared_experts",
-                     "routed_scaling"]
+                     "routed_scaling", "n_group", "topk_group"]
         return {k: getattr(self, k) for k in keys}
 
 
@@ -313,6 +432,8 @@ def _layer_leaves(cfg, mixer, ffn="gated_mlp"):
                ("ssm_norm", (inner,)), ("ssm_out", (inner, d))]
     elif mixer == "latent_attention":
         mix = mla.leaves(cfg)
+    elif mixer == "linear_attention":
+        mix = kda.leaves(cfg)
     else:
         hq, hkv, e = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         mix = [("wq", (d, hq, e)), ("wk", (d, hkv, e)), ("wv", (d, hkv, e)),
@@ -322,16 +443,11 @@ def _layer_leaves(cfg, mixer, ffn="gated_mlp"):
     return [("norm1", (d,))] + mix + [("norm2", (d,))] + feed
 
 
-# (mixer, feed-forward) -> the kinds of its layer's leaves
-# (docs/transformer.md)
-LAYER_LEAVES = {(mixer, ffn): tuple(k for k, _ in _layer_leaves(
-    HybridLMConfig(), mixer, ffn)) for mixer in MIXERS
-    for ffn in FEED_FORWARDS}
-
-
-def _block_leaves(p, prefix, mixer, ffn):
-    """kind -> array: one block's leaves out of ``p`` (name -> array)."""
-    return {kind: p[prefix + kind] for kind in LAYER_LEAVES[mixer, ffn]}
+def _block_leaves(cfg, p, prefix, mixer, ffn):
+    """kind -> array: one block's leaves out of ``p`` (name -> array); the
+    kinds are the configuration's (docs/transformer.md has the table)."""
+    return {kind: p[prefix + kind]
+            for kind, _ in _layer_leaves(cfg, mixer, ffn)}
 
 
 class HybridLM:
@@ -358,6 +474,8 @@ def _product_widths(cfg, mixer):
                 cfg.d_model]
     if mixer == "latent_attention":
         return mla.product_widths(cfg)
+    if mixer == "linear_attention":
+        return kda.product_widths(cfg)
     return [cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim,
             cfg.n_kv_heads * cfg.head_dim, cfg.d_model]
 
@@ -394,10 +512,27 @@ def _layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn="gated_mlp"):
     experts add what the buffer of routed rows holds (the rows gathered,
     the gate's halves and product, the rows' results in both types) and the
     float32 sum they are added into: the router's layout decides the rows,
-    and both branches of its ``lax.cond`` work in a buffer of that size."""
+    and both branches of its ``lax.cond`` work in a buffer of that size.
+    Linear attention's live set is the chunked scan's, not a score matrix:
+    what one checkpointed block of ``kda.SCAN_BLOCK_CHUNKS`` chunks holds in
+    float32 a token and head (the running sums and their exponentials, the
+    columns' factors of every sub-block, ``W``, ``U`` and the output; the
+    ``L x L`` system, the factors of its inverse and the scores), with
+    their gradients."""
     d = cfg.d_model
     tokens = batch * seq
-    if mixer == "mamba":
+    if mixer == "linear_attention":
+        inner, chunk = cfg.n_heads * cfg.kda_head_dim, cfg.kda_chunk
+        block = min(seq, kda.SCAN_BLOCK_CHUNKS * chunk)
+        subs = -(-chunk // kda.SUB_BLOCK)
+        # q, k, v from the convolution and normed, the gate in float32 (as
+        # two), the output and its norm
+        widths = 10 * inner
+        # float32 elements with their gradients, 8 bytes each: as half as
+        # many of the 16 bytes the sum below takes a score at
+        scores = batch * block * ((6 + subs) * inner
+                                  + 12 * chunk * cfg.n_heads) // 2
+    elif mixer == "mamba":
         inner, n = cfg.ssm_inner, cfg.ssm_state
         chunks = -(-seq // cfg.ssm_chunk)
         widths = 2 * (inner + 2 * n) + 3 * inner
@@ -566,7 +701,8 @@ class HybridProgram(ProgramLayout):
 
     # -- init -------------------------------------------------------------
     def _draw_leaf(self, key, kind, shape):
-        """One leaf from ``key``, by its kind: projections normal over the
+        """One leaf from ``key``, by its kind (``kda_a_log`` and
+        ``kda_dt_bias`` as the code below says): projections normal over the
         root of their fan-in; the embedding and an untied head normal times
         ``init_scale``; norms and ``D`` one; the router's choosing bias
         uniform within 0.1 (so that choosing by ``s + b`` and weighing by
@@ -576,8 +712,20 @@ class HybridProgram(ProgramLayout):
         log-uniform in [0.001, 0.1], as the ``mamba2`` modelling code
         sets them."""
         f32 = jnp.float32
-        if kind.startswith("norm") or kind in ("ssm_norm", "ssm_d"):
+        if kind.startswith("norm") or kind in ("ssm_norm", "ssm_d",
+                                               "kda_norm"):
             return jnp.ones(shape, f32)
+        if kind == "kda_a_log":
+            # rates within a factor of two of one, and biases from -8 to 2,
+            # spread the gate over (lower_bound, 0): memories from a fifth
+            # of a token to a thousand
+            return jax.random.uniform(key, shape, f32, -math.log(2.0),
+                                      math.log(2.0))
+        if kind == "kda_dt_bias":
+            return jax.random.uniform(key, shape, f32, -8.0, 2.0)
+        if kind.startswith("kda_conv_"):
+            bound = self.cfg.kda_conv ** -0.5
+            return jax.random.uniform(key, shape, f32, -bound, bound)
         if kind in ("embed", "head"):
             return jax.random.normal(key, shape, f32) * self.cfg.init_scale
         if kind == "router_bias":
@@ -627,6 +775,9 @@ class HybridProgram(ProgramLayout):
         q, k, v = mla.queries_keys_values(lp, x, cfg)
         o = causal_gqa_attention(q, k, v, q.shape[-1] ** -0.5,
                                  cfg.attention_block)
+        if cfg.attention_gate:
+            with jax.named_scope("mla_gate"):
+                o = mla.gated(lp, x, o)
         with jax.named_scope("mla_out_proj"):
             return checkpoint_name(
                 jnp.einsum("bthe,hed->btd", o, lp["wo"]), ssm.PROJECTION)
@@ -638,6 +789,10 @@ class HybridProgram(ProgramLayout):
         if mixer == "mamba":
             with jax.named_scope("mamba_mixer"):
                 m = ssm.mamba2_mixer(
+                    lp, rms_norm(h, lp["norm1"], cfg.norm_eps), cfg)
+        elif mixer == "linear_attention":
+            with jax.named_scope("kda_mixer"):
+                m = kda.kda_mixer(
                     lp, rms_norm(h, lp["norm1"], cfg.norm_eps), cfg)
         else:
             attend = (self._latent_attention if mixer == "latent_attention"
@@ -710,7 +865,7 @@ class HybridProgram(ProgramLayout):
             ATTENTION_OUT, *([ssm.PROJECTION] if keep else []))
 
         def run(prefix, mixer, ffn, h):
-            lp = _block_leaves(p, prefix, mixer, ffn)
+            lp = _block_leaves(cfg, p, prefix, mixer, ffn)
             layer = jax.checkpoint(
                 lambda lp, h: self._layer(mixer, ffn, lp, h), policy=policy)
             _compiles.count("recomputed_layers")
@@ -719,6 +874,8 @@ class HybridProgram(ProgramLayout):
                 _compiles.count("ssm_layers")
                 _compiles.count("ssm_kernel_layers",
                                 int(ssm.scan_kernel_tiles(cfg, dtype)))
+            elif mixer == "linear_attention":
+                _compiles.count("linear_attention_layers")
             else:
                 _compiles.count("attention_layers")
                 _compiles.count("flash_attention_layers", int(bool(
@@ -736,7 +893,12 @@ class HybridProgram(ProgramLayout):
                        -(-x.shape[1] // cfg.ssm_chunk))
         _compiles.note("kept_product_bytes",
                        kept_product_bytes(cfg, *x.shape, dtype) * keep)
+        if "linear_attention" in cfg.layer_types:
+            _compiles.note("kda_chunks_per_seq",
+                           -(-x.shape[1] // cfg.kda_chunk))
         if "sparse_experts" in cfg.ffn_types:
+            _compiles.note("moe_groups_kept",
+                           cfg.topk_group if cfg.n_group > 1 else 0)
             _compiles.note("experts_held", cfg.experts_held)
             _compiles.note("router_width", cfg.n_routed_experts)
             _compiles.note("moe_grouped_rows", moe.buffer_rows(cfg, x.size))
@@ -776,7 +938,7 @@ class HybridProgram(ProgramLayout):
             p = dict(zip(self.param_names, train_vals))
             h, out = self._embed(p, x), []
             for prefix, mixer, ffn in cfg.blocks:
-                lp = _block_leaves(p, prefix, mixer, ffn)
+                lp = _block_leaves(cfg, p, prefix, mixer, ffn)
                 h = self._mix(mixer, lp, h)
                 if ffn == "sparse_experts":
                     out.append(moe.held_loads(

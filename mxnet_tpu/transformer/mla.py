@@ -13,6 +13,12 @@ rotary, carried by a decoupled part of every query head and by one key of
     o = softmax([q_nope|q_r] [k_nope|k_r]^T / sqrt(nope + rope), causal) v
     out = o W_o
 
+Two variants, by the configuration (``bailing_hybrid``'s): with
+``q_lora_rank`` None the query path is one product and no norm, ``q = x W_q``;
+with ``attention_gate`` the result is gated head by head before the output
+product, ``out = (o * sigmoid(x W_g)[head]) W_o``, one scalar a head and
+token (``W_g``: d -> heads).
+
 The scores and the output product are ``hybrid.py``'s, through
 :func:`~.hybrid.causal_gqa_attention` with as many key-value heads as heads:
 ``nope + rope`` query-key columns against ``v_head_dim`` value columns, as
@@ -31,7 +37,7 @@ from jax.ad_checkpoint import checkpoint_name
 from .layers import rms_norm
 from .ssm import PROJECTION
 
-__all__ = ["rope_interleaved", "queries_keys_values", "leaves",
+__all__ = ["rope_interleaved", "queries_keys_values", "gated", "leaves",
            "product_widths"]
 
 
@@ -39,18 +45,24 @@ def leaves(cfg):
     """[(kind, shape)] of the mixer's leaves, in declaration order."""
     d, h = cfg.d_model, cfg.n_heads
     nope, rope, v = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    return [("wq_a", (d, cfg.q_lora_rank)), ("norm_q", (cfg.q_lora_rank,)),
-            ("wq_b", (cfg.q_lora_rank, h, nope + rope)),
-            ("wkv_a", (d, cfg.kv_lora_rank + rope)),
-            ("norm_kv", (cfg.kv_lora_rank,)),
-            ("wkv_b", (cfg.kv_lora_rank, h, nope + v)),
-            ("wo", (h, v, d))]
+    if cfg.q_lora_rank is None:
+        queries = [("wq", (d, h, nope + rope))]
+    else:
+        queries = [("wq_a", (d, cfg.q_lora_rank)),
+                   ("norm_q", (cfg.q_lora_rank,)),
+                   ("wq_b", (cfg.q_lora_rank, h, nope + rope))]
+    return queries + [("wkv_a", (d, cfg.kv_lora_rank + rope)),
+                      ("norm_kv", (cfg.kv_lora_rank,)),
+                      ("wkv_b", (cfg.kv_lora_rank, h, nope + v))] \
+        + ([("w_gate", (d, h))] if cfg.attention_gate else []) \
+        + [("wo", (h, v, d))]
 
 
 def product_widths(cfg):
     """Widths of the mixer's tagged projection products."""
     h = cfg.n_heads
-    return [cfg.q_lora_rank, h * (cfg.qk_nope_dim + cfg.qk_rope_dim),
+    return ([] if cfg.q_lora_rank is None else [cfg.q_lora_rank]) + [
+            h * (cfg.qk_nope_dim + cfg.qk_rope_dim),
             cfg.kv_lora_rank + cfg.qk_rope_dim,
             h * (cfg.qk_nope_dim + cfg.v_head_dim), cfg.d_model]
 
@@ -98,10 +110,14 @@ def queries_keys_values(lp, x, cfg):
     section 6, PR 36)."""
     nope, rank = cfg.qk_nope_dim, cfg.kv_lora_rank
     with jax.named_scope("mla_q_proj"):
-        c_q = checkpoint_name(x @ lp["wq_a"], PROJECTION)
-        c_q = rms_norm(c_q, lp["norm_q"], cfg.norm_eps)
-        q = checkpoint_name(jnp.einsum("btr,rhe->bhte", c_q, lp["wq_b"]),
-                            PROJECTION)
+        if cfg.q_lora_rank is None:
+            q = checkpoint_name(jnp.einsum("btd,dhe->bhte", x, lp["wq"]),
+                                PROJECTION)
+        else:
+            c_q = checkpoint_name(x @ lp["wq_a"], PROJECTION)
+            c_q = rms_norm(c_q, lp["norm_q"], cfg.norm_eps)
+            q = checkpoint_name(
+                jnp.einsum("btr,rhe->bhte", c_q, lp["wq_b"]), PROJECTION)
     with jax.named_scope("mla_kv_proj"):
         c_kv = checkpoint_name(x @ lp["wkv_a"], PROJECTION)
         k_r = c_kv[..., rank:]
@@ -116,3 +132,10 @@ def queries_keys_values(lp, x, cfg):
             [kv[..., :nope],
              jnp.broadcast_to(k_r, kv.shape[:3] + k_r.shape[-1:])], axis=-1)
     return tuple(a.transpose(0, 2, 1, 3) for a in (q, k, kv[..., nope:]))
+
+
+def gated(lp, x, o):
+    """The head-wise output gate: ``o`` (b, t, heads, v) times ``sigmoid(x
+    W_g)``, one scalar a head and token, the sigmoid in float32."""
+    gate = jax.nn.sigmoid((x @ lp["w_gate"]).astype(jnp.float32))
+    return o * gate[..., None].astype(o.dtype)

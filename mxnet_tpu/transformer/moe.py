@@ -5,6 +5,9 @@ table")::
 
     s = sigmoid(x W_r^T)                     float32, over all the experts
     chosen = top-k of (s + b)                b chooses and never weighs
+        with ``n_group`` > 1 (group-limited choice, ``noaux_tc``): a group's
+        score is the sum of its two largest ``s + b``; only the experts of
+        the ``topk_group`` best groups can be chosen
     w = s[chosen] / (sum s[chosen] + 1e-20) * routed_scaling
     out = Shared(x) + sum over the chosen e held here of w_e Expert_e(x)
 
@@ -39,8 +42,9 @@ __all__ = ["BUFFER_FACTOR", "leaves", "router_choice", "held_loads",
 
 # the buffer of routed rows over the rows an even router sends here.  Sized
 # to routing by seed-drawn choosing biases within 0.1, which send one to two
-# and a half times the even rows a layer here (PERF.md, PR 35); a trained,
-# balanced router would need less
+# and a half times the even rows a layer here (PERF.md, PR 35; a quarter to
+# two and a half times with the group step, PR 38); a trained, balanced
+# router would need less
 BUFFER_FACTOR = 3
 
 
@@ -77,16 +81,34 @@ def buffer_rows(cfg, tokens):
 
 def router_choice(x, router, bias, cfg):
     """``(chosen, weights)`` of tokens ``x`` (T, d): the ``experts_per_token``
-    experts with the largest ``s + b`` of each token (T, k) and their
-    weights from ``s`` alone, float32."""
+    experts with the largest ``s + b`` of each token (T, k), among the
+    experts of the token's ``topk_group`` best groups where the
+    configuration has groups, and their weights from ``s`` alone,
+    float32."""
     s = jax.nn.sigmoid(jnp.einsum("td,ed->te", x, router,
                                   preferred_element_type=jnp.float32))
-    _, chosen = lax.top_k(s + bias.astype(jnp.float32),
-                          cfg.experts_per_token)
+    z = s + bias.astype(jnp.float32)
+    if cfg.n_group > 1:
+        with jax.named_scope("moe_group_choice"):
+            z = _kept_groups(z, cfg.n_group, cfg.topk_group)
+    _, chosen = lax.top_k(z, cfg.experts_per_token)
     w = jnp.take_along_axis(s, chosen, axis=-1)
     if cfg.norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return chosen, w * cfg.routed_scaling
+
+
+def _kept_groups(z, groups, kept):
+    """``z`` (T, experts) with the experts outside each token's ``kept``
+    best of ``groups`` equal groups at ``-inf``; a group's score is the sum
+    of its two largest entries (DeepSeek-V3's group-limited choice)."""
+    grouped = z.reshape(z.shape[0], groups, -1)
+    score = jnp.sum(lax.top_k(grouped, min(2, grouped.shape[-1]))[0],
+                    axis=-1)
+    _, best = lax.top_k(score, kept)                      # (T, kept)
+    keep = jnp.any(best[:, :, None] == jnp.arange(groups)[None, None, :],
+                   axis=1)                                # (T, groups)
+    return jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(z.shape)
 
 
 def _held(chosen, cfg):

@@ -78,7 +78,10 @@ def test_pipeline_mp_matches_inprocess_bitwise(tmp_path):
 def test_pipeline_worker_crash_respawns_exactly_once(tmp_path):
     """SIGKILL a worker mid-epoch: it is respawned, its undelivered
     batches are re-dispatched, and no batch is dropped or duplicated."""
-    rec, idx = _make_rec(tmp_path, n=32)
+    # enough batches that the killed worker still owes some, however fast it
+    # ran ahead of the first read: with 8 it had at times delivered them all
+    # and nothing was left to respawn for
+    rec, idx = _make_rec(tmp_path, n=128)
     it = ImagePipelineIter(num_workers=2, seed=3, shuffle=False,
                            path_imgrec=rec, path_imgidx=idx, **_KW)
     try:
@@ -92,7 +95,7 @@ def test_pipeline_worker_crash_respawns_exactly_once(tmp_path):
                 break
         labels = np.concatenate([first.label[0].asnumpy()]
                                 + [b.label[0].asnumpy() for b in rest])
-        assert sorted(labels.tolist()) == [float(i) for i in range(32)]
+        assert sorted(labels.tolist()) == [float(i) for i in range(128)]
         assert it.stats.snapshot()["respawns"] >= 1
     finally:
         it.close()

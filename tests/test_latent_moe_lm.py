@@ -427,16 +427,32 @@ def test_the_cell_has_680_million_parameters_and_the_references_leaves(
 
 
 @pytest.mark.parametrize("key,value", [
-    ("n_group", 8), ("topk_group", 4), ("scoring_func", "softmax"),
+    ("scoring_func", "softmax"),
     ("rope_scaling", {"type": "yarn", "factor": 40}), ("moe_layer_freq", 2),
     ("num_nextn_predict_layers", 2), ("topk_method", "greedy"),
-    ("rope_interleave", False), ("q_lora_rank", None),
+    ("rope_interleave", False),
     ("hidden_act", "gelu"), ("attention_bias", True),
     ("model_type", "mixtral")])
 def test_from_hf_refuses_what_it_does_not_implement(cell, key, value):
     with pytest.raises(ValueError, match=key if key != "model_type"
                        else "mixtral"):
         HybridLMConfig.from_hf(dict(cell, **{key: value}), seq_len=64)
+
+
+@pytest.mark.parametrize("keys,reads", [
+    ({"n_group": 8, "topk_group": 4}, {"n_group": 8, "topk_group": 4}),
+    ({"n_group": 4, "topk_group": 1}, {"n_group": 4, "topk_group": 1}),
+    ({"q_lora_rank": None}, {"q_lora_rank": None})])
+def test_from_hf_reads_what_pr_38_implemented(cell, keys, reads):
+    """Group-limited choice and a null ``q_lora_rank`` were refused by name
+    until ``moe.router_choice`` had the group step and ``mla.py`` the query
+    path of one product (PR 38); the published JoyAI row has neither."""
+    published = dict(cell, **cell["published"])
+    cfg = HybridLMConfig.from_hf(dict(published, **keys), seq_len=64)
+    assert {k: getattr(cfg, k) for k in reads} == reads
+    kinds = [k for k, _ in hybrid._layer_leaves(cfg, "latent_attention")]
+    assert ("wq" in kinds) == (cfg.q_lora_rank is None)
+    assert ("wq_a" in kinds) == (cfg.q_lora_rank is not None)
 
 
 def test_from_hf_keeps_the_published_meaning_of_n_routed_experts(cell):

@@ -178,7 +178,9 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
                                     "experts_held", "router_width",
                                     "moe_grouped_rows", "moe_expected_rows",
                                     "mtp_modules", "attention_layers",
-                                    "flash_attention_layers"}
+                                    "flash_attention_layers",
+                                    "linear_attention_layers",
+                                    "kda_chunks_per_seq", "moe_groups_kept"}
 
 
 def test_the_doctor_reads_the_spans_and_the_compile_counters(
