@@ -10,10 +10,10 @@ calls, in ONE process (a chip belongs to one process at a time), on a TPU:
 
 Phases, each fatal (an uncaught exception ends the run non-zero, and
 neither closing line is printed): ``device``, ``train``, ``fed``,
-``infer``, ``kernels`` and — when four devices are visible —
-``four_chips``.  Every phase prints one JSON line naming the device JAX
-reports and the jax/jaxlib/libtpu versions; a ``summary`` line lists the
-phases that passed, and the last line of stdout is
+``infer``, ``kernels``, ``delta_rule`` and — when four devices are
+visible — ``four_chips``.  Every phase prints one JSON line naming the
+device JAX reports and the jax/jaxlib/libtpu versions; a ``summary`` line
+lists the phases that passed, and the last line of stdout is
 ``{"ok": true, "device": {...}}``.  The compile seconds and step
 milliseconds in those lines are information, not metrics.
 
@@ -511,6 +511,86 @@ def phase_kernels(smoke):
             "max_abs_err": verdicts}
 
 
+def phase_delta_rule(smoke):
+    """The delta-rule kernel pair of ``ops/kda_kernels.py`` under Mosaic at
+    the ``Ling-3.0-flash`` cell's tile (32 heads of 128 columns, chunks of
+    64; 1,024 tokens), bfloat16 operands, against the token-by-token
+    ``kda.kda_recurrence`` in float32 at precision HIGHEST, values and all
+    five gradients: with seeded keys; with keys that all but coincide at
+    ``beta`` 0.9 and hardly any decay (what a few steps of training make of
+    a layer, and where the first ``Ling-3.0-flash`` tree read NaN on the
+    chip and on no CPU: PERF.md section 6, PR 38); and with the gate at its
+    bound of -5 at every token.  Bounds, as shares of the reference's
+    largest entry: 4% (bfloat16 operands through a chunk's triangular
+    system; a v5e read 0.6% and 0.8%), and 20% for the aligned keys, whose
+    differences bfloat16 rounds to a tenth (a v5e read 7.8%; the nilpotent
+    product read 1e3 to 1e21 there).  The gate's gradient is a sum of
+    ``k x dk`` terms that all but cancels, so its error is held to their
+    size."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import kda_kernels
+    from mxnet_tpu.transformer import kda
+
+    heads, tokens, width = (2, 128, 128) if smoke.rehearsal \
+        else (32, 1024, 128)
+    check(kda_kernels.tiles(64, heads, width, jnp.bfloat16),
+          "kda_kernels.tiles refuses the cell's tile")
+    shape = (1, tokens, heads, width)
+    by_all = (0, 1, 2, 3, 4)
+
+    def inputs(seed, noise=None, g=None, beta=None):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+        base = 0.0 if noise is None else \
+            jax.random.normal(ks[5], (1, 1, heads, width)) / noise
+        q = kda._l2_normed(base + jax.random.normal(ks[0], shape)) \
+            * width ** -0.5
+        k = kda._l2_normed(base + jax.random.normal(ks[1], shape))
+        v = jax.random.normal(ks[2], shape)
+        gate = -5 * jax.nn.sigmoid(3 * jax.random.normal(ks[3], shape) - 2) \
+            if g is None else jnp.full(shape, g, jnp.float32)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])) \
+            if beta is None else jnp.full(shape[:3], beta, jnp.float32)
+        return q, k, v, gate, beta
+
+    def scored(fn, weight):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight),
+            argnums=by_all))
+
+    verdicts = {}
+    cases = (("seeded keys", 4e-2, {}),
+             ("aligned keys", 2e-1, dict(noise=0.1, g=-0.001, beta=0.9)),
+             ("gate at its bound", 4e-2, dict(g=-5.0)))
+    for seed, (tag, bound, kw) in enumerate(cases):
+        args = inputs(40 + seed, **kw)
+        low = tuple(a.astype(jnp.bfloat16) for a in args[:3]) + args[3:]
+        weight = jax.random.normal(jax.random.PRNGKey(9), shape)
+        kernels = scored(kda_kernels.kda_scan, weight)
+        if smoke.on_tpu:
+            check(kernels.lower(*low).as_text().count("tpu_custom_call")
+                  >= 2, "the kernels did not lower to Mosaic calls")
+        out = jax.jit(kda_kernels.kda_scan)(*low)
+        _, got = kernels(*low)
+        with jax.default_matmul_precision("highest"):
+            ref = kda.kda_recurrence(*args)
+            _, want = scored(kda.kda_recurrence, weight)(*args)
+        peak = lambda a: float(jnp.max(jnp.abs(a.astype(jnp.float32))))
+        scales = [peak(w) for w in want]
+        scales[3] += scales[1] * peak(args[1])   # dg: sums of k x dk
+        errs = {"out": peak(out.astype(jnp.float32) - ref) / peak(ref)}
+        for name, a, b, scale in zip(("dq", "dk", "dv", "dg", "dbeta"),
+                                     got, want, scales):
+            errs[name] = peak(a.astype(jnp.float32) - b) / scale
+        check(all(np.isfinite(e) and e <= bound for e in errs.values()),
+              "delta-rule kernels, %s: %r" % (tag, errs))
+        verdicts[tag] = errs
+    return {"heads": heads, "tokens": tokens, "interpret": not smoke.on_tpu,
+            "rel_err": verdicts}
+
+
 def four_data_parallel(smoke, four):
     """The train phase on ``make_mesh((4,), ("data",))`` at global batch
     4 x per-chip: batch split over four distinct devices, training state
@@ -675,6 +755,7 @@ def main(argv=None):
     smoke.run("fed", lambda: phase_fed(smoke, state))
     smoke.run("infer", lambda: phase_infer(smoke, state))
     smoke.run("kernels", lambda: phase_kernels(smoke))
+    smoke.run("delta_rule", lambda: phase_delta_rule(smoke))
     if smoke.device["count"] >= 4:
         smoke.run("four_chips", lambda: phase_four_chips(smoke))
     else:

@@ -1034,6 +1034,14 @@ def render_doctor(report):
                    compiled.get("recomputed_layers", 0),
                    compiled.get("kept_product_layers", 0),
                    compiled.get("kept_product_bytes", 0) / 1e9))
+        if compiled.get("linear_attention_layers"):
+            lines.append(
+                "   %d linear-attention layer(s) in the traced programs, the "
+                "delta rule in %d chunk(s) a sequence, as a Pallas kernel "
+                "pair in %d of them"
+                % (compiled["linear_attention_layers"],
+                   compiled.get("kda_chunks_per_seq", 0),
+                   compiled.get("kda_kernel_layers", 0)))
         if compiled.get("attention_layers"):
             lines.append(
                 "   %d attention layer(s) in the traced programs, the scores "

@@ -65,7 +65,12 @@ One ``jax.monitoring`` duration listener, registered when
   ``Ling-3.0-flash`` step), the chunks its scan cuts a sequence of the last
   traced program into (256 at 16,384 tokens), and the groups of experts a
   token may choose from in the last traced program with sparse experts (4 of
-  8 in that step; 0 where the router has no group step).
+  8 in that step; 0 where the router has no group step);
+- ``kda_kernel_layers``: of the ``linear_attention_layers``, the ones whose
+  delta-rule scan was traced as the Pallas kernel pair of
+  ``ops/kda_kernels.py`` (``kda_kernels.tiles`` decides from the chunk, the
+  heads, their width and the compute dtype: 6 of 6 in that step, 0 at a
+  size that does not tile, where the scan is ``kda.kda_chunked``).
 
 Always on: the listener fires only when something is traced, lowered or
 compiled, which a steady step never does.  ``telemetry.enable()`` calls
@@ -92,7 +97,7 @@ _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "experts_held", "router_width", "moe_grouped_rows",
          "moe_expected_rows", "mtp_modules", "attention_layers",
          "flash_attention_layers", "linear_attention_layers",
-         "kda_chunks_per_seq", "moe_groups_kept")
+         "kda_chunks_per_seq", "moe_groups_kept", "kda_kernel_layers")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

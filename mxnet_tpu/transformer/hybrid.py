@@ -513,25 +513,35 @@ def _layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn="gated_mlp"):
     the gate's halves and product, the rows' results in both types) and the
     float32 sum they are added into: the router's layout decides the rows,
     and both branches of its ``lax.cond`` work in a buffer of that size.
-    Linear attention's live set is the chunked scan's, not a score matrix:
-    what one checkpointed block of ``kda.SCAN_BLOCK_CHUNKS`` chunks holds in
-    float32 a token and head (the running sums and their exponentials, the
-    columns' factors of every sub-block, ``W``, ``U`` and the output; the
-    ``L x L`` system, the factors of its inverse and the scores), with
-    their gradients."""
+    Linear attention's live set is the chunked scan's, not a score matrix.
+    Where the scan runs as the kernel pair (``kda.scan_kernel_tiles``) that
+    is the state every chunk was handed, float32 ``E x E`` a chunk and head,
+    and the five gradients the backward kernel writes (``dq``, ``dk``,
+    ``dv`` in ``dtype``, ``dg`` in float32); in ``jax.numpy`` what one
+    checkpointed block of ``kda.SCAN_BLOCK_CHUNKS`` chunks holds in float32
+    a token and head (the running sums and their exponentials, the columns'
+    factors of every sub-block, ``W``, ``U`` and the output; the ``L x L``
+    system, the factors of its inverse and the scores), with their
+    gradients."""
     d = cfg.d_model
     tokens = batch * seq
     if mixer == "linear_attention":
         inner, chunk = cfg.n_heads * cfg.kda_head_dim, cfg.kda_chunk
-        block = min(seq, kda.SCAN_BLOCK_CHUNKS * chunk)
-        subs = -(-chunk // kda.SUB_BLOCK)
         # q, k, v from the convolution and normed, the gate in float32 (as
         # two), the output and its norm
         widths = 10 * inner
-        # float32 elements with their gradients, 8 bytes each: as half as
-        # many of the 16 bytes the sum below takes a score at
-        scores = batch * block * ((6 + subs) * inner
-                                  + 12 * chunk * cfg.n_heads) // 2
+        if kda.scan_kernel_tiles(cfg, dtype):
+            # the gradients of q, k, v and (as two) of the gate; the handed
+            # states' 4 bytes an element as a quarter as many scores
+            widths += 5 * inner
+            scores = batch * -(-seq // chunk) * inner * cfg.kda_head_dim // 4
+        else:
+            block = min(seq, kda.SCAN_BLOCK_CHUNKS * chunk)
+            subs = -(-chunk // kda.SUB_BLOCK)
+            # float32 elements with their gradients, 8 bytes each: as half
+            # as many of the 16 bytes the sum below takes a score at
+            scores = batch * block * ((6 + subs) * inner
+                                      + 12 * chunk * cfg.n_heads) // 2
     elif mixer == "mamba":
         inner, n = cfg.ssm_inner, cfg.ssm_state
         chunks = -(-seq // cfg.ssm_chunk)
@@ -876,6 +886,8 @@ class HybridProgram(ProgramLayout):
                                 int(ssm.scan_kernel_tiles(cfg, dtype)))
             elif mixer == "linear_attention":
                 _compiles.count("linear_attention_layers")
+                _compiles.count("kda_kernel_layers",
+                                int(kda.scan_kernel_tiles(cfg, dtype)))
             else:
                 _compiles.count("attention_layers")
                 _compiles.count("flash_attention_layers", int(bool(

@@ -50,6 +50,15 @@ sub-block starts the factors reach ``e^+-80``: they fit, but the cotangents
 of the small ones fall under float32's least normal number and the gradient
 of ``k`` at the bound is off by 5e-4; in the middle it is exact to rounding.)
 
+**Which path runs.**  :func:`kda_mixer` asks :func:`scan_kernel_tiles`
+(``ops/kda_kernels.tiles`` over the chunk, the heads, their width and the
+compute dtype) and nothing else: the shapes it takes (the ``Ling-3.0-flash``
+cell's chunks of 64 and heads of 128 columns) run the same algebra as the
+Pallas kernel pair ``kda_kernels.kda_scan``, every array below in VMEM only;
+every other shape runs :func:`kda_chunked`, which is also what the tests
+hold the kernels to.  The next paragraph describes :func:`kda_chunked`, the
+``jax.numpy`` path, alone.
+
 **Memory.**  The chunks' arrays (three decayed copies of ``k``, two of ``q``,
 the ``L x L`` matrices and the factors of the inverse, ``W``, ``U`` and the
 state every chunk is handed) would be some 5 GB a layer at 16k tokens of 32
@@ -72,15 +81,18 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import kda_kernels
 from .layers import rms_norm
 from .ssm import PROJECTION, causal_conv1d
 
 __all__ = ["SUB_BLOCK", "SCAN_BLOCK_CHUNKS", "leaves", "product_widths",
-           "kda_recurrence", "kda_chunked", "kda_mixer"]
+           "kda_recurrence", "kda_chunked", "scan_kernel_tiles", "kda_mixer"]
 
 # tokens whose exponents share a reference, the running sum in their middle
 SUB_BLOCK = 16
-# chunks one checkpointed block of the sequence holds.  A block costs some
+# chunks one checkpointed block of the sequence holds in ``kda_chunked``, the
+# ``jax.numpy`` path (the kernels of ``ops/kda_kernels.py`` have no block
+# and do not read this).  A block costs some
 # 760 device operations around its chunks' scan whatever its size, and the
 # profiler's buffer holds 3.73 million: at 16 the cell's step ran 124,000 and
 # a traced run kept 30 of its 40 steps; at 32 it runs 99,000 and 37.7 are
@@ -292,6 +304,13 @@ def kda_chunked(q, k, v, g, beta, chunk):
     return jnp.moveaxis(o, 0, 1).reshape(b, padded, h, -1)[:, :t]
 
 
+def scan_kernel_tiles(cfg, dtype):
+    """Whether :func:`kda_mixer` spells a scan of ``cfg``'s sizes in
+    ``dtype`` as the kernel pair of ``ops/kda_kernels.py``."""
+    return kda_kernels.tiles(cfg.kda_chunk, cfg.n_heads, cfg.kda_head_dim,
+                             dtype)
+
+
 def _l2_normed(x, eps=1e-6):
     """``x / sqrt(sum x^2 + eps)`` over the last axis, in float32."""
     xf = x.astype(jnp.float32)
@@ -326,7 +345,10 @@ def kda_mixer(lp, x, cfg):
         ).reshape(b, t, h, e))
         beta = jax.nn.sigmoid(beta.astype(f32))
     with jax.named_scope("kda_scan"):
-        o = kda_chunked(q, k, v, g, beta, cfg.kda_chunk)
+        if scan_kernel_tiles(cfg, q.dtype):
+            o = kda_kernels.kda_scan(q, k, v, g, beta)
+        else:
+            o = kda_chunked(q, k, v, g, beta, cfg.kda_chunk)
     with jax.named_scope("kda_out_norm"):
         o = rms_norm(o.reshape(b, t, h * e), lp["kda_norm"], cfg.norm_eps)
         o = (o.reshape(b, t, h, e) * jax.nn.sigmoid(

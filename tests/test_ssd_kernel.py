@@ -407,3 +407,46 @@ def test_the_chips_compiler_takes_the_flash_kernels_at_the_cells_widths(
     assert len(calls) == 3
     assert not re.findall(
         r"(?:f32|bf16)\[[0-9,]*(?:512|1024|%d),%d\]" % (t, t), text)
+
+
+# the delta-rule kernels of ``ops/kda_kernels.py``, for the same reason here
+# and not in ``tests/test_kda_kernel.py``
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_the_chips_compiler_takes_the_delta_rule_kernels_at_the_cells_shape(
+        one_chip, monkeypatch, request, dtype):
+    """One layer's scan of ``Ling-3.0-flash`` (1 x 16,384 tokens, 32 heads of
+    128 key and value columns, chunks of 64) under the layer's checkpoint,
+    compiled for a v5e that is described, not attached: value and gradient
+    hold the forward kernel twice (the step and the layer's re-run) and the
+    backward kernel once, no loop, and no float32 array of a chunk's square
+    or of a block of chunks."""
+    import re
+    from mxnet_tpu.ops import kda_kernels
+    from mxnet_tpu.transformer import kda
+    monkeypatch.setattr(kda_kernels, "resolve_interpret", lambda *a: False)
+    forget = lambda: [fn.clear_cache() for fn in (kda_kernels._forward,
+                                                  kda_kernels._backward)]
+    forget()
+    request.addfinalizer(forget)
+    b, t, h, e = 1, 16384, 32, 128
+    assert kda_kernels.tiles(64, h, e, dtype)
+
+    def sds(shape, kind):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)
+
+    args = (sds((b, t, h, e), dtype),) * 3 + (
+        sds((b, t, h, e), jnp.float32), sds((b, t, h), jnp.float32))
+    layer = jax.checkpoint(lambda q, k, v, g, beta: kda_kernels.kda_scan(
+        q * 2, k, v, g * 0.5, beta))
+    loss = lambda *a: layer(*a).astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))) \
+        .lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sum("/_kda_scan_fwd_kernel/pallas_call" in c for c in calls) == 2
+    assert sum("/_kda_scan_bwd_kernel/pallas_call" in c for c in calls) == 1
+    assert len(calls) == 3
+    assert " while(" not in text
+    assert not re.findall(r"f32\[[0-9,]*,64,64\]", text)
+    assert kda.SCAN_BLOCK_CHUNKS == 32
+    assert not re.findall(r"f32\[[0-9,]*32,64,[0-9,]*\]", text)

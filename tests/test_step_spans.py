@@ -180,7 +180,8 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
                                     "mtp_modules", "attention_layers",
                                     "flash_attention_layers",
                                     "linear_attention_layers",
-                                    "kda_chunks_per_seq", "moe_groups_kept"}
+                                    "kda_chunks_per_seq", "moe_groups_kept",
+                                    "kda_kernel_layers"}
 
 
 def test_the_doctor_reads_the_spans_and_the_compile_counters(
