@@ -119,10 +119,16 @@ def use_compilation_cache():
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and
     nothing is set here.  Otherwise the cache lives at
     ``<checkout>/.jax_cache``: a fixed path, because the path is part of
-    the cache key and a directory that moves never hits."""
+    the cache key and a directory that moves never hits.
+
+    The key holds the programs' metadata (their ``op_name``s, the program
+    scopes): JAX's own key leaves it out, so a step whose scopes changed
+    and nothing else would load an executable compiled with the old ones,
+    and a device trace would name its operations by them."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
-        import jax
         path = os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)

@@ -1461,23 +1461,35 @@ class DataParallelTrainer:
     def lower_step(self, data, label):
         """``jax.stages.Lowered`` of the jitted function :meth:`step`
         dispatches, at this batch geometry and the live training state
-        (replicated single-program tier, after the first step) —
-        ``.as_text()`` shows what the step lowers to, e.g. a Pallas
-        kernel as a Mosaic ``tpu_custom_call``.  It lowers the same
-        function with arguments assembled by the same ``_step_args``;
+        (the replicated tier and the ``mesh_plan`` tier, each one program
+        a step, after the first step) — ``.as_text()`` shows what the
+        step lowers to, e.g. a Pallas kernel as a Mosaic
+        ``tpu_custom_call``; ``.compile().as_text()`` the optimized HLO
+        whose instructions a device trace names.  It lowers the same
+        function with arguments assembled as :meth:`step` assembles them;
         it is not a handle on the executable that already ran.  Nothing
         executes, no buffer is donated."""
-        if self._step_fn is None:
+        step_fn = (getattr(self, "_mesh_step_fn", None)
+                   if self._plan is not None else self._step_fn)
+        if step_fn is None:
             raise RuntimeError(
-                "lower_step() needs the replicated tier's compiled step "
-                "(take one step first; the kvstore, zero=1 and mesh_plan "
-                "tiers run split programs)")
+                "lower_step() needs a one-program tier's compiled step "
+                "(take one step first; the kvstore and zero=1 tiers run "
+                "split programs)")
         batch_sh = self.batch_sharding
-        train_vals, aux_vals = self._live_vals()
-        return self._step_fn.lower(*self._step_args(
-            train_vals, aux_vals, self._put_batch(data, batch_sh),
-            self._put_batch(label, batch_sh), jax.random.PRNGKey(0),
-            *self._step_scalars(self._opt.lr)))
+        return step_fn.lower(*self._tier_args(
+            self._put_batch(data, batch_sh), self._put_batch(label, batch_sh),
+            jax.random.PRNGKey(0), *self._step_scalars(self._opt.lr)))
+
+    def _tier_args(self, x, y, rng, lr, count):
+        """The positional arguments of this tier's step program: the mesh
+        tier's parameters and optimizer leaves, or ``_step_args`` over the
+        live values."""
+        if self._plan is not None:
+            return (tuple(self._mesh_params[n]
+                          for n in self._mesh_param_names),
+                    self._mesh_state_leaves, x, y, rng, lr, count)
+        return self._step_args(*self._live_vals(), x, y, rng, lr, count)
 
     def _live_vals(self):
         """``(train_vals, aux_vals)``: the live parameter arrays in the
@@ -1710,14 +1722,7 @@ class DataParallelTrainer:
             lr_host = (self._opt.lr_scheduler(self._step_count)
                        if self._opt.lr_scheduler else self._opt.lr)
             rng = _rng.next_key()
-            lr, count = self._step_scalars(lr_host)
-            if self._plan is not None:
-                args = (tuple(self._mesh_params[n]
-                              for n in self._mesh_param_names),
-                        self._mesh_state_leaves, x, y, rng, lr, count)
-            else:
-                args = self._step_args(*self._live_vals(), x, y, rng, lr,
-                                       count)
+            args = self._tier_args(x, y, rng, *self._step_scalars(lr_host))
         if attr:
             # step bookkeeping (arg tuples, lr, the key) is host work; the
             # small programs it enqueues can be held back like the step

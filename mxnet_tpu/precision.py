@@ -93,11 +93,15 @@ def is_reduced(dtype):
 def _to_compute(v, dtype):
     """``v`` in the compute ``dtype`` if it is a floating leaf; integer
     leaves (labels, token ids) stay put.  The one cast every tier's step
-    makes at its forward boundary."""
+    makes at its forward boundary, under the program scope
+    ``compute_cast`` (docs/observability.md "Program scopes"): the
+    masters' casts, their transposes in the backward pass, the batch's."""
+    import jax
     import jax.numpy as jnp
 
     if hasattr(v, "dtype") and jnp.issubdtype(v.dtype, jnp.floating):
-        return v.astype(dtype)
+        with jax.named_scope("compute_cast"):
+            return v.astype(dtype)
     return v
 
 

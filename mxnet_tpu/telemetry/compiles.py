@@ -41,11 +41,6 @@ One ``jax.monitoring`` duration listener, registered when
 - ``ssm_chunks_per_seq``, ``kept_product_bytes``: chunks the state-space
   scan of the last traced program cuts a sequence into, and the bytes of
   the products it keeps (2.16e9 in that step; no sums: the newest values);
-- ``latent_attention_layers``, ``moe_layers``, ``mtp_modules``: layers whose
-  mixer is latent attention, layers whose feed-forward is sparse experts
-  (the prediction module's layer counts in both) and prediction modules in
-  the programs traced so far (6, 5 and 1 for one trace of the
-  ``JoyAI-LLM-Flash`` step);
 - ``attention_layers``, ``flash_attention_layers``: layers whose mixer is
   grouped-query or latent attention in the programs traced so far, and of
   them the ones whose scores were traced as the flash kernels of
@@ -53,19 +48,14 @@ One ``jax.monitoring`` duration listener, registered when
   heads, the widths and the compute dtype: 6 of 6 for one trace of the
   ``JoyAI-LLM-Flash`` step, 1 of 1 for ``granite-4.0-h-micro``'s, 0 at the
   rehearsal sizes, where attention is blocks of rows in ``jax.numpy``);
-- ``experts_held``, ``router_width``, ``moe_grouped_rows``,
-  ``moe_expected_rows``: of the last traced program with sparse experts,
-  the experts held here and the router's width (16 of 256), the static
-  rows of the buffer the grouped products are handed, a layer a step, and
-  the rows an even router sends here (``tokens x experts a token x held /
-  width``: 24,576 and 8,192 in that step; no sums: the newest values);
-- ``linear_attention_layers``, ``kda_chunks_per_seq``, ``moe_groups_kept``:
-  layers whose mixer is delta-rule linear attention (``transformer/kda.py``)
-  in the programs traced so far (6 for one trace of the seven-layer
-  ``Ling-3.0-flash`` step), the chunks its scan cuts a sequence of the last
-  traced program into (256 at 16,384 tokens), and the groups of experts a
-  token may choose from in the last traced program with sparse experts (4 of
-  8 in that step; 0 where the router has no group step);
+- ``moe_grouped_rows``: of the last traced program with sparse experts,
+  the static rows of the buffer the grouped products are handed, a layer a
+  step (24,576 in the ``JoyAI-LLM-Flash`` step; no sums: the newest value);
+- ``linear_attention_layers``, ``kda_chunks_per_seq``: layers whose mixer
+  is delta-rule linear attention (``transformer/kda.py``) in the programs
+  traced so far (6 for one trace of the seven-layer ``Ling-3.0-flash``
+  step), and the chunks its scan cuts a sequence of the last traced program
+  into (256 at 16,384 tokens; the newest value);
 - ``kda_kernel_layers``: of the ``linear_attention_layers``, the ones whose
   delta-rule scan was traced as the Pallas kernel pair of
   ``ops/kda_kernels.py`` (``kda_kernels.tiles`` decides from the chunk, the
@@ -93,11 +83,9 @@ BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "blocked_bias_grads", "ssm_layers", "recomputed_layers",
          "ssm_chunks_per_seq", "kept_product_layers", "kept_product_bytes",
-         "ssm_kernel_layers", "latent_attention_layers", "moe_layers",
-         "experts_held", "router_width", "moe_grouped_rows",
-         "moe_expected_rows", "mtp_modules", "attention_layers",
+         "ssm_kernel_layers", "moe_grouped_rows", "attention_layers",
          "flash_attention_layers", "linear_attention_layers",
-         "kda_chunks_per_seq", "moe_groups_kept", "kda_kernel_layers")
+         "kda_chunks_per_seq", "kda_kernel_layers")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

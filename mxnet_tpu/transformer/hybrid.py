@@ -833,10 +833,12 @@ class HybridProgram(ProgramLayout):
             h = jnp.take(p["embed"], ids, axis=0)
             return h * jnp.asarray(self.cfg.embedding_multiplier, h.dtype)
 
-    def _token_losses(self, p, h, norm, y):
-        """Cross-entropy of every position of ``h`` (b, t, d) against ``y``
-        over the rows held, through the final norm ``norm`` and the head
-        (the embedding where it is tied), float32 (b, t).  Where a
+    def _head_loss(self, p, h, norm, y, reduce):
+        """The cross-entropy of every position of ``h`` (b, t, d) against
+        ``y`` over the rows held, float32 (b, t), through the final norm
+        ``norm`` and the head (the embedding where it is tied), reduced to
+        the loss by ``reduce`` (the mean, or the prediction module's
+        weighted mean): all of it under the scope ``lm_head_loss``.  Where a
         prediction module makes the heads two, each is a
         ``jax.checkpoint``: the backward pass re-runs its logits, and the
         step never holds both heads' (1 GB each at 16k tokens over 16k
@@ -853,8 +855,8 @@ class HybridProgram(ProgramLayout):
         if cfg.mtp_modules:
             losses = jax.checkpoint(losses)
         with jax.named_scope("lm_head_loss"):
-            return losses(h, p[norm],
-                          p["embed" if cfg.tie_embeddings else "head"])
+            return reduce(losses(h, p[norm],
+                                 p["embed" if cfg.tie_embeddings else "head"]))
 
     def loss_replica(self, train_vals, x, y, key):
         """Mean token cross-entropy of the local ``(b, t)`` chunk over the
@@ -892,9 +894,6 @@ class HybridProgram(ProgramLayout):
                 _compiles.count("attention_layers")
                 _compiles.count("flash_attention_layers", int(bool(
                     attention_kernel_blocks(cfg, mixer, x.shape[1], dtype))))
-            _compiles.count("latent_attention_layers",
-                            int(mixer == "latent_attention"))
-            _compiles.count("moe_layers", int(ffn == "sparse_experts"))
             return layer(lp, h)
 
         h = self._embed(p, x)
@@ -909,16 +908,9 @@ class HybridProgram(ProgramLayout):
             _compiles.note("kda_chunks_per_seq",
                            -(-x.shape[1] // cfg.kda_chunk))
         if "sparse_experts" in cfg.ffn_types:
-            _compiles.note("moe_groups_kept",
-                           cfg.topk_group if cfg.n_group > 1 else 0)
-            _compiles.note("experts_held", cfg.experts_held)
-            _compiles.note("router_width", cfg.n_routed_experts)
             _compiles.note("moe_grouped_rows", moe.buffer_rows(cfg, x.size))
-            _compiles.note("moe_expected_rows",
-                           int(moe.expected_rows(cfg, x.size)))
-        loss = self._token_losses(p, h, "norm_f", y).mean()
+        loss = self._head_loss(p, h, "norm_f", y, jnp.mean)
         for entry in cfg.mtp_blocks:
-            _compiles.count("mtp_modules")
             with jax.named_scope("mtp_module"):
                 # position i reads the stream before the final norm and the
                 # token after it, and is asked for the one after that
@@ -931,9 +923,11 @@ class HybridProgram(ProgramLayout):
                 ahead = jnp.pad(y[:, 1:], ((0, 0), (0, 1)))
                 asked = (jnp.arange(y.shape[1]) < y.shape[1] - 1).astype(
                     jnp.float32)
-                losses = self._token_losses(p, u, "mtp_norm_f", ahead)
-                loss = loss + cfg.mtp_weight * (
-                    jnp.sum(losses * asked) / (y.shape[0] * asked.sum()))
+                loss = loss + self._head_loss(
+                    p, u, "mtp_norm_f", ahead,
+                    lambda losses: cfg.mtp_weight * (
+                        jnp.sum(losses * asked)
+                        / (y.shape[0] * asked.sum())))
         return loss
 
     def routing_report(self, train_vals, x):

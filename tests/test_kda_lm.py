@@ -676,16 +676,11 @@ def test_the_trace_notes_its_counters(ref):
         tuple(weights[n] for n in program.param_names))
     after = compiles.counters()
     grew = {k: after[k] - before[k] for k in
-            ("linear_attention_layers", "latent_attention_layers",
-             "attention_layers", "moe_layers", "recomputed_layers",
-             "ssm_layers", "mtp_modules")}
-    assert grew == {"linear_attention_layers": 2,
-                    "latent_attention_layers": 1, "attention_layers": 1,
-                    "moe_layers": 2, "recomputed_layers": 3, "ssm_layers": 0,
-                    "mtp_modules": 0}
+            ("linear_attention_layers", "attention_layers",
+             "recomputed_layers", "ssm_layers")}
+    assert grew == {"linear_attention_layers": 2, "attention_layers": 1,
+                    "recomputed_layers": 3, "ssm_layers": 0}
     assert after["kda_chunks_per_seq"] == 3          # 40 tokens in 16s
-    assert after["moe_groups_kept"] == 2
-    assert (after["experts_held"], after["router_width"]) == (4, 16)
     text = lowered.as_text(debug_info=True)
     for scope in ("kda_mixer", "kda_in_proj", "kda_conv", "kda_gate",
                   "kda_scan", "kda_out_norm", "kda_out_proj", "mla_gate",

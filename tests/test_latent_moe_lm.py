@@ -521,14 +521,11 @@ def test_the_trace_notes_its_counters_and_the_routing_report_reads_loads(ref):
         tuple(weights[n] for n in program.param_names))
     after = compiles.counters()
     grew = {k: after[k] - before[k] for k in
-            ("latent_attention_layers", "moe_layers", "mtp_modules",
-             "recomputed_layers", "ssm_layers")}
-    assert grew == {"latent_attention_layers": 4, "moe_layers": 3,
-                    "mtp_modules": 1, "recomputed_layers": 4, "ssm_layers": 0}
+            ("attention_layers", "recomputed_layers", "ssm_layers")}
+    assert grew == {"attention_layers": 4, "recomputed_layers": 4,
+                    "ssm_layers": 0}
     tokens = x.size
-    assert (after["experts_held"], after["router_width"]) == (2, 8)
     assert after["moe_grouped_rows"] == moe.buffer_rows(cfg, tokens) == 96
-    assert after["moe_expected_rows"] == 32
     report = program.routing_report(
         tuple(weights[n] for n in program.param_names), x)
     assert [r["layer"] for r in report] == ["l1", "l2"]

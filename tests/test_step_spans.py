@@ -174,13 +174,10 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
                                     "kept_product_layers",
                                     "kept_product_bytes",
                                     "ssm_kernel_layers",
-                                    "latent_attention_layers", "moe_layers",
-                                    "experts_held", "router_width",
-                                    "moe_grouped_rows", "moe_expected_rows",
-                                    "mtp_modules", "attention_layers",
+                                    "moe_grouped_rows", "attention_layers",
                                     "flash_attention_layers",
                                     "linear_attention_layers",
-                                    "kda_chunks_per_seq", "moe_groups_kept",
+                                    "kda_chunks_per_seq",
                                     "kda_kernel_layers"}
 
 
