@@ -1049,6 +1049,12 @@ def render_doctor(report):
                 "as Pallas flash kernels in %d of them"
                 % (compiled["attention_layers"],
                    compiled.get("flash_attention_layers", 0)))
+        if compiled.get("hyper_connection_sublayers"):
+            lines.append(
+                "   %d sublayer(s) in hyper-connections in the traced "
+                "programs; %d latent-attention layer(s) with YaRN positions"
+                % (compiled["hyper_connection_sublayers"],
+                   compiled.get("yarn_rope_layers", 0)))
         if rec.get("anomalies"):
             lines.append("   %d step-time anomaly event(s) flagged"
                          % rec["anomalies"])

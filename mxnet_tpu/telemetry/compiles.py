@@ -66,7 +66,14 @@ One ``jax.monitoring`` duration listener, registered when
   of ``ops/short_conv_kernels.py`` (``kda.conv_kernel_tiles`` decides from
   the heads, their width, the convolutions' width and the compute dtype: 6
   of 6 in that step, 0 where the heads are not 128 columns wide, where
-  they are ``ssm.causal_conv1d``, SiLU and ``kda._l2_normed``).
+  they are ``ssm.causal_conv1d``, SiLU and ``kda._l2_normed``);
+- ``hyper_connection_sublayers``: sublayers wrapped in a
+  manifold-constrained hyper-connection (``transformer/mhc.py``) in the
+  programs traced so far, two a layer where ``hc_mult`` is above 1 (10 for
+  one trace of the five-layer ``Xing4.0-29B-A4B`` step, 0 in every other);
+- ``yarn_rope_layers``: latent-attention layers whose rotary positions are
+  YaRN-scaled (``rope_scaling``, ``transformer/mla.py``) in the programs
+  traced so far (5 for one trace of that step).
 
 Always on: the listener fires only when something is traced, lowered or
 compiled, which a steady step never does.  ``telemetry.enable()`` calls
@@ -92,7 +99,8 @@ _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "ssm_kernel_layers", "moe_grouped_rows", "attention_layers",
          "flash_attention_layers", "linear_attention_layers",
          "kda_chunks_per_seq", "kda_kernel_layers",
-         "kda_conv_kernel_layers")
+         "kda_conv_kernel_layers", "hyper_connection_sublayers",
+         "yarn_rope_layers")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

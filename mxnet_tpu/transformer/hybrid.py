@@ -39,6 +39,14 @@ others ``linear_attention`` (``transformer/kda.py``), both with a head-wise
 output gate (``attention_gate``); a router that chooses within the best
 ``topk_group`` of ``n_group`` groups of experts; no prediction module.
 
+and those of ``xing4_0`` (``Xing4.0-29B-A4B``): the ``deepseek_v3`` family's
+layers with ``hc_mult`` residual streams instead of one, joined around every
+sublayer by manifold-constrained hyper-connections (``transformer/mhc.py``:
+the stream is ``(b, t, hc_mult, d)``, expanded from the embedding and summed
+before the final norm; no ``residual_multiplier``), and YaRN-scaled rotary
+positions (``rope_scaling``, ``transformer/mla.py``); a prediction module
+beside several streams is refused.
+
 No layer is divided: a plan with a ``model``, ``sequence`` or ``pipe`` axis
 is refused.  The ``data`` axis works as for every mesh program (the step
 wrapper owns the one gradient exchange).  ``vocab_size`` is the number of
@@ -56,7 +64,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import pallas_kernels
 from ..telemetry import compiles as _compiles
 from . import layers as L
-from . import kda, mla, moe, ssm
+from . import kda, mhc, mla, moe, ssm
 from .layers import gated_mlp, rms_norm
 from .model import ProgramLayout
 
@@ -67,7 +75,7 @@ __all__ = ["HybridLMConfig", "HybridLM", "HybridProgram",
 MIXERS = ("mamba", "attention", "latent_attention", "linear_attention")
 FEED_FORWARDS = ("gated_mlp", "sparse_experts")
 # the model_types whose keys and equations are deepseek_v3's
-DEEPSEEK_FAMILY = ("deepseek_v3", "joyai_llm_flash")
+DEEPSEEK_FAMILY = ("deepseek_v3", "joyai_llm_flash", "xing4_0")
 # what a training step holds on the mesh tier, bytes a parameter: float32
 # master 4, compute copy 2, float32 gradient 4, float32 momentum 4
 RESIDENT_BYTES_PER_PARAM = 14
@@ -78,6 +86,9 @@ ROOM = 0.9
 # which a layer's checkpoint always keeps: 1/24 of the products' bytes saves
 # the layer's re-run attention's forward pass
 ATTENTION_OUT = "attention_out"
+# the start of a hyper-connection's scalars a_pre, a_post, a_res: the
+# coefficients begin near their biases and move with the token as they grow
+HC_ALPHA = 0.01
 
 
 class HybridLMConfig:
@@ -94,8 +105,13 @@ class HybridLMConfig:
     ``kda_head_dim`` key and value columns, the convolution's width
     ``kda_conv``, the scan's ``kda_chunk`` and the gate's bound
     ``kda_lower_bound`` are the ``linear_attention`` mixer's
-    (``transformer/kda.py``).  :meth:`from_hf` reads the keys of a published
-    ``config.json`` by its ``model_type``."""
+    (``transformer/kda.py``).  ``hc_mult`` above 1 is that many residual
+    streams joined by hyper-connections with ``hc_sinkhorn_iters``
+    iterations of Sinkhorn, ``hc_eps`` and the clamp ``hc_res_clamp`` of the
+    mixing logits (``transformer/mhc.py``); ``rope_scaling`` (None, or the
+    published dict of type ``yarn``) scales latent attention's rotary
+    positions (``transformer/mla.py``).  :meth:`from_hf` reads the keys of a
+    published ``config.json`` by its ``model_type``."""
 
     def __init__(self, vocab_size=64, d_model=32, layer_types=("mamba",
                  "attention"), d_ff=64, n_heads=4, n_kv_heads=2, head_dim=8,
@@ -111,7 +127,9 @@ class HybridLMConfig:
                  n_shared_experts=1, routed_scaling=1.0, norm_topk_prob=True,
                  mtp_modules=0, mtp_weight=0.3, n_group=1, topk_group=1,
                  attention_gate=False, kda_head_dim=8,
-                 kda_conv=4, kda_chunk=8, kda_lower_bound=-5.0):
+                 kda_conv=4, kda_chunk=8, kda_lower_bound=-5.0, hc_mult=1,
+                 hc_sinkhorn_iters=20, hc_eps=1e-6, hc_res_clamp=(-30.0, 30.0),
+                 rope_scaling=None):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.layer_types = tuple(layer_types)
@@ -160,6 +178,12 @@ class HybridLMConfig:
         self.kda_conv = int(kda_conv)
         self.kda_chunk = int(kda_chunk)
         self.kda_lower_bound = float(kda_lower_bound)
+        self.hc_mult = int(hc_mult)
+        self.hc_sinkhorn_iters = int(hc_sinkhorn_iters)
+        self.hc_eps = float(hc_eps)
+        self.hc_res_clamp = tuple(float(v) for v in hc_res_clamp)
+        self.rope_scaling = None if rope_scaling is None else dict(
+            rope_scaling)
         unknown = set(self.layer_types) - set(MIXERS)
         if unknown or not self.layer_types:
             raise ValueError("layer_types must name mixers of %s, got %r"
@@ -191,6 +215,18 @@ class HybridLMConfig:
         if self.mtp_modules not in (0, 1):
             raise ValueError("mtp_modules is 0 or 1, got %d"
                              % self.mtp_modules)
+        if self.hc_mult < 1:
+            raise ValueError("hc_mult is at least 1, got %d" % self.hc_mult)
+        if self.hc_mult > 1 and self.mtp_modules:
+            # how several streams enter and leave the module is in no
+            # published equation; in from_hf's terms, by its key's name
+            raise ValueError("only num_nextn_predict_layers 0 is implemented "
+                             "with hc_mult %d, got %d"
+                             % (self.hc_mult, self.mtp_modules))
+        if self.rope_scaling is not None and _rope_type(
+                self.rope_scaling) != "yarn":
+            raise ValueError("only rope_scaling type 'yarn' is implemented, "
+                             "got %r" % (_rope_type(self.rope_scaling),))
         sub = min(kda.SUB_BLOCK, self.kda_chunk)
         if ("linear_attention" in self.layer_types
                 and -self.kda_lower_bound * sub > kda.MAX_EXPONENT):
@@ -263,8 +299,10 @@ class HybridLMConfig:
         ``config.json``: ``expert_shard=(index, of)`` among ``sizes`` says
         (absent: all of them).  ``n_group``/``topk_group`` above 1 give the
         router its group step, a null ``q_lora_rank`` the query path of one
-        product."""
-        for key, only in (("rope_scaling", None), ("scoring_func", "sigmoid"),
+        product.  ``xing4_0``'s keys: ``hc_mult`` streams with the
+        ``hc_*``/``mhc_h_res_clamp_*`` settings (no prediction module beside
+        more than one), and ``rope_scaling`` of type ``yarn``."""
+        for key, only in (("scoring_func", "sigmoid"),
                           ("topk_method", "noaux_tc"), ("moe_layer_freq", 1),
                           ("hidden_act", "silu"), ("attention_bias", False),
                           ("rope_interleave", True)):
@@ -275,7 +313,7 @@ class HybridLMConfig:
         if modules > 1:
             raise ValueError("only num_nextn_predict_layers 0 or 1 is "
                              "implemented, got %d" % modules)
-        layers = int(config["num_hidden_layers"])
+        layers =int(config["num_hidden_layers"])
         dense = min(int(config["first_k_dense_replace"]), layers)
         return cls(
             vocab_size=config["vocab_size"], d_model=config["hidden_size"],
@@ -302,6 +340,12 @@ class HybridLMConfig:
             tie_embeddings=config["tie_word_embeddings"],
             mtp_modules=modules,
             mtp_weight=config.get("mtp_loss_weight", 0.3),
+            hc_mult=config.get("hc_mult", 1),
+            hc_sinkhorn_iters=config.get("hc_sinkhorn_iters", 20),
+            hc_eps=config.get("hc_eps", 1e-6),
+            hc_res_clamp=(config.get("mhc_h_res_clamp_min", -30.0),
+                          config.get("mhc_h_res_clamp_max", 30.0)),
+            rope_scaling=config.get("rope_scaling"),
             norm_eps=config["rms_norm_eps"], **sizes)
 
     @classmethod
@@ -410,6 +454,11 @@ class HybridLMConfig:
             keys += ["q_lora_rank", "kv_lora_rank", "qk_nope_dim",
                      "qk_rope_dim", "v_head_dim", "rope_theta",
                      "attention_gate"]
+            if self.rope_scaling is not None:
+                keys.append("rope_scaling")
+        if self.hc_mult > 1:
+            keys += ["hc_mult", "hc_sinkhorn_iters", "hc_eps",
+                     "hc_res_clamp"]
         if "linear_attention" in self.layer_types:
             keys += ["kda_head_dim", "kda_conv", "kda_chunk",
                      "kda_lower_bound"]
@@ -418,6 +467,10 @@ class HybridLMConfig:
                      "expert_shard", "experts_held", "n_shared_experts",
                      "routed_scaling", "n_group", "topk_group"]
         return {k: getattr(self, k) for k in keys}
+
+
+def _rope_type(scaling):
+    return scaling.get("type", scaling.get("rope_type"))
 
 
 def _layer_leaves(cfg, mixer, ffn="gated_mlp"):
@@ -440,7 +493,8 @@ def _layer_leaves(cfg, mixer, ffn="gated_mlp"):
                ("wo", (hq, e, d))]
     feed = (moe.leaves(cfg) if ffn == "sparse_experts"
             else [("mlp_in", (d, 2 * f)), ("mlp_out", (f, d))])
-    return [("norm1", (d,))] + mix + [("norm2", (d,))] + feed
+    return [("norm1", (d,))] + mix + [("norm2", (d,))] + feed \
+        + (mhc.leaves(cfg) if cfg.hc_mult > 1 else [])
 
 
 def _block_leaves(cfg, p, prefix, mixer, ffn):
@@ -522,7 +576,9 @@ def _layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn="gated_mlp"):
     a token and head (the running sums and their exponentials, the columns'
     factors of every sub-block, ``W``, ``U`` and the output; the ``L x L``
     system, the factors of its inverse and the scores), with their
-    gradients."""
+    gradients.  Hyper-connections (``hc_mult`` above 1) add the ``n``-wide
+    stream into the layer, between its sublayers and out of it with their
+    cotangents, in ``dtype``, and a write-out's float32 sums with theirs."""
     d = cfg.d_model
     tokens = batch * seq
     if mixer == "linear_attention":
@@ -567,7 +623,12 @@ def _layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn="gated_mlp"):
                      * (4 * d + 3 * cfg.moe_d_ff))
     else:
         elements += tokens * cfg.d_ff
-    return elements * jnp.dtype(dtype).itemsize + 4 * scores * 4
+    sums = 0
+    if cfg.hc_mult > 1:
+        stream = tokens * cfg.hc_mult * d
+        elements += 6 * stream
+        sums = 2 * stream * 4
+    return elements * jnp.dtype(dtype).itemsize + 4 * scores * 4 + sums
 
 
 def keeps_products(cfg, n_params, batch, seq, dtype, bytes_limit):
@@ -575,15 +636,20 @@ def keeps_products(cfg, n_params, batch, seq, dtype, bytes_limit):
     its layers' projection products for the backward pass: they are kept
     where the resident state (:data:`RESIDENT_BYTES_PER_PARAM` a parameter,
     an over-count), the kept products and the largest layer's live set fit
-    in :data:`ROOM` of ``bytes_limit``, the device's memory.  A device that
-    reports no limit (``None``) keeps.  A pure function of its arguments:
-    one shape on one kind of device compiles one program."""
+    in :data:`ROOM` of ``bytes_limit``, the device's memory; with
+    ``hc_mult`` streams, so must the input every layer's checkpoint keeps
+    and the last layer's output, ``hc_mult`` times the width each.  A
+    device that reports no limit (``None``) keeps.  A pure function of its
+    arguments: one shape on one kind of device compiles one program."""
     if bytes_limit is None:
         return True
     live = max(_layer_live_bytes(cfg, mixer, batch, seq, dtype, ffn)
                for mixer, ffn in {b[1:] for b in cfg.blocks + cfg.mtp_blocks})
     held = (RESIDENT_BYTES_PER_PARAM * n_params
             + kept_product_bytes(cfg, batch, seq, dtype) + live)
+    if cfg.hc_mult > 1:
+        held += (len(cfg.blocks) + 1) * batch * seq * cfg.hc_mult \
+            * cfg.d_model * jnp.dtype(dtype).itemsize
     return held <= ROOM * bytes_limit
 
 
@@ -712,8 +778,11 @@ class HybridProgram(ProgramLayout):
     # -- init -------------------------------------------------------------
     def _draw_leaf(self, key, kind, shape):
         """One leaf from ``key``, by its kind (``kda_a_log`` and
-        ``kda_dt_bias`` as the code below says): projections normal over the
-        root of their fan-in; the embedding and an untied head normal times
+        ``kda_dt_bias`` as the code below says): projections (a
+        hyper-connection's ``phi`` too) normal over the root of their
+        fan-in; a hyper-connection's ``alpha`` :data:`HC_ALPHA` and its
+        ``bias`` uniform within one, so that the streams part from the first
+        sublayer on; the embedding and an untied head normal times
         ``init_scale``; norms and ``D`` one; the router's choosing bias
         uniform within 0.1 (so that choosing by ``s + b`` and weighing by
         ``s`` differ); the convolution uniform within one over the
@@ -725,6 +794,10 @@ class HybridProgram(ProgramLayout):
         if kind.startswith("norm") or kind in ("ssm_norm", "ssm_d",
                                                "kda_norm"):
             return jnp.ones(shape, f32)
+        if kind.startswith("hc_") and kind.endswith("_alpha"):
+            return jnp.full(shape, HC_ALPHA, f32)
+        if kind.startswith("hc_") and kind.endswith("_bias"):
+            return jax.random.uniform(key, shape, f32, -1.0, 1.0)
         if kind == "kda_a_log":
             # rates within a factor of two of one, and biases from -8 to 2,
             # spread the gate over (lower_bound, 0): memories from a fifth
@@ -783,7 +856,7 @@ class HybridProgram(ProgramLayout):
     def _latent_attention(self, lp, x):
         cfg = self.cfg
         q, k, v = mla.queries_keys_values(lp, x, cfg)
-        o = causal_gqa_attention(q, k, v, q.shape[-1] ** -0.5,
+        o = causal_gqa_attention(q, k, v, mla.softmax_scale(cfg),
                                  cfg.attention_block)
         if cfg.attention_gate:
             with jax.named_scope("mla_gate"):
@@ -792,46 +865,67 @@ class HybridProgram(ProgramLayout):
             return checkpoint_name(
                 jnp.einsum("bthe,hed->btd", o, lp["wo"]), ssm.PROJECTION)
 
-    def _mix(self, mixer, lp, h):
-        """A layer's first half: the residual stream after its mixer."""
+    def _mixer(self, mixer, lp, h):
+        """A layer's mixer over the stream it reads, through its norm."""
         cfg = self.cfg
-        scale = jnp.asarray(cfg.residual_multiplier, h.dtype)
         if mixer == "mamba":
             with jax.named_scope("mamba_mixer"):
-                m = ssm.mamba2_mixer(
+                return ssm.mamba2_mixer(
                     lp, rms_norm(h, lp["norm1"], cfg.norm_eps), cfg)
-        elif mixer == "linear_attention":
+        if mixer == "linear_attention":
             with jax.named_scope("kda_mixer"):
-                m = kda.kda_mixer(
+                return kda.kda_mixer(
                     lp, rms_norm(h, lp["norm1"], cfg.norm_eps), cfg)
-        else:
-            attend = (self._latent_attention if mixer == "latent_attention"
-                      else self._attention)
-            with jax.named_scope("attention"):
-                m = attend(lp, rms_norm(h, lp["norm1"], cfg.norm_eps))
-        return h + scale * m
+        attend = (self._latent_attention if mixer == "latent_attention"
+                  else self._attention)
+        with jax.named_scope("attention"):
+            return attend(lp, rms_norm(h, lp["norm1"], cfg.norm_eps))
+
+    def _feed_forward(self, ffn, lp, h):
+        """A layer's feed-forward over the stream it reads, through its
+        norm."""
+        cfg = self.cfg
+        if ffn == "sparse_experts":
+            with jax.named_scope("sparse_experts"):
+                return moe.sparse_experts(
+                    lp, rms_norm(h, lp["norm2"], cfg.norm_eps), cfg)
+        with jax.named_scope("gated_mlp"):
+            return gated_mlp(lp, rms_norm(h, lp["norm2"], cfg.norm_eps))
+
+    def _residual(self, prefix, lp, h, sublayer):
+        """The residual stream ``h`` after ``sublayer``: ``h +
+        residual_multiplier * sublayer(h)``, or with several streams the
+        sublayer in its hyper-connection (leaves ``prefix...``)."""
+        cfg = self.cfg
+        if cfg.hc_mult > 1:
+            return mhc.connect(lp, prefix, h, sublayer, cfg)
+        scale = jnp.asarray(cfg.residual_multiplier, h.dtype)
+        return h + scale * sublayer(h)
+
+    def _mix(self, mixer, lp, h):
+        """A layer's first half: the residual stream after its mixer."""
+        return self._residual(mhc.SUBLAYERS[0], lp, h,
+                              lambda u: self._mixer(mixer, lp, u))
 
     def _feed(self, ffn, lp, h):
         """A layer's second half: the stream after its feed-forward."""
-        cfg = self.cfg
-        scale = jnp.asarray(cfg.residual_multiplier, h.dtype)
-        if ffn == "sparse_experts":
-            with jax.named_scope("sparse_experts"):
-                m = moe.sparse_experts(
-                    lp, rms_norm(h, lp["norm2"], cfg.norm_eps), cfg)
-        else:
-            with jax.named_scope("gated_mlp"):
-                m = gated_mlp(lp, rms_norm(h, lp["norm2"], cfg.norm_eps))
-        return h + scale * m
+        return self._residual(mhc.SUBLAYERS[1], lp, h,
+                              lambda u: self._feed_forward(ffn, lp, u))
 
     def _layer(self, mixer, ffn, lp, h):
         """One layer over its leaves ``lp`` (kind -> array)."""
         return self._feed(ffn, lp, self._mix(mixer, lp, h))
 
     def _embed(self, p, ids):
+        """The residual stream at the start: the embedding, or ``hc_mult``
+        copies of it."""
         with jax.named_scope("embed"):
             h = jnp.take(p["embed"], ids, axis=0)
-            return h * jnp.asarray(self.cfg.embedding_multiplier, h.dtype)
+            h = h * jnp.asarray(self.cfg.embedding_multiplier, h.dtype)
+        if self.cfg.hc_mult == 1:
+            return h
+        with jax.named_scope("hyper_connection"):
+            return mhc.expand(h, self.cfg.hc_mult)
 
     def _head_loss(self, p, h, norm, y, reduce):
         """The cross-entropy of every position of ``h`` (b, t, d) against
@@ -854,6 +948,9 @@ class HybridProgram(ProgramLayout):
 
         if cfg.mtp_modules:
             losses = jax.checkpoint(losses)
+        if cfg.hc_mult > 1:
+            with jax.named_scope("hyper_connection"):
+                h = mhc.merge(h)
         with jax.named_scope("lm_head_loss"):
             return reduce(losses(h, p[norm],
                                  p["embed" if cfg.tie_embeddings else "head"]))
@@ -896,6 +993,11 @@ class HybridProgram(ProgramLayout):
                 _compiles.count("attention_layers")
                 _compiles.count("flash_attention_layers", int(bool(
                     attention_kernel_blocks(cfg, mixer, x.shape[1], dtype))))
+                _compiles.count("yarn_rope_layers", int(
+                    mixer == "latent_attention"
+                    and cfg.rope_scaling is not None))
+            _compiles.count("hyper_connection_sublayers",
+                            2 * (cfg.hc_mult > 1))
             return layer(lp, h)
 
         h = self._embed(p, x)
@@ -911,7 +1013,7 @@ class HybridProgram(ProgramLayout):
                            -(-x.shape[1] // cfg.kda_chunk))
         if "sparse_experts" in cfg.ffn_types:
             _compiles.note("moe_grouped_rows", moe.buffer_rows(cfg, x.size))
-        loss = self._head_loss(p, h, "norm_f", y, jnp.mean)
+        loss =self._head_loss(p, h, "norm_f", y, jnp.mean)
         for entry in cfg.mtp_blocks:
             with jax.named_scope("mtp_module"):
                 # position i reads the stream before the final norm and the
@@ -949,9 +1051,14 @@ class HybridProgram(ProgramLayout):
                 lp = _block_leaves(cfg, p, prefix, mixer, ffn)
                 h = self._mix(mixer, lp, h)
                 if ffn == "sparse_experts":
-                    out.append(moe.held_loads(
-                        lp, rms_norm(h, lp["norm2"], cfg.norm_eps), cfg))
-                h = self._feed(ffn, lp, h)
+                    # the loads of the input the feed-forward is handed
+                    def feed(u, lp=lp):
+                        out.append(moe.held_loads(
+                            lp, rms_norm(u, lp["norm2"], cfg.norm_eps), cfg))
+                        return self._feed_forward(ffn, lp, u)
+                    h = self._residual(mhc.SUBLAYERS[1], lp, h, feed)
+                else:
+                    h = self._feed(ffn, lp, h)
             return out
 
         names = [prefix[:-1] for prefix, _, ffn in cfg.blocks

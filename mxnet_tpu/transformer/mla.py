@@ -26,8 +26,26 @@ the flash kernels of ``ops/pallas_kernels.py`` where ``flash_tiles`` takes
 the shapes (two widths are theirs to take) and as blocks of rows elsewhere.
 In training nothing is cached, so the key-values are expanded from the
 latent; a latent cache is serving's.
+
+``rope_scaling`` of type ``yarn`` (DeepSeek-V3's) changes the rotary turns
+and the scale of the scores, and nothing else: with ``r = qk_rope_dim``,
+``s = factor`` and ``L0 = original_max_position_embeddings``::
+
+    f_j   = theta^(-2j/r)                      j = 0 .. r/2 - 1
+    d(b)  = r ln(L0 / (2 pi b)) / (2 ln theta)
+    lo    = max(floor(d(beta_fast)), 0) ;  hi = min(ceil(d(beta_slow)), r - 1)
+    ramp  = clamp((j - lo) / (hi - lo), 0, 1)
+    f'_j  = f_j (1 - ramp_j) + (f_j / s) ramp_j
+    cos, sin times mscale(s, mscale) / mscale(s, mscale_all_dim)
+    scale = (nope + rope)^-1/2 mscale(s, mscale_all_dim)^2
+    mscale(s, m) = 0.1 m ln s + 1                (1 where s <= 1)
+
+(the second factor of the scale only where ``mscale_all_dim`` is given and
+not nought, as DeepSeek-V3's attention takes it).
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +56,7 @@ from .layers import rms_norm
 from .ssm import PROJECTION
 
 __all__ = ["rope_interleaved", "queries_keys_values", "gated", "leaves",
-           "product_widths"]
+           "product_widths", "yarn", "softmax_scale"]
 
 
 def leaves(cfg):
@@ -67,22 +85,69 @@ def product_widths(cfg):
             h * (cfg.qk_nope_dim + cfg.v_head_dim), cfg.d_model]
 
 
-def rope_interleaved(x, theta, offset=0, axis=1):
+def _mscale(factor, m):
+    return 0.1 * m * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn(cfg):
+    """``(turns, factor)`` of ``cfg``'s YaRN rotary positions: the turn of
+    every pair, float32 (r/2), and the factor of cos and sin; None where
+    ``rope_scaling`` is None."""
+    scaling = cfg.rope_scaling
+    if scaling is None:
+        return None
+    r, theta = cfg.qk_rope_dim, cfg.rope_theta
+    factor = float(scaling["factor"])
+    start = float(scaling["original_max_position_embeddings"])
+
+    def dim(rotations):
+        return r * math.log(start / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(dim(scaling.get("beta_fast", 32))), 0)
+    hi = min(math.ceil(dim(scaling.get("beta_slow", 1))), r - 1)
+    ramp = np.clip((np.arange(r // 2) - lo) / (hi - lo if hi > lo else 1e-3),
+                   0.0, 1.0)
+    turns = theta ** (-np.arange(0, r, 2, dtype=np.float64) / r)
+    turns = turns * (1 - ramp) + turns / factor * ramp
+    return (turns.astype(np.float32),
+            _mscale(factor, scaling.get("mscale", 1))
+            / _mscale(factor, scaling.get("mscale_all_dim", 0)))
+
+
+def softmax_scale(cfg):
+    """The scores' scale: ``(nope + rope)^-1/2``, and under YaRN times
+    ``mscale(factor, mscale_all_dim)^2``."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    scaling = cfg.rope_scaling
+    if scaling is not None and scaling.get("mscale_all_dim"):
+        scale *= _mscale(float(scaling["factor"]),
+                         scaling["mscale_all_dim"]) ** 2
+    return scale
+
+
+def rope_interleaved(x, theta, offset=0, axis=1, turns=None, factor=1.0):
     """Rotary positions over ``x`` (b, t, ..., r; the positions along
     ``axis``) in float32: the pair of columns ``(2j, 2j+1)`` of position
-    ``p`` turns by ``p theta^(-2j/r)`` (``rope_interleave``: the pairs are
-    neighbours, not the two halves).  The turned pair stays where it was, so
-    the scores are those of the HF code, which moves the pairs apart first
-    in queries and keys alike."""
+    ``p`` turns by ``p theta^(-2j/r)``, or by ``p turns[j]`` where the turns
+    are given (YaRN's, :func:`yarn`), cos and sin times ``factor``
+    (``rope_interleave``: the pairs are neighbours, not the two halves).
+    The turned pair stays where it was, so the scores are those of the HF
+    code, which moves the pairs apart first in queries and keys alike."""
     r = x.shape[-1]
     f32 = jnp.float32
     axis %= x.ndim
-    turns = theta ** (-jnp.arange(0, r, 2, dtype=f32) / r)
+    if turns is None:
+        turns = theta ** (-jnp.arange(0, r, 2, dtype=f32) / r)
+    else:
+        turns = jnp.asarray(turns, f32)
     angle = (offset + jnp.arange(x.shape[axis], dtype=f32))[:, None] * turns
     shape = tuple(n if i in (axis, x.ndim - 1) else 1
                   for i, n in enumerate(x.shape))
     cos = jnp.repeat(jnp.cos(angle), 2, axis=-1).reshape(shape)
     sin = jnp.repeat(jnp.sin(angle), 2, axis=-1).reshape(shape)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     # the pair's other column, signed: (-x[2j+1], x[2j]), as a product with
     # a signed permutation (exact: one term of +-1 a column).  As two rolls
     # along the columns it is a shuffle of lanes where the columns are the
@@ -125,8 +190,11 @@ def queries_keys_values(lp, x, cfg):
         kv = checkpoint_name(jnp.einsum("btr,rhe->bhte", c_kv, lp["wkv_b"]),
                              PROJECTION)
     with jax.named_scope("mla_rope"):
-        q_r = rope_interleaved(q[..., nope:], cfg.rope_theta, axis=2)
-        k_r = rope_interleaved(k_r[:, None], cfg.rope_theta, axis=2)
+        turns, factor = yarn(cfg) or (None, 1.0)
+        q_r = rope_interleaved(q[..., nope:], cfg.rope_theta, axis=2,
+                               turns=turns, factor=factor)
+        k_r = rope_interleaved(k_r[:, None], cfg.rope_theta, axis=2,
+                               turns=turns, factor=factor)
         q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
         k = jnp.concatenate(
             [kv[..., :nope],

@@ -26,7 +26,6 @@ from mxnet_tpu.transformer import hybrid
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 CELL_CONFIG = os.path.join(BENCH, "configs", "Ling-3.0-flash.json")
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 PARAMETERS = 822_060_224
 # a configuration file of the bailing_hybrid family at a size for the CPU:
 # three layers (linear, latent, linear; the first dense), 4 of 16 experts
@@ -568,15 +567,19 @@ def test_the_cell_has_822_million_parameters_and_the_references_leaves(
 
 @pytest.fixture(scope="module")
 def row():
-    if not os.path.isfile(CATALOG):
-        pytest.skip("no catalog beside the model-configs guide")
-    with open(CATALOG) as f:
-        return next(r for r in map(json.loads, f)
-                    if r["name"] == "Ling-3.0-flash")["config"]
+    """The published ``config.json`` key for key: the ``PUBLISHED`` dict of
+    ``tests/benchmark/test_ling_cell.py``, which the cell's configuration
+    file is held to."""
+    spec = importlib.util.spec_from_file_location(
+        "ling_cell_published",
+        os.path.join(REPO, "tests", "benchmark", "test_ling_cell.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return dict(module.PUBLISHED)
 
 
 def test_from_hf_builds_the_42_layer_table_of_the_catalogs_row(row):
-    """The catalog row's ``config`` key for key, with the two things the
+    """The published ``config`` key for key, with the two things the
     program cannot run taken out by name first (the prediction module, the
     clamps of layers 34-41): layers 5, 11, ..., 41 latent, the other 35
     linear; two dense layers, 40 of 512 experts in 8 groups."""

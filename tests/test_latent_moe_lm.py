@@ -428,7 +428,7 @@ def test_the_cell_has_680_million_parameters_and_the_references_leaves(
 
 @pytest.mark.parametrize("key,value", [
     ("scoring_func", "softmax"),
-    ("rope_scaling", {"type": "yarn", "factor": 40}), ("moe_layer_freq", 2),
+    ("rope_scaling", {"type": "linear", "factor": 4}), ("moe_layer_freq", 2),
     ("num_nextn_predict_layers", 2), ("topk_method", "greedy"),
     ("rope_interleave", False),
     ("hidden_act", "gelu"), ("attention_bias", True),

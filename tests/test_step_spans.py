@@ -179,7 +179,9 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
                                     "linear_attention_layers",
                                     "kda_chunks_per_seq",
                                     "kda_kernel_layers",
-                                    "kda_conv_kernel_layers"}
+                                    "kda_conv_kernel_layers",
+                                    "hyper_connection_sublayers",
+                                    "yarn_rope_layers"}
 
 
 def test_the_doctor_reads_the_spans_and_the_compile_counters(
