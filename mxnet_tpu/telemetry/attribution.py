@@ -1052,8 +1052,10 @@ def render_doctor(report):
         if compiled.get("hyper_connection_sublayers"):
             lines.append(
                 "   %d sublayer(s) in hyper-connections in the traced "
-                "programs; %d latent-attention layer(s) with YaRN positions"
+                "programs, as Pallas kernel pairs in %d of them; %d "
+                "latent-attention layer(s) with YaRN positions"
                 % (compiled["hyper_connection_sublayers"],
+                   compiled.get("hc_kernel_sublayers", 0),
                    compiled.get("yarn_rope_layers", 0)))
         if rec.get("anomalies"):
             lines.append("   %d step-time anomaly event(s) flagged"

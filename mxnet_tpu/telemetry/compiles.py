@@ -71,6 +71,12 @@ One ``jax.monitoring`` duration listener, registered when
   manifold-constrained hyper-connection (``transformer/mhc.py``) in the
   programs traced so far, two a layer where ``hc_mult`` is above 1 (10 for
   one trace of the five-layer ``Xing4.0-29B-A4B`` step, 0 in every other);
+- ``hc_kernel_sublayers``: of the ``hyper_connection_sublayers``, the ones
+  traced as the Pallas kernel pairs of ``ops/mhc_kernels.py``
+  (``mhc.kernel_tiles`` decides from the tokens, the streams, their width
+  and the compute dtype: 10 of 10 in that step, 0 at a width that is no
+  multiple of 128, where the connection is ``mhc.coefficients``,
+  ``read_in`` and ``write_out``);
 - ``yarn_rope_layers``: latent-attention layers whose rotary positions are
   YaRN-scaled (``rope_scaling``, ``transformer/mla.py``) in the programs
   traced so far (5 for one trace of that step).
@@ -100,7 +106,7 @@ _NAMES = ("trace_s", "lower_s", "backend_s", "in_span_programs",
          "flash_attention_layers", "linear_attention_layers",
          "kda_chunks_per_seq", "kda_kernel_layers",
          "kda_conv_kernel_layers", "hyper_connection_sublayers",
-         "yarn_rope_layers")
+         "hc_kernel_sublayers", "yarn_rope_layers")
 
 _lock = threading.Lock()
 _totals = dict.fromkeys(_NAMES, 0)

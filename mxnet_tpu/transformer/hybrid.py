@@ -42,8 +42,8 @@ output gate (``attention_gate``); a router that chooses within the best
 and those of ``xing4_0`` (``Xing4.0-29B-A4B``): the ``deepseek_v3`` family's
 layers with ``hc_mult`` residual streams instead of one, joined around every
 sublayer by manifold-constrained hyper-connections (``transformer/mhc.py``:
-the stream is ``(b, t, hc_mult, d)``, expanded from the embedding and summed
-before the final norm; no ``residual_multiplier``), and YaRN-scaled rotary
+the stream is ``(b, t, hc_mult d)``, the streams side by side, expanded from
+the embedding and summed before the final norm; no ``residual_multiplier``), and YaRN-scaled rotary
 positions (``rope_scaling``, ``transformer/mla.py``); a prediction module
 beside several streams is refused.
 
@@ -950,7 +950,7 @@ class HybridProgram(ProgramLayout):
             losses = jax.checkpoint(losses)
         if cfg.hc_mult > 1:
             with jax.named_scope("hyper_connection"):
-                h = mhc.merge(h)
+                h = mhc.merge(h, cfg.hc_mult)
         with jax.named_scope("lm_head_loss"):
             return reduce(losses(h, p[norm],
                                  p["embed" if cfg.tie_embeddings else "head"]))
@@ -998,6 +998,9 @@ class HybridProgram(ProgramLayout):
                     and cfg.rope_scaling is not None))
             _compiles.count("hyper_connection_sublayers",
                             2 * (cfg.hc_mult > 1))
+            _compiles.count("hc_kernel_sublayers", 2 * (
+                cfg.hc_mult > 1
+                and mhc.kernel_tiles(cfg, x.size, dtype) is not None))
             return layer(lp, h)
 
         h = self._embed(p, x)

@@ -4,7 +4,9 @@ arXiv:2409.19606) as the residual path of :class:`~.hybrid.HybridLM`'s
 layer table (docs/transformer.md "The layer table").
 
 The residual stream is ``n = hc_mult`` streams of the model's width ``C``,
-held ``(b, t, n, C)`` with ``C`` the minor axis.  Around every sublayer ``F``
+held ``(b, t, n C)`` between the layers (the streams side by side, ``C``
+the minor axis; :func:`coefficients`, :func:`read_in` and :func:`write_out`
+read the view ``(b, t, n, C)``).  Around every sublayer ``F``
 (a layer's mixer, then its feed-forward), with coefficients a token and in
 float32 from the token's own streams::
 
@@ -28,15 +30,28 @@ summed before the final norm (:func:`merge`).  A sublayer's leaves are
 The mixes are ``n`` broadcast multiply-adds over the stream, summed in
 float32 and rounded to the stream's type once: as products of ``n x n``
 matrices a token they would go to the matrix unit padded to its tile.
+
+**Which path runs.**  :func:`connect` asks :func:`kernel_tiles`
+(``ops/mhc_kernels.mhc_tiles`` over the tokens, ``n``, ``C`` and the
+stream's dtype) and nothing else: where it gives blocks (``C`` a multiple of
+128, ``n`` from 2 to 8) the connection is that module's two kernel pairs,
+``connect_in`` (the coefficients and the read-in) and ``connect_out`` (the
+write-out), the same mathematics at the same precision; everywhere else it
+is :func:`coefficients`, :func:`read_in` and :func:`write_out`, which the
+tests hold the kernels to.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import mhc_kernels
+
 __all__ = ["leaves", "coefficients", "sinkhorn", "read_in", "write_out",
-           "expand", "merge", "connect", "SUBLAYERS"]
+           "expand", "merge", "connect", "kernel_tiles", "SUBLAYERS"]
 
 # the two sublayers of a layer, by the prefix of their leaves' kinds
 SUBLAYERS = ("hc_mix_", "hc_ffn_")
@@ -113,24 +128,51 @@ def write_out(h_res, h_post, x, y):
 
 
 def expand(e, n):
-    """The streams at the start: ``n`` copies of the embedding ``e``
-    (b, t, C) -> (b, t, n, C)."""
-    return jnp.broadcast_to(e[..., None, :],
-                            e.shape[:-1] + (n,) + e.shape[-1:])
+    """The streams at the start: ``n`` copies of the embedding ``e`` side
+    by side, (b, t, C) -> (b, t, n C)."""
+    return jnp.concatenate([e] * n, axis=-1)
 
 
-def merge(x):
-    """The streams at the end, summed: (b, t, n, C) -> (b, t, C), in float32
-    and rounded once."""
-    return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
+def merge(x, n):
+    """The ``n`` streams at the end, summed: (b, t, n C) -> (b, t, C), in
+    float32 and rounded once."""
+    c = x.shape[-1] // n
+    return functools.reduce(jnp.add, [x[..., j * c:(j + 1) * c].astype(
+        jnp.float32) for j in range(n)]).astype(x.dtype)
+
+
+def kernel_tiles(cfg, tokens, dtype):
+    """The blocks of ``ops/mhc_kernels.py`` for a connection of ``tokens``
+    tokens of the stream in ``dtype``, or None where the spelling runs."""
+    return mhc_kernels.mhc_tiles(tokens, cfg.hc_mult, cfg.d_model, dtype)
 
 
 def connect(lp, prefix, x, sublayer, cfg):
-    """The stream ``x`` (b, t, n, C) after ``sublayer`` (the sublayer's own
-    norm and body, (b, t, C) -> (b, t, C)) wrapped in its hyper-connection,
-    whose leaves are ``lp[prefix + ...]``.  The connection's work is under
-    the scope ``hyper_connection``: ``hc_coeffs``, ``hc_read_in``,
-    ``hc_write_out``; the sublayer's under its own."""
+    """The stream ``x`` (b, t, n C), the ``n`` streams side by side (or its
+    view (b, t, n, C); the result has ``x``'s shape), after ``sublayer``
+    (the sublayer's own norm and body, (b, t, C) -> (b, t, C)) wrapped in
+    its hyper-connection, whose leaves are ``lp[prefix + ...]``.  The
+    connection's work is under the scope ``hyper_connection``:
+    ``hc_coeffs``, ``hc_read_in``, ``hc_write_out``; the sublayer's under
+    its own."""
+    (b, t), n, c = x.shape[:2], cfg.hc_mult, cfg.d_model
+    wide = n * c
+    tiles = kernel_tiles(cfg, b * t, x.dtype)
+    if tiles is not None:
+        with jax.named_scope("hyper_connection"):
+            flat = mhc_kernels.whole_blocks(x.reshape(b * t, wide), tiles)
+            u, h, flat = mhc_kernels.connect_in(
+                flat, lp[prefix + "phi"], lp[prefix + "bias"],
+                lp[prefix + "alpha"], tiles,
+                (cfg.norm_eps, tuple(cfg.hc_res_clamp),
+                 cfg.hc_sinkhorn_iters, cfg.hc_eps))
+        y = sublayer(u[:b * t].reshape(b, t, c))
+        with jax.named_scope("hyper_connection"), \
+                jax.named_scope("hc_write_out"):
+            y = mhc_kernels.whole_blocks(y.reshape(b * t, c), tiles)
+            out = mhc_kernels.connect_out(flat, y, h, tiles)
+            return out[:b * t].reshape(x.shape)
+    shape, x = x.shape, x.reshape(b, t, n, c)
     with jax.named_scope("hyper_connection"):
         with jax.named_scope("hc_coeffs"):
             h_pre, h_post, h_res = coefficients(
@@ -141,4 +183,4 @@ def connect(lp, prefix, x, sublayer, cfg):
     y = sublayer(u)
     with jax.named_scope("hyper_connection"), \
             jax.named_scope("hc_write_out"):
-        return write_out(h_res, h_post, x, y)
+        return write_out(h_res, h_post, x, y).reshape(shape)

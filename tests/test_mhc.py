@@ -150,11 +150,12 @@ def test_read_in_and_write_out_are_the_explicit_sums(dtype):
 
 
 def test_expand_and_merge():
+    """The streams side by side, (b, t, n C)."""
     e = jax.random.normal(jax.random.PRNGKey(4), (2, 3, 8))
     x = mhc.expand(e, 4)
-    assert x.shape == (2, 3, 4, 8)
-    np.testing.assert_array_equal(x[..., 2, :], e)
-    np.testing.assert_allclose(mhc.merge(x), 4 * e, rtol=1e-6)
+    assert x.shape == (2, 3, 32)
+    np.testing.assert_array_equal(x[..., 16:24], e)
+    np.testing.assert_allclose(mhc.merge(x, 4), 4 * e, rtol=1e-6)
 
 
 # -- one stream is today's residual ------------------------------------------

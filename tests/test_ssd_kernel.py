@@ -503,3 +503,45 @@ def test_the_chips_compiler_takes_the_conv_kernels_beside_their_neighbours(
                 and not line.startswith("ENTRY")
                 and "get-tuple-element(%_short_conv" not in line
                 and "tpu_custom_call" not in line]
+
+
+# the hyper-connection kernels of ``ops/mhc_kernels.py``, for the same
+# reason here and not in ``tests/test_mhc_kernel.py``
+def test_the_chips_compiler_takes_the_hyper_connection_kernels(
+        one_chip, monkeypatch, request):
+    """One sublayer's connection of ``Xing4.0-29B-A4B`` (2 x 4,096 tokens,
+    four streams of 3,584, bfloat16) around a sublayer, compiled for a v5e
+    that is described, not attached, at the blocks ``mhc_tiles`` gives
+    (256 x 1,792): value and gradient hold the six kernels once each, and no
+    float32 copy of the stream or of ``u``."""
+    from mxnet_tpu.ops import mhc_kernels
+    from mxnet_tpu.transformer import HybridLMConfig, mhc
+    monkeypatch.setattr(mhc_kernels, "resolve_interpret", lambda *a: False)
+    forget = lambda: [fn.clear_cache() for fn in (
+        mhc_kernels._in_forward, mhc_kernels._in_backward,
+        mhc_kernels._out_forward, mhc_kernels._out_backward)]
+    forget()
+    request.addfinalizer(forget)
+    cfg = HybridLMConfig(layer_types=("latent_attention",), d_model=3584,
+                         hc_mult=4, norm_eps=1e-6)
+    dtype = jnp.bfloat16
+    assert mhc.kernel_tiles(cfg, 8192, dtype) == (256, 1792)
+    leaves = {"hc_mix_" + kind: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+              for kind, shape in mhc.leaves(cfg)[:3]
+              for kind in [kind[len("hc_mix_"):]]}
+    x = jax.ShapeDtypeStruct((2, 4096, 4 * 3584), dtype, sharding=one_chip)
+    loss = lambda lp, x: mhc.connect(lp, "hc_mix_", x, jnp.tanh, cfg) \
+        .astype(jnp.float32).sum()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        leaves, x).compile().as_text()
+    calls = [line.split("=")[0].strip().lstrip("%").split(".")[0]
+             for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sorted(calls) == sorted([
+        "_mhc_coeffs_fwd_kernel", "_mhc_read_in_kernel",
+        "_mhc_coeffs_bwd_kernel", "_mhc_in_bwd_kernel",
+        "_mhc_out_fwd_kernel", "_mhc_out_bwd_kernel"])
+    assert not [line for line in text.splitlines()
+                if "hyper_connection" in line
+                and ("f32[8192,14336]" in line or "f32[8192,3584]" in line)]

@@ -181,6 +181,7 @@ def test_dump_metrics_writes_the_spans_and_the_compile_counters(tmp_path):
                                     "kda_kernel_layers",
                                     "kda_conv_kernel_layers",
                                     "hyper_connection_sublayers",
+                                    "hc_kernel_sublayers",
                                     "yarn_rope_layers"}
 
 

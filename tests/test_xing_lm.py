@@ -21,7 +21,7 @@ import pytest
 from mxnet_tpu.parallel import DataParallelTrainer, MeshPlan
 from mxnet_tpu import telemetry
 from mxnet_tpu.telemetry import compiles
-from mxnet_tpu.transformer import HybridLM, HybridLMConfig, mla, moe
+from mxnet_tpu.transformer import HybridLM, HybridLMConfig, mhc, mla, moe
 from mxnet_tpu.transformer import hybrid
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -175,6 +175,30 @@ def test_every_leafs_gradient_is_the_references(ref):
             lambda v: program.loss_replica(v, x, y, None))(vals)
         want, want_grads = jax.value_and_grad(
             lambda p: ref.loss_fn(SMALL, p, x, y, ref.HOLD["float32"]))(
+                weights)
+    assert float(loss) == pytest.approx(float(want), rel=2e-6)
+    for name, g in zip(program.param_names, grads):
+        if "router_bias" in name:
+            assert float(jnp.abs(g).max()) == 0.0
+            continue
+        _close(g, want_grads[name], 2e-4)
+
+
+def test_every_leafs_gradient_is_the_references_on_the_kernels(ref):
+    """As above at a width of 128, where the connections are the Pallas
+    kernel pairs of ``ops/mhc_kernels.py`` (the dense layer alone: two
+    connections, 64 tokens padded to a block of 128)."""
+    config = dict(SMALL, hidden_size=128, num_hidden_layers=1)
+    cfg, program = _program(ref, config)
+    assert mhc.kernel_tiles(cfg, 64, jnp.float32) == (128, 128)
+    weights = ref.make_weights(config, {}, 23)
+    x, y = _batch(config, 24)
+    vals = tuple(weights[n] for n in program.param_names)
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda v: program.loss_replica(v, x, y, None)))(vals)
+        want, want_grads = jax.jit(jax.value_and_grad(
+            lambda p: ref.loss_fn(config, p, x, y, ref.HOLD["float32"])))(
                 weights)
     assert float(loss) == pytest.approx(float(want), rel=2e-6)
     for name, g in zip(program.param_names, grads):
@@ -430,16 +454,19 @@ def test_the_trace_notes_its_counters_and_scopes(ref):
         tuple(weights[n] for n in program.param_names))
     after = compiles.counters()
     grew = {k: after[k] - before[k] for k in
-            ("hyper_connection_sublayers", "yarn_rope_layers",
-             "attention_layers", "recomputed_layers")}
-    assert grew == {"hyper_connection_sublayers": 6, "yarn_rope_layers": 3,
-                    "attention_layers": 3, "recomputed_layers": 3}
+            ("hyper_connection_sublayers", "hc_kernel_sublayers",
+             "yarn_rope_layers", "attention_layers", "recomputed_layers")}
+    # a width of 32 does not tile: the connections are the spelling
+    assert grew == {"hyper_connection_sublayers": 6, "hc_kernel_sublayers": 0,
+                    "yarn_rope_layers": 3, "attention_layers": 3,
+                    "recomputed_layers": 3}
     text = telemetry.render_doctor({
         "directory": "d", "ranks": {"worker0": {"compiles": grew}},
         "stragglers": [], "events": dict.fromkeys(
             ("straggler", "anomaly", "queue_growth", "fault"), ())})
-    assert ("6 sublayer(s) in hyper-connections in the traced programs; 3 "
-            "latent-attention layer(s) with YaRN positions") in text
+    assert ("6 sublayer(s) in hyper-connections in the traced programs, as "
+            "Pallas kernel pairs in 0 of them; 3 latent-attention layer(s) "
+            "with YaRN positions") in text
     text = lowered.as_text(debug_info=True)
     for scope in ("hyper_connection", "hc_coeffs", "hc_read_in",
                   "hc_write_out", "mla_rope", "attention", "sparse_experts"):
